@@ -4,13 +4,18 @@ Prints ONE JSON line: {"metric", "value", "unit", "vs_baseline"}.
 The reference publishes no absolute numbers (BASELINE.md), so vs_baseline
 is measured MFU against the north-star 45% MFU target from BASELINE.json.
 
-Runs the fused TrainStep (fwd+bwd+AdamW in one XLA executable) on a Llama
-model in bf16; model size adapts to the backend (sub-1B on a single TPU
-chip, tiny on CPU so the script stays runnable everywhere).
+Runs the fused TrainStep (fwd+bwd+AdamW in one XLA executable) on a ~1B
+Llama in bf16 on one TPU chip. Every line names the platform, device kind
+and device count it ran on. With no TPU it exits non-zero; only
+PT_BENCH_SMOKE (set by tools/bench_smoke.py) asks for the tiny CPU walk,
+whose lines say `cpu` and carry no MFU.
 """
 from __future__ import annotations
 
 import json
+import os
+import shutil
+import sys
 import time
 
 import numpy as np
@@ -28,7 +33,21 @@ def main():
     from paddle_tpu.models import (LlamaConfig, LlamaForCausalLM,
                                    LlamaPretrainingCriterion)
 
-    on_tpu = jax.default_backend() == "tpu"
+    dev = jax.devices()[0]
+    on_tpu = dev.platform == "tpu"
+    if not on_tpu and not os.environ.get("PT_BENCH_SMOKE"):
+        sys.exit(f"bench.py measures a TPU; JAX found {dev.platform!r} "
+                 "(tools/bench_smoke.py walks the tiny CPU config)")
+    peak = peak_flops(dev)
+    if on_tpu and peak is None:
+        sys.exit(f"no peak FLOP/s known for device kind "
+                 f"{dev.device_kind!r}: add it to PEAK_FLOPS in "
+                 "paddle_tpu/observability/hardware.py")
+    device = {"platform": dev.platform, "device_kind": dev.device_kind,
+              "device_count": len(jax.devices())}
+    from paddle_tpu.distributed.resilience import compile_cache
+    cache_root = compile_cache.enable_jax_cache()
+
     if on_tpu:
         # ~1B-param Llama sized for one v5e chip: wide (4096) rather than
         # deep — 4096-wide bf16 matmuls reach ~72% of MXU peak on v5e vs
@@ -71,17 +90,18 @@ def main():
     # warmup (compile) + sync
     loss = step((ids,), (labels,))
     loss = step((ids,), (labels,))
-    _ = float(loss)
+    loss._data.block_until_ready()
 
     t0 = time.perf_counter()
     for _ in range(iters):
         loss = step((ids,), (labels,))
-    _ = float(loss)  # block on the device
+    loss._data.block_until_ready()
     dt = time.perf_counter() - t0
 
     tokens_per_sec = batch * seq * iters / dt
     flops = model_flops_per_token(cfg, seq, n_params) * tokens_per_sec
-    mfu = flops / peak_flops(jax.devices()[0]) * 100.0
+    # no peak (the CPU walk) means no MFU, never another chip's
+    mfu = None if peak is None else flops / peak * 100.0
     assert np.isfinite(float(loss)), "non-finite loss in benchmark"
 
     # telemetry segment AFTER the headline timing loop: the telemetry path
@@ -90,20 +110,18 @@ def main():
     # steps yield the compile split, per-step wall, and cost_analysis MFU
     # for the artifact; the registry dump rides along as its own line.
     # resilience surfaces (ISSUE 11) ride the instrumented segment: the
-    # persistent AOT compile cache is pointed at a throwaway dir (the
-    # telemetry-path compile goes through it — hits+misses must be
-    # live), and ONE bounded async checkpoint measures its critical-path
-    # exposure (the snapshot+gather wall the attribution ledger bills to
-    # `checkpoint`; the write itself is off-path, so this should be ~0)
-    import os
-    import tempfile
+    # persistent AOT compile cache lives in a fixed subdirectory of the
+    # one compile-cache root (the telemetry-path compile goes through it
+    # — hits+misses must be live; a second run hits), and ONE bounded
+    # async checkpoint measures its critical-path exposure (the
+    # snapshot+gather wall the attribution ledger bills to `checkpoint`;
+    # the write itself is off-path, so this should be ~0)
     from paddle_tpu.framework.flags import set_flags
-    from paddle_tpu.distributed.resilience import compile_cache
     from paddle_tpu.distributed.checkpoint import (save_state_dict,
                                                    wait_async_save)
-    resil_dir = tempfile.mkdtemp(prefix="ptcc_bench_")
+    ckpt_dir = os.path.join(cache_root, "bench_ckpt")
     compile_cache.reset_stats()
-    set_flags({"compile_cache_dir": os.path.join(resil_dir, "cache")})
+    set_flags({"compile_cache_dir": os.path.join(cache_root, "aot")})
     ckpt_sd, budget = {}, 16 << 20   # bounded state subset (~16 MB)
     for k, p in model.named_parameters():
         nbytes = int(np.prod(p.shape)) * 2
@@ -119,18 +137,16 @@ def main():
             loss = step((ids,), (labels,))
             if it == 1:
                 t0c = time.perf_counter()
-                save_state_dict(ckpt_sd, os.path.join(resil_dir, "ckpt"),
-                                async_save=True)
+                save_state_dict(ckpt_sd, ckpt_dir, async_save=True)
                 ckpt_exposed = time.perf_counter() - t0c
-        _ = float(loss)
+        loss._data.block_until_ready()
         wait_async_save()
         obs.disable()
     finally:
-        # exception-safe: the throwaway cache dir must never outlive
-        # the run (serialized executables add up) nor stay configured
+        # exception-safe: the AOT cache must not stay configured, and
+        # the throwaway checkpoint must not outlive the run
         set_flags({"compile_cache_dir": ""})
-        import shutil
-        shutil.rmtree(resil_dir, ignore_errors=True)
+        shutil.rmtree(ckpt_dir, ignore_errors=True)
     cc_stats = compile_cache.stats()
     tel = obs.dump()
     exec_hist = tel.get("paddle_tpu_train_step_duration_seconds",
@@ -158,7 +174,7 @@ def main():
     # the int8/fp8 MXU rate, gated present by tools/bench_smoke.py
     from paddle_tpu.kernels.pallas.quant_matmul import active_matmul_dtype
     print(json.dumps({
-        "metric": "train_step_telemetry",
+        "metric": "train_step_telemetry", **device,
         "recompiles": step.recompile_count,
         "matmul_dtype": active_matmul_dtype(default=cfg.dtype),
         "peak_hbm_bytes": {label: ex["peak_bytes"]
@@ -175,9 +191,10 @@ def main():
                           "misses": cc_stats["misses"]},
         "checkpoint_async_exposed_s": round(ckpt_exposed, 6),
         "roofline": roof["executables"],
-        "mfu_gauge_percent": round(tel.get(
+        # absent (null) for a device without a known peak
+        "mfu_gauge_percent": tel.get(
             "paddle_tpu_train_step_mfu_percent",
-            {}).get("values", {}).get("", 0.0), 2),
+            {}).get("values", {}).get(""),
         "cost_analysis_flops_per_step": tel.get(
             "paddle_tpu_train_step_flops_per_step",
             {}).get("values", {}).get("", 0.0),
@@ -192,8 +209,10 @@ def main():
         "metric": "llama_train_tokens_per_sec_per_chip",
         "value": round(tokens_per_sec, 1),
         "unit": f"tokens/s ({n_params/1e6:.0f}M params, bs={batch}, "
-                f"seq={seq}, MFU={mfu:.1f}%)",
-        "vs_baseline": round(mfu / 45.0, 3),
+                f"seq={seq}, MFU="
+                + ("not measured" if mfu is None else f"{mfu:.1f}%") + ")",
+        "vs_baseline": None if mfu is None else round(mfu / 45.0, 3),
+        **device,
     }))
 
 

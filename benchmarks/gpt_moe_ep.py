@@ -152,7 +152,10 @@ def main(batch=8, seq=1024, iters=10, mode="capacity"):
         times.append((time.perf_counter() - t0) / iters)
     step_s = sorted(times)[len(times) // 2]       # median beats CPU noise
     tps = round(batch * seq / step_s, 1)
-    mfu = flops_per_tok * tps / peak_flops(jax.devices()[0]) * 100.0
+    # no MFU for a device whose peak is not known (the CPU smoke walk)
+    peak = peak_flops(jax.devices()[0])
+    mfu = "not measured" if peak is None \
+        else f"{flops_per_tok * tps / peak * 100.0:.1f}%"
 
     # routing probe (eager, observability on): drop fraction + the
     # paddle_tpu_moe_* counters — traced steps have no concrete routing,
@@ -194,7 +197,7 @@ def main(batch=8, seq=1024, iters=10, mode="capacity"):
                       "value": tps,
                       "unit": f"tokens/s ({n_params/1e6:.0f}M params, "
                               f"{n_active/1e6:.0f}M activated, "
-                              f"MFU={mfu:.1f}% of activated flops, "
+                              f"MFU={mfu} of activated flops, "
                               + ("dense 4h FFN)" if dense else
                                  f"{experts} experts top-2 {mode} "
                                  "+ ZeRO-2)")}))

@@ -102,7 +102,9 @@ def main(config="mp8", first=True):
 
     tokens_per_sec = batch * seq * iters / dt
     flops = model_flops_per_token(cfg, seq, n_params) * tokens_per_sec
-    mfu = flops / peak_flops(jax.devices()[0]) * 100.0
+    # no MFU for a device whose peak is not known (the CPU smoke walk)
+    peak = peak_flops(jax.devices()[0])
+    mfu = None if peak is None else flops / peak * 100.0
     assert np.isfinite(float(loss))
 
     hbm_gb = None
@@ -116,9 +118,10 @@ def main(config="mp8", first=True):
         "metric": f"llama_7b_{config}_shard_tokens_per_sec_per_chip",
         "value": round(tokens_per_sec, 1),
         "unit": f"tokens/s ({n_params / 1e6:.0f}M params/chip, "
-                f"bs={batch}, seq={seq}, MFU={mfu:.1f}%, "
-                f"peak HBM={hbm_gb} GiB)",
-        "vs_baseline": round(mfu / 45.0, 3),
+                f"bs={batch}, seq={seq}, MFU="
+                + ("not measured" if mfu is None else f"{mfu:.1f}%")
+                + f", peak HBM={hbm_gb} GiB)",
+        "vs_baseline": None if mfu is None else round(mfu / 45.0, 3),
     }))
 
     # -- grad-sync A/B: once per invocation, dp mesh permitting (the

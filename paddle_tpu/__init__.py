@@ -20,12 +20,6 @@ _jax.config.update("jax_default_matmul_precision", "highest")
 # downcasts f64 input), so no f64 compute sneaks onto the TPU.
 _jax.config.update("jax_enable_x64", True)
 
-# older jax runtimes (0.4.x) lack jax.shard_map / check_vma: install the
-# adapter so the whole stack can use the one modern spelling
-from .framework.jax_compat import ensure_jax_compat as _ejc
-_ejc()
-del _ejc
-
 # framework core -------------------------------------------------------------
 from .framework.dtype import (  # noqa: F401
     DType, dtype as _dtype_fn, convert_dtype,

@@ -65,16 +65,12 @@ def init_distributed_runtime():
     the kill-and-resume drill's run-2 is exactly this path."""
     env = ParallelEnv()
     if env.world_size > 1 and env._coordinator and not _initialized[0]:
-        try:
-            # CPU cross-process computations need the gloo collectives
-            # client (jax >= 0.4.3x refuses them on the default CPU
-            # backend: "Multiprocess computations aren't implemented");
-            # must be set BEFORE jax.distributed.initialize. Harmless
-            # for TPU pods — the knob only shapes the host CPU client.
-            jax.config.update("jax_cpu_collectives_implementation",
-                              "gloo")
-        except Exception:
-            pass                     # older jax: knob absent, path works
+        # CPU cross-process computations need the gloo collectives
+        # client (the default CPU backend refuses them: "Multiprocess
+        # computations aren't implemented"); must be set BEFORE
+        # jax.distributed.initialize. Harmless for TPU pods — the knob
+        # only shapes the host CPU client.
+        jax.config.update("jax_cpu_collectives_implementation", "gloo")
         from ..utils.retry import bounded_retry
 
         def _connect():

@@ -418,7 +418,7 @@ def tagged_mlp_step(sched, layer_names, mesh, lr=0.01):
     tools/overlap_evidence --mode gradsync (schedule analysis) compile,
     so the autotuner times exactly the lowering the evidence tool
     measures. Takes ({name: [h,h] array}, x sharded over sched.axis)."""
-    from jax import shard_map  # the jax_compat adapter's surface
+    from jax import shard_map
 
     def step(ws, xs):
         def loss(ws):

@@ -52,13 +52,15 @@ from __future__ import annotations
 import hashlib
 import logging
 import os
+import pathlib
 import pickle
 import threading
 
 from ...framework.flags import define_flag, flag
 
 __all__ = ["enabled", "cache_dir", "cache_key", "load", "store",
-           "get_or_compile", "stats", "reset_stats"]
+           "get_or_compile", "stats", "reset_stats", "cache_root",
+           "enable_jax_cache"]
 
 define_flag("compile_cache_dir", "",
             "directory for the persistent AOT executable cache "
@@ -72,7 +74,7 @@ define_flag("compile_cache_multiprocess", False,
 
 logger = logging.getLogger("paddle_tpu.resilience")
 
-_MAGIC = b"ptcc/1\n"
+_MAGIC = b"ptcc/2\n"
 
 # process-local stats, maintained even with telemetry off: the drill's
 # restarted (cold) process proves its hits through this surface
@@ -112,6 +114,26 @@ def _count(what, n=1, nbytes=None):
                         nbytes)
     except Exception:
         pass
+
+
+def cache_root():
+    """The one directory compiled programs are kept in: where
+    JAX_COMPILATION_CACHE_DIR says, else `.jax_cache` beside the package
+    (the checkout's root; git-ignored). The path is part of JAX's cache
+    key, so it never comes from a temporary name, a pid or the time."""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or str(
+        pathlib.Path(__file__).resolve().parents[3] / ".jax_cache")
+
+
+def enable_jax_cache():
+    """Turn JAX's persistent compilation cache on at `cache_root()`;
+    call before the first compile. With JAX_COMPILATION_CACHE_DIR set
+    JAX has already read it, and no directory is set in code."""
+    root = cache_root()
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        import jax
+        jax.config.update("jax_compilation_cache_dir", root)
+    return root
 
 
 def cache_dir():
@@ -194,9 +216,15 @@ def load(key):
         digest, blob = body[:64], body[64:]
         if hashlib.sha256(blob).hexdigest().encode() != digest:
             raise ValueError("payload checksum mismatch")
-        payload, in_tree, out_tree = pickle.loads(blob)
+        payload, in_tree, out_tree, device_ids = pickle.loads(blob)
+        import jax
         from jax.experimental import serialize_executable as _se
-        compiled = _se.deserialize_and_load(payload, in_tree, out_tree)
+        # reload onto the devices it was compiled for, in their order:
+        # left to itself the loader spreads it over every device
+        by_id = {d.id: d for d in jax.devices()}
+        compiled = _se.deserialize_and_load(
+            payload, in_tree, out_tree,
+            execution_devices=[by_id[i] for i in device_ids])
     except Exception as e:
         logger.warning("compile cache entry %s corrupt (%s): recompiling",
                        os.path.basename(path), e)
@@ -220,7 +248,10 @@ def store(key, compiled):
     try:
         from jax.experimental import serialize_executable as _se
         payload, in_tree, out_tree = _se.serialize(compiled)
-        blob = pickle.dumps((payload, in_tree, out_tree), protocol=4)
+        device_ids = [d.id for d in
+                      compiled.runtime_executable().local_devices()]
+        blob = pickle.dumps((payload, in_tree, out_tree, device_ids),
+                            protocol=4)
         body = (_MAGIC + hashlib.sha256(blob).hexdigest().encode()
                 + blob)
         d = cache_dir()

@@ -574,21 +574,24 @@ class TrainStep:
                 tokens = 1
         tps = tokens / dt if dt > 0 else 0.0
         flops = self._flops_by_sig.get(sig, 0.0)
-        mfu = 0.0
-        if flops and dt > 0:
-            mfu = flops / dt / _obs.peak_flops(jax.devices()[0]) * 100.0
         reg.counter("paddle_tpu_train_step_tokens_total",
                     "Tokens processed by TrainStep").inc(tokens)
         reg.gauge("paddle_tpu_train_step_tokens_per_second",
                   "Last-step TrainStep throughput").set(tps)
-        reg.gauge("paddle_tpu_train_step_mfu_percent",
-                  "Last-step model FLOPs utilization "
-                  "(cost_analysis FLOPs / peak)").set(mfu)
-        _obs.log_step({"event": "train_step",
-                       "step": int(self.opt._step_count),
-                       "wall_s": dt, "tokens_per_s": tps,
-                       "mfu_percent": mfu,
-                       "recompiles": self.recompile_count})
+        record = {"event": "train_step",
+                  "step": int(self.opt._step_count),
+                  "wall_s": dt, "tokens_per_s": tps,
+                  "recompiles": self.recompile_count}
+        # a device whose peak is not known has no MFU: the gauge and the
+        # field are absent rather than priced at some other chip's peak
+        peak = _obs.peak_flops(jax.devices()[0])
+        if peak is not None:
+            mfu = flops / dt / peak * 100.0 if flops and dt > 0 else 0.0
+            reg.gauge("paddle_tpu_train_step_mfu_percent",
+                      "Last-step model FLOPs utilization "
+                      "(cost_analysis FLOPs / peak)").set(mfu)
+            record["mfu_percent"] = mfu
+        _obs.log_step(record)
         return out
 
     def __call__(self, inputs, labels=()):

@@ -45,10 +45,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 from ._x64 import i32_trace
 from .grouped_matmul import (DEFAULT_BM, _interpret, _pick_tile,

@@ -25,9 +25,20 @@ def _interpret():
     return jax.default_backend() != "tpu"
 
 
-def _row_block(n_rows):
+# Mosaic gives one kernel 16 MiB of scoped VMEM on the v5e (the limit its
+# compiler names when it refuses). The double-buffered row blocks may take
+# half; the kernel's f32 temporaries need the rest.
+_VMEM_BLOCK_BUDGET = 8 << 20
+
+
+def _row_block(n_rows, width, itemsize):
+    """Rows per grid step: the largest power of two <= 256 that divides
+    n_rows and keeps the backward's three [rows, width] blocks (x, g, dx),
+    each double buffered, inside the block budget. Forward and backward
+    share it."""
+    fit = max(_VMEM_BLOCK_BUDGET // (6 * width * itemsize), 1)
     for b in (256, 128, 64, 32, 16, 8, 4, 2, 1):
-        if n_rows % b == 0:
+        if b <= fit and n_rows % b == 0:
             return b
     return 1
 
@@ -55,7 +66,7 @@ def _bwd_kernel(x_ref, w_ref, rstd_ref, g_ref, dx_ref, *, eps):
 @i32_trace
 def _rms_fwd(x2d, w, eps):
     n, h = x2d.shape
-    br = _row_block(n)
+    br = _row_block(n, h, x2d.dtype.itemsize)
     out, rstd = pl.pallas_call(
         functools.partial(_fwd_kernel, eps=eps),
         grid=(n // br,),
@@ -79,7 +90,7 @@ def _rms_fwd(x2d, w, eps):
 @i32_trace
 def _rms_bwd(x2d, w, rstd, g2d, eps):
     n, h = x2d.shape
-    br = _row_block(n)
+    br = _row_block(n, h, x2d.dtype.itemsize)
     nb = n // br
     dx = pl.pallas_call(
         functools.partial(_bwd_kernel, eps=eps),

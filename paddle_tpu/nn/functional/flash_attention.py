@@ -51,18 +51,14 @@ def _sdpa_mask_xla(q, k, v, mask, *, scale):
 
 
 def _use_pallas(q):
-    try:
-        import jax
-        if jax.default_backend() != "tpu":
-            return False
-        from ...kernels.pallas import flash_attention as fa  # noqa: F401
-        d = q.shape[-1]
-        s = q.shape[1]
-        # kernel blocks are 128-wide: seq must divide evenly or rows of the
-        # output block would be undefined
-        return d in (64, 128, 256) and s >= 128 and s % 128 == 0
-    except Exception:
-        return False
+    """The Pallas kernel serves these shapes on a TPU. Whether it imports
+    and lowers is not asked here: on a TPU that failure raises at the call
+    rather than giving way to the O(s^2) reference in silence."""
+    d, s = q.shape[-1], q.shape[1]
+    # kernel blocks are 128-wide: seq must divide evenly or rows of the
+    # output block would be undefined
+    return (_use_pallas_backend() and d in (64, 128, 256)
+            and s >= 128 and s % 128 == 0)
 
 
 def flash_attention(query, key, value, dropout=0.0, causal=False,
@@ -148,11 +144,7 @@ def flash_attn_unpadded(query, key, value, cu_seqlens_q, cu_seqlens_k,
 
 
 def _use_pallas_backend():
-    try:
-        import jax as _j
-        return _j.default_backend() == "tpu"
-    except Exception:
-        return False
+    return jax.default_backend() == "tpu"
 
 
 @primitive("flash_varlen_pallas")

@@ -11,13 +11,14 @@ PEAK_FLOPS = {
 }
 
 
-def peak_flops(device) -> float:
-    """Peak bf16 FLOPs/s for a jax device; assumes v5e when unknown."""
+def peak_flops(device) -> float | None:
+    """Peak bf16 FLOPs/s for a jax device, or None for a device the table
+    does not know: no peak means no MFU, never another chip's."""
     kind = getattr(device, "device_kind", "").lower()
     for key, val in PEAK_FLOPS.items():
         if key in kind:
             return val
-    return 197e12
+    return None
 
 
 def model_flops_per_token(cfg, seq_len: int, n_params: int) -> float:

@@ -20,7 +20,7 @@ import re
 
 __all__ = ["parse_hlo_computations", "matmuls_reachable",
            "ring_body_matmul_counts", "collective_overlap_report",
-           "grad_sync_overlap_report",
+           "grad_sync_overlap_report", "collective_independent_matmuls",
            "estimate_collective_seconds", "computation_weights",
            "scope_of_op_name", "entry_io_bytes", "live_range_report",
            "roofline_report", "ROOFLINE_CLASSES", "DEFAULT_ROOFLINE_RATES"]
@@ -188,7 +188,72 @@ def _split_computations(text):
             continue
         if cur is not None and "=" in line:
             lines_by_comp[cur].append(line)
-    return lines_by_comp
+    return {comp: _with_operand_shapes(lines)
+            for comp, lines in lines_by_comp.items()}
+
+
+_OPERAND_NAME = re.compile(r"%([\w.\-]+)")
+# an operand printed bare: its name follows the opening paren or a comma
+# directly (a typed one follows its type's closing bracket)
+_BARE_OPERAND = re.compile(r"(^|[(,])(\s*)%([\w.\-]+)")
+
+
+def _op_spans(rhs):
+    """(op, end of the output-shape region, start, end of the operand
+    region inside the op's parens) for the text right of ' = '; None when
+    no op token is found."""
+    m_op = _OP_NAME.search(rhs)
+    if not m_op:
+        return None
+    close = _matching_paren(rhs, m_op.end() - 1)
+    return (m_op.group(1), m_op.start(), m_op.end(),
+            close if close > 0 else len(rhs))
+
+
+def _with_operand_shapes(lines):
+    """One computation's lines with every operand's type written before
+    its name, as older XLA printed them (`dot(f32[4,8]{1,0} %a, ...)`).
+    This XLA prints operands bare (`dot(%a, %b)`), and the readers below
+    take operand bytes, contracting sizes and quantized dtypes from the
+    operand region. Types come from the defining lines of the same
+    computation; operands that already carry one stay as they are."""
+    types, parsed = {}, []
+    for line in lines:
+        lhs, sep, rhs = line.partition(" = ")
+        spans = _op_spans(rhs) if sep else None
+        nm = _INSTR_NAME.match(line)
+        if nm and spans:
+            types[nm.group(1)] = rhs[:spans[1]].strip()
+        parsed.append((lhs + sep, rhs, spans))
+
+    def typed(m):
+        t = types.get(m.group(3))
+        return f"{m.group(1)}{m.group(2)}{t} %{m.group(3)}" if t \
+            else m.group(0)
+
+    out = []
+    for line, (lhs, rhs, spans) in zip(lines, parsed):
+        if spans is None:
+            out.append(line)
+            continue
+        _, _, start, end = spans
+        out.append(lhs + rhs[:start]
+                   + _BARE_OPERAND.sub(typed, rhs[start:end]) + rhs[end:])
+    return out
+
+
+def _collective_kind(line):
+    """The collective this instruction line starts (its `-start` half
+    included), or None — also for the `-done` half."""
+    kind = next((k for k in _COLLECTIVE_KINDS
+                 if re.search(rf"\b{k}(?:-start)?\(", line)), None)
+    return None if kind is None or f"{kind}-done(" in line else kind
+
+
+def _matmul_work(line, reach):
+    """Matmul-class ops on this line plus everything it calls."""
+    return (1 if _MATMUL.search(line) else 0) + sum(
+        reach.get(cm.group(1), 0) for cm in _CALL_EDGE.finditer(line))
 
 
 def collective_overlap_report(text):
@@ -298,14 +363,10 @@ def grad_sync_overlap_report(text):
         # quadratic in collectives x lines)
         after = [0] * (len(lines) + 1)
         for j in range(len(lines) - 1, -1, -1):
-            w = 1 if _MATMUL.search(lines[j]) else 0
-            for cm in _CALL_EDGE.finditer(lines[j]):
-                w += reach.get(cm.group(1), 0)
-            after[j] = after[j + 1] + w
+            after[j] = after[j + 1] + _matmul_work(lines[j], reach)
         for i, line in enumerate(lines):
-            kind = next((k for k in _COLLECTIVE_KINDS
-                         if re.search(rf"\b{k}(?:-start)?\(", line)), None)
-            if kind is None or f"{kind}-done(" in line:
+            kind = _collective_kind(line)
+            if kind is None:
                 continue
             nm = _INSTR_NAME.match(line)
             if not nm:
@@ -318,6 +379,51 @@ def grad_sync_overlap_report(text):
                 "matmuls_after": after[i + 1],
             })
     return report
+
+
+def collective_independent_matmuls(text):
+    """{(computation, collective): matmul-class work of that computation
+    that neither feeds the collective nor needs its result}.
+
+    The dependence structure itself, where grad_sync_overlap_report reads
+    it off the schedule: work that is independent of a collective is what
+    a backend with asynchronous collectives can run while it is on the
+    wire, wherever this backend's scheduler happened to put the
+    collective. A monolithic tail sync depends on every gradient matmul
+    and reports 0. Quadratic in a computation's size: for tests and
+    tools, not for the per-compile telemetry path."""
+    comps = parse_hlo_computations(text)
+    reach = {name: matmuls_reachable(comps, name) for name in comps}
+    out = {}
+    for comp, lines in _split_computations(text).items():
+        index = {nm.group(1): i for i, line in enumerate(lines)
+                 if (nm := _INSTR_NAME.match(line))}
+        work = [_matmul_work(line, reach) for line in lines]
+        feeds = [set() for _ in lines]          # i -> lines it reads
+        users = [set() for _ in lines]
+        for i, line in enumerate(lines):
+            for m in _OPERAND_NAME.finditer(line.partition(" = ")[2]):
+                j = index.get(m.group(1))
+                if j is not None and j != i:
+                    feeds[i].add(j)
+                    users[j].add(i)
+
+        def closure(start, edges):
+            seen, todo = set(), [start]
+            while todo:
+                for j in edges[todo.pop()]:
+                    if j not in seen:
+                        seen.add(j)
+                        todo.append(j)
+            return seen
+
+        for name, i in index.items():
+            if _collective_kind(lines[i]) is None:
+                continue
+            tied = closure(i, feeds) | closure(i, users) | {i}
+            out[(comp, name)] = sum(w for j, w in enumerate(work)
+                                    if j not in tied)
+    return out
 
 
 _WHILE_EDGE = re.compile(
@@ -822,14 +928,11 @@ def _split_op_regions(line):
     output-shape region before it, and the operand region inside its
     parens (operand shapes are printed inline post-optimization)."""
     rhs = line.split(" = ", 1)[1] if " = " in line else ""
-    m_op = _OP_NAME.search(rhs)
-    if not m_op:
+    spans = _op_spans(rhs)
+    if spans is None:
         return "?", rhs, ""
-    op = m_op.group(1)
-    head = rhs[:m_op.start()]
-    close = _matching_paren(rhs, m_op.end() - 1)
-    opargs = rhs[m_op.end():close] if close > 0 else rhs[m_op.end():]
-    return op, head, opargs
+    op, head_end, start, end = spans
+    return op, rhs[:head_end], rhs[start:end]
 
 
 def _reach_flops(comps, lines_by_comp, name, memo, _stack=None):

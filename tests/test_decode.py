@@ -192,8 +192,11 @@ def test_int8_weight_only_lane():
 
 
 def test_int8_blockwise_weight_lane():
-    """Per-block int8 weights (ISSUE 17 quant_matmul path): logits
-    track dense closely AND greedy decoding is token-identical."""
+    """Per-block int8 weights (ISSUE 17 quant_matmul path): logits track
+    dense closely, and greedy decoding gives the dense tokens up to the
+    first near-tie — a step where the dense model's own logits put the
+    two tokens closer than the codec moves a logit. (Exact tokens of a
+    random model hang on such ties.)"""
     model = _tiny()
     model.eval()
     decq = CachedDecoder(model, max_len=64,
@@ -209,11 +212,27 @@ def test_int8_blockwise_weight_lane():
     q = np.asarray(q, np.float32)
     cos = (ref * q).sum() / (np.linalg.norm(ref) * np.linalg.norm(q))
     assert cos > 0.999, cos
-    out_q = decq.generate(ids, max_new_tokens=8)
-    out_d = dec.generate(ids, max_new_tokens=8)
-    assert np.isfinite(out_q.numpy()).all()
-    # greedy parity: block-scaled int8 must not flip a single token
-    np.testing.assert_array_equal(out_q.numpy(), out_d.numpy())
+    # what the codec moves a logit by, measured where both ran the same
+    # context; either side of a comparison may move by it
+    band = 2 * float(np.abs(ref - q).max())
+    assert band < 0.1 * float(ref.max() - ref.mean()), band
+    out_q = decq.generate(ids, max_new_tokens=8).numpy()
+    out_d = dec.generate(ids, max_new_tokens=8).numpy()
+    assert np.isfinite(out_q).all()
+    compared = 0
+    for row_q, row_d in zip(out_q, out_d):
+        differ = np.nonzero(row_q != row_d)[0]
+        if differ.size == 0:
+            compared += len(row_d) - ids.shape[1]
+            continue
+        i = int(differ[0])           # same context up to here
+        compared += i - ids.shape[1]
+        with pt.no_grad():
+            logits = model(pt.to_tensor(row_d[None, :i])).numpy()[0, -1]
+        assert logits[row_d[i]] - logits[row_q[i]] <= band, (
+            i, row_d[i], row_q[i], logits[row_d[i]] - logits[row_q[i]],
+            band)
+    assert compared >= 8         # not every row may hang on a first tie
 
 
 def test_rejects_pipelined_model():

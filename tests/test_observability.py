@@ -134,7 +134,10 @@ class TestTrainStepTelemetry:
         assert reg.counter(
             "paddle_tpu_train_step_recompiles_total").value() == 1
 
-    def test_step_metrics_and_mfu_gauges(self, telemetry):
+    def test_step_metrics_and_mfu_gauges(self, telemetry, monkeypatch):
+        # the CPU is in no peak table: give it a peak to read an MFU
+        from paddle_tpu.observability import hardware
+        monkeypatch.setitem(hardware.PEAK_FLOPS, "cpu", 1e12)
         step = _tiny_step()
         for _ in range(3):
             step(*_batch(8))
@@ -153,6 +156,24 @@ class TestTrainStepTelemetry:
         # cost_analysis FLOPs feed the MFU gauge (may be 0 on backends
         # that report no flops, but the gauge must exist)
         assert reg.get("paddle_tpu_train_step_mfu_percent") is not None
+
+    def test_no_mfu_for_a_device_without_a_known_peak(self, telemetry,
+                                                      tmp_path):
+        """The CPU's peak is not known, so no MFU is reported for it —
+        neither the gauge nor the step log's field — rather than one
+        priced at some chip's peak."""
+        import jax
+        assert obs.peak_flops(jax.devices()[0]) is None
+        path = str(tmp_path / "steps.jsonl")
+        obs.set_jsonl_path(path)
+        step = _tiny_step()
+        step(*_batch(4))
+        obs.set_jsonl_path(None)
+        assert obs.registry().get(
+            "paddle_tpu_train_step_mfu_percent") is None
+        steps = [json.loads(l) for l in open(path)]
+        steps = [r for r in steps if r.get("event") == "train_step"]
+        assert steps and all("mfu_percent" not in r for r in steps)
 
     def test_telemetry_path_matches_disabled_path(self, telemetry):
         """The AOT telemetry path must be numerically identical to the

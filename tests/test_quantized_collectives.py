@@ -11,7 +11,7 @@ int8-compressed training run against fp32.
 import numpy as np
 import pytest
 
-import paddle_tpu as pt  # noqa: F401  (installs the jax-0.4.x shims first)
+import paddle_tpu as pt  # noqa: F401
 import jax
 import jax.numpy as jnp
 from jax import shard_map
@@ -393,11 +393,18 @@ def test_shardmap_bucket_sync_physical_int8(dp_mesh):
 
 
 def test_grad_sync_overlap_report_on_buckets(dp_mesh):
-    """Schedule-position evidence (the --mode gradsync analyzer's
-    machinery): bucketing ON leaves matmul-class backward work scheduled
-    after the early buckets' collectives; OFF (one bucket) is a single
-    tail collective with none."""
-    from paddle_tpu.utils.hlo_analysis import grad_sync_overlap_report
+    """Bucketing ON leaves matmul-class backward work that can run while
+    the early buckets' collectives are on the wire; OFF (one bucket) is a
+    single tail collective with none (the --mode gradsync analyzer's
+    machinery).
+
+    The CPU backend of this XLA merges independent all-reduces into one
+    variadic tail all-reduce (`cpu-all-reduce-combiner`) and sinks what
+    is left next to its consumer, so the buckets are read with that pass
+    off and from the dependence structure, not from where this CPU
+    scheduler put them."""
+    from paddle_tpu.utils.hlo_analysis import (
+        collective_independent_matmuls, grad_sync_overlap_report)
     layers = 4
     rng = np.random.default_rng(6)
     ws = {f"w{i}": jnp.asarray(rng.standard_normal((128, 128)) * 0.1,
@@ -423,16 +430,22 @@ def test_grad_sync_overlap_report_on_buckets(dp_mesh):
         f = jax.jit(shard_map(step, mesh=dp_mesh,
                               in_specs=(P(), P("dp")), out_specs=P(),
                               check_vma=False))
-        return [r for r in grad_sync_overlap_report(
-                    f.lower(ws, x).compile().runtime_executable()
-                    .hlo_modules()[0].to_string())
+        text = f.lower(ws, x).compile(compiler_options={
+            "xla_disable_hlo_passes": "cpu-all-reduce-combiner"}) \
+            .runtime_executable().hlo_modules()[0].to_string()
+        free = collective_independent_matmuls(text)
+        return [dict(r, matmuls_free=free[(r["computation"], r["name"])])
+                for r in grad_sync_overlap_report(text)
                 if r["kind"] == "all-reduce"]
 
     off = compiled(1e9)
     on = compiled(128 * 128 * 4 / 2**20)  # one bucket per layer
     assert len(off) == 1 and off[0]["matmuls_after"] == 0
+    assert off[0]["matmuls_free"] == 0
     assert len(on) == layers
-    assert sum(1 for r in on if r["matmuls_after"] >= 1) >= layers - 1
+    assert sum(1 for r in on if r["matmuls_free"] >= 1) >= layers - 1
+    # the earliest bucket still has work scheduled behind it on this CPU
+    assert max(r["matmuls_after"] for r in on) >= 1
 
 
 # -- end-to-end: 2-step training grad parity ---------------------------------

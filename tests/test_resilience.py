@@ -71,6 +71,27 @@ class TestCompileCache:
         assert st["hits"] == 1 and st["misses"] == 1 and st["stores"] == 1
         assert st["bytes_written"] > 0 and st["bytes_read"] > 0
 
+    @pytest.mark.parametrize("n_devices", [2, 8])
+    def test_sharded_executable_reloads_on_its_own_devices(self, cache_dir,
+                                                           n_devices):
+        """An executable compiled for a sub-mesh reloads onto those
+        devices, in their order — not onto every device of the backend."""
+        from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+        devs = jax.devices()[::-1][:n_devices]      # not the default order
+        sh = NamedSharding(Mesh(np.array(devs), ("x",)), P("x"))
+        x = jax.device_put(jnp.arange(16.0).reshape(8, 2), sh)
+
+        def lowered():
+            return jax.jit(lambda a: a * 2.0 + 1.0, in_shardings=sh,
+                           out_shardings=sh).lower(x)
+
+        _, i1 = cc.get_or_compile(lowered(), tag="t")
+        c2, i2 = cc.get_or_compile(lowered(), tag="t")
+        assert (i1["cache"], i2["cache"]) == ("miss", "hit")
+        out = c2(x)
+        assert out.sharding.is_equivalent_to(sh, 2)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(x) * 2 + 1)
+
     def test_corrupt_entry_recompiles_never_crashes(self, cache_dir):
         low = jax.jit(lambda x: x * 3.0).lower(jnp.ones((4,)))
         cc.get_or_compile(low, tag="t")
