@@ -1,0 +1,7 @@
+"""device.idle_share.serve (%): 1 - (union of the device-op intervals)
+over the traced window. Layer: device. Source: device trace. Moves
+serve_tokens_per_s."""
+
+
+def read(view):
+    return 100.0 * view.summary.idle_share

@@ -1,0 +1,8 @@
+"""programs.compiles_in_window.train (count): backend compiles (or
+persistent-cache retrievals) that `jax.monitoring` reported inside the
+window; 0 is what a warmed run shows. Layer: programs. Source: program
+counters. Moves train_tokens_per_s."""
+
+
+def read(view):
+    return len(view.meter.between(*view.window))
