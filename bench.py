@@ -191,10 +191,6 @@ def main():
                           "misses": cc_stats["misses"]},
         "checkpoint_async_exposed_s": round(ckpt_exposed, 6),
         "roofline": roof["executables"],
-        # absent (null) for a device without a known peak
-        "mfu_gauge_percent": tel.get(
-            "paddle_tpu_train_step_mfu_percent",
-            {}).get("values", {}).get(""),
         "cost_analysis_flops_per_step": tel.get(
             "paddle_tpu_train_step_flops_per_step",
             {}).get("values", {}).get("", 0.0),

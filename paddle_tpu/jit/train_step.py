@@ -573,30 +573,24 @@ class TrainStep:
             else:
                 tokens = 1
         tps = tokens / dt if dt > 0 else 0.0
-        flops = self._flops_by_sig.get(sig, 0.0)
         reg.counter("paddle_tpu_train_step_tokens_total",
                     "Tokens processed by TrainStep").inc(tokens)
         reg.gauge("paddle_tpu_train_step_tokens_per_second",
                   "Last-step TrainStep throughput").set(tps)
-        record = {"event": "train_step",
-                  "step": int(self.opt._step_count),
-                  "wall_s": dt, "tokens_per_s": tps,
-                  "recompiles": self.recompile_count}
-        # a device whose peak is not known has no MFU: the gauge and the
-        # field are absent rather than priced at some other chip's peak
-        peak = _obs.peak_flops(jax.devices()[0])
-        if peak is not None:
-            mfu = flops / dt / peak * 100.0 if flops and dt > 0 else 0.0
-            reg.gauge("paddle_tpu_train_step_mfu_percent",
-                      "Last-step model FLOPs utilization "
-                      "(cost_analysis FLOPs / peak)").set(mfu)
-            record["mfu_percent"] = mfu
-        _obs.log_step(record)
+        _obs.log_step({"event": "train_step",
+                       "step": int(self.opt._step_count),
+                       "wall_s": dt, "tokens_per_s": tps,
+                       "recompiles": self.recompile_count})
         return out
 
     def __call__(self, inputs, labels=()):
         """One fused step: loss = loss_fn(model(*inputs), *labels).
         `inputs`/`labels` may be a single Tensor or a tuple/list of them."""
+        with _obs.span("train_step:call",
+                       step=int(self.opt._step_count)):
+            return self._call(inputs, labels)
+
+    def _call(self, inputs, labels):
         if isinstance(inputs, Tensor):
             inputs = (inputs,)
         if isinstance(labels, Tensor):

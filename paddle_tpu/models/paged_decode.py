@@ -188,8 +188,9 @@ class PagedDecoder(CachedDecoder):
         self.headroom_guard = headroom_guard
         self.admission_deferrals = 0
         # per-request lifecycle ledger (observability/requests.py):
-        # created lazily by serve() when telemetry is on; persists across
-        # serve() calls so operators see one continuous request stream
+        # created by the first serve() and fed by every one, telemetry
+        # on or off; persists across serve() calls so operators see one
+        # continuous request stream
         self.request_ledger = None
         # overload-shedding tallies (host-side, always on — cheap dict
         # bumps; the telemetry causes land in the ledger/registry too)
@@ -1244,13 +1245,15 @@ class PagedDecoder(CachedDecoder):
         `compile`, prefill/chunk device time is `execute` (synced for an
         honest wall), the admission/bookkeeping host loop is `dispatch`
         — emitted per iteration to the JSONL sink like TrainStep's.
-        They ALSO thread every request through the per-request lifecycle
-        ledger (`self.request_ledger`, observability/requests.py):
-        arrival/admit/prefill/first-token/chunk/retire timestamps,
-        TTFT/TPOT, the {queue_wait, prefill, decode, overhead} buckets
-        that telescope to the request wall, retire causes, and
-        HeadroomGuard deferral counts — emitted per request to the
-        JSONL sink and the sliding-window SLO quantiles.
+
+        Every run, telemetry on or off, threads every request through
+        the per-request lifecycle ledger (`self.request_ledger`,
+        observability/requests.py): arrival/admit/prefill/first-token/
+        chunk/retire timestamps, TTFT/TPOT, the {queue_wait, prefill,
+        decode, overhead} buckets that telescope to the request wall,
+        retire causes, and HeadroomGuard deferral counts. Telemetry adds
+        the export: each retired request to the JSONL sink and the
+        sliding-window SLO quantiles.
         """
         from ..serving.batcher import serve_loop
         return serve_loop(

@@ -5,7 +5,7 @@ Families (all Prometheus-scrapable via `scrape()`, JSON via `dump()`):
 
 - step:       paddle_tpu_train_step_duration_seconds{phase},
               _compile_seconds, _recompiles_total, _tokens_total,
-              _tokens_per_second, _mfu_percent, _flops_per_step
+              _tokens_per_second, _flops_per_step
               (jit/train_step.py)
 - memory:     paddle_tpu_device_bytes_in_use/_peak_bytes_in_use/_bytes_limit,
               paddle_tpu_memory_guard_checks_total,
@@ -60,8 +60,16 @@ recorder (flight_recorder.py — SIGTERM/watchdog/HeadroomGuard black
 box), and a live Prometheus endpoint (exporter.py, FLAGS_telemetry_port).
 
 Enable with `paddle_tpu.observability.enable()` or FLAGS_enable_telemetry=1;
-per-step JSONL via `set_jsonl_path(path)`; spans via
-`tracing.enable_tracing()` or FLAGS_enable_tracing=1.
+per-step JSONL via `set_jsonl_path(path)`.
+
+Spans need neither: start a JAX profiler trace (`jax.profiler.start_trace`
+.. `stop_trace`) and every `span()` of the serve loop, the train step and
+the collectives lies in the `.xplane.pb`'s host plane beside the device's
+ops, on one clock, and in the ring (`tracing.tail()`); or arm the ring
+alone with `tracing.enable_tracing()` / FLAGS_enable_tracing=1 and export
+it with `tracing.export_chrome(path)`. `tracing.recording()` says whether
+a span opened now would be recorded; with nothing recording a span is a
+shared null object. Neither way changes the program that runs.
 """
 from .registry import (  # noqa: F401
     Counter, Gauge, Histogram, Quantile, MetricsRegistry,
@@ -72,7 +80,8 @@ from .registry import (  # noqa: F401
 from .hardware import PEAK_FLOPS, peak_flops, model_flops_per_token  # noqa: F401
 from . import tasks  # noqa: F401
 from . import tracing  # noqa: F401
-from .tracing import span, enable_tracing, disable_tracing, tracing_enabled  # noqa: F401
+from .tracing import (span, recording, enable_tracing,  # noqa: F401
+                      disable_tracing, tracing_enabled)
 from . import attribution  # noqa: F401
 from . import memory_profile  # noqa: F401
 from . import roofline  # noqa: F401
@@ -86,7 +95,7 @@ __all__ = [
     "registry", "enabled", "enable", "disable", "scrape", "dump", "reset",
     "log_step", "set_jsonl_path", "close_jsonl", "flush_jsonl",
     "PEAK_FLOPS", "peak_flops", "model_flops_per_token", "tasks",
-    "tracing", "span", "enable_tracing", "disable_tracing",
+    "tracing", "span", "recording", "enable_tracing", "disable_tracing",
     "tracing_enabled", "attribution", "memory_profile", "roofline",
     "requests",
     "flight_recorder", "exporter",

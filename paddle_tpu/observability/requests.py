@@ -4,7 +4,9 @@
 PRs 1/7/9 answer "where did the STEP's time/HBM go"; this module answers
 the question at the granularity millions of users experience — a
 request. `PagedDecoder.serve()` threads every request through a
-`RequestLedger`, which records the full lifecycle:
+`RequestLedger` — always, it is the serve loop's own accounting, so
+`summary()` gives queue wait, TTFT and TPOT of the program users run —
+which records the full lifecycle:
 
     arrival -> (guard deferrals) -> admit -> prefill -> first token
             -> decode chunks ... -> retire (cause)
@@ -36,13 +38,17 @@ Gemma-on-TPU serving comparison evaluate in):
   experienced as responsive, the number the continuous-batching
   scheduler (ROADMAP 1) will be gated on.
 
-Emission per retired request (telemetry on):
+Export is what stays conditional. Per retired request, telemetry on:
 
 - one JSONL record (event "request_lifecycle") with timestamps, buckets,
   TTFT/TPOT, cause, and the guard-deferral count;
 - registry counters (admitted/retired{cause}/tokens) and sliding-window
   `Quantile` series (paddle_tpu_request_{ttft,tpot,queue_wait,wall}_
   seconds) so p50/p99 are LIVE scrape()-able operational metrics;
+
+and, while the span tracer records (`tracing.recording()`: its ring
+armed or a JAX profiler session running), telemetry on or off:
+
 - per-request Perfetto tracks: queue/prefill/decode spans recorded into
   the trace ring on a synthetic per-request tid (named "req <rid>" via
   tracing.set_track_name), so one merged trace shows a request's life
@@ -285,7 +291,7 @@ class RequestLedger:
             rec._last_ts = ts
             rec.slot = slot
             rec.blocks = int(blocks)
-        if _tracing.tracing_enabled():
+        if _tracing.recording():
             rec.track_tid = _next_track_tid()
             _tracing.set_track_name(rec.track_tid, f"req {rec.rid}")
             self._track_span(rec, "req:queue", rec.arrival_ts, ts)
@@ -376,7 +382,7 @@ class RequestLedger:
 
     # -- emission ----------------------------------------------------------
     def _track_span(self, rec, name, t0, t1, meta=None):
-        if rec.track_tid is None or not _tracing.tracing_enabled():
+        if rec.track_tid is None or not _tracing.recording():
             return
         m = {"rid": str(rec.rid)}
         if meta:

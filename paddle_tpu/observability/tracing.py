@@ -1,28 +1,39 @@
-"""Span tracer: near-zero-overhead-when-disabled, rank-tagged, ring-buffered.
+"""Span tracer: near-zero-overhead-when-nothing-records, rank-tagged,
+ring-buffered, and on the device trace's clock when a profiler runs.
 
 The third observability layer (metrics -> **traces** -> attribution).
-PR 1's registry answers *how much* (counters/gauges/MFU); this answers
-*where the time went*: every instrumented region — eager collectives,
-grad-sync bucket flushes, mp permute rings, MoE dispatch, train-step
-compile/execute, serve() chunks — opens a `span(name)` that records
-(name, t0, t1, pid, tid, rank, meta) into a bounded ring buffer on a
-monotonic clock.
+PR 1's registry answers *how much*; this answers *where the time went*.
+The instrumented regions are the serve loop's phases (`serve:iteration`,
+`:feed`, `:admit`, `:prefill`, `:wait_first_token`, `:chunk`,
+`:wait_chunk`, `:commit`, ...), the train step (`train_step:call`, with
+`:compile` / `:execute` under telemetry), eager collectives
+(`collective:<op>`), backend compiles (`xla:compile`, from a
+`jax.monitoring` listener) and the per-request tracks the
+`RequestLedger` writes (`req:queue`, `req:prefill`, `req:decode`).
 
-Design contract (mirrors the registry's overhead contract):
+Design contract:
 
-- **Disabled (default)**: `span()` is one module-global bool read and a
-  shared null context — no allocation, no lock, no clock. Gated by the
-  per-call-overhead test in tests/test_tracing_attribution.py.
-- **Enabled**: completed spans land in a `deque(maxlen=capacity)` under
-  one lock; the oldest spans fall off — the ring IS the flight
-  recorder's black-box window (observability/flight_recorder.py reads
-  it at dump time).
-- **Profiler bridge**: a finished span also feeds the legacy
-  profiler._HostEventBuffer when a Profiler is recording, so the
-  existing `Profiler`/`export_chrome_tracing` flow keeps seeing the
-  collective/grad_sync/mp/moe spans it always did. The tracer SUBSUMES
-  those call sites (they now open `tracing.span(...)` instead of bare
-  `profiler.RecordEvent`), it does not replace the profiler.
+- **When a span records**: the ring is armed (`enable_tracing()` /
+  FLAGS_enable_tracing) **or a JAX profiler session is recording**
+  (`jax.profiler.start_trace` .. `stop_trace`, answered by
+  `TraceAnnotation.is_enabled()`). `recording()` is that question.
+- **Nothing records (default)**: `span()` is one bool read, one
+  `is_enabled()` and a shared null context — no allocation, no lock, no
+  clock. Gated by the per-call-overhead test in
+  tests/test_tracing_attribution.py.
+- **Recording**: a span (a) opens a `jax.profiler.TraceAnnotation` for
+  its duration while a profiler session records, so it lies in the
+  `.xplane.pb`'s `/host:CPU` plane on the device trace's own clock,
+  metadata as event stats; (b) lands as `(id, parent, name, t0, t1, tid,
+  rank, meta)` in a `deque(maxlen=capacity)` under one lock on
+  `perf_counter_ns` — the oldest spans fall off, the ring IS the flight
+  recorder's black-box window and what a metric reader in the same
+  process reads after a traced window. `id` is a process-wide counter;
+  `parent` is the id of the span open on the same thread when this one
+  opened, None at the top.
+- **One store**: the legacy `profiler.Profiler` arms and disarms this
+  ring with its RECORD state and exports from `tail()`;
+  `profiler.RecordEvent` lands here through `record_span`.
 
 Multi-process export: perf-counter timestamps are rebased onto the unix
 epoch at enable time, so per-rank part files written by
@@ -34,19 +45,23 @@ file directly in Perfetto / chrome://tracing).
 from __future__ import annotations
 
 import glob
+import itertools
 import json
 import os
 import threading
 import time
 from collections import deque
 
+import jax
+from jax.profiler import TraceAnnotation as _Annotation
+
 from ..framework.flags import define_flag, flag
 
 __all__ = [
-    "span", "record_span", "tracing_enabled", "enable_tracing",
+    "span", "record_span", "recording", "tracing_enabled", "enable_tracing",
     "disable_tracing", "drain", "clear", "tail", "chrome_events",
     "export_chrome", "write_rank_part", "merge_rank_parts", "trace_rank",
-    "set_track_name",
+    "set_track_name", "new_span_id",
 ]
 
 define_flag("enable_tracing", False,
@@ -65,6 +80,13 @@ _RING = deque(maxlen=65536)
 # from different processes share a clock base in merged traces
 _EPOCH_OFFSET_NS = [0]
 _RANK = [None]
+# is a JAX profiler session recording? One atomic read (TraceMe's
+# recorder level), False before start_trace and after stop_trace
+_profiling = _Annotation.is_enabled
+# process-wide span ids (next() on a count is atomic under the GIL) and
+# the per-thread stack of open span ids a new span takes its parent from
+_IDS = itertools.count(1)
+_OPEN = threading.local()
 
 
 def trace_rank() -> int:
@@ -80,7 +102,6 @@ def trace_rank() -> int:
         try:
             from jax._src import distributed as _jax_dist
             if _jax_dist.global_state.client is not None:
-                import jax
                 r = int(jax.process_index())
         except Exception:
             pass
@@ -94,7 +115,15 @@ def trace_rank() -> int:
 
 
 def tracing_enabled() -> bool:
+    """Is the ring armed (`enable_tracing()`)? See `recording()` for
+    "would a span record now"."""
     return _ACTIVE[0]
+
+
+def recording() -> bool:
+    """Would a span opened now be recorded: the ring is armed or a JAX
+    profiler session is recording."""
+    return _ACTIVE[0] or _profiling()
 
 
 def enable_tracing(capacity=None):
@@ -119,9 +148,15 @@ def clear():
         _RING.clear()
 
 
+def new_span_id():
+    """Take one id off the process-wide counter: every span opened
+    later has a larger one (the legacy Profiler's cycle watermark)."""
+    return next(_IDS)
+
+
 # -- the span primitive ------------------------------------------------------
 class _NullSpan:
-    """Shared no-op context for the disabled path."""
+    """Shared no-op context for the path on which nothing records."""
     __slots__ = ()
 
     def __enter__(self):
@@ -130,41 +165,73 @@ class _NullSpan:
     def __exit__(self, *exc):
         return False
 
+    def set(self, **meta):
+        return self
+
 
 _NULL = _NullSpan()
 
-# the legacy profiler's host-span buffer: profiler/profiler.py REGISTERS
-# it here at its own import time (no import from this side — if the
-# profiler module was never imported, no Profiler can be recording)
-_PROF_BUFFER = [None]
+
+def _open_ids():
+    try:
+        return _OPEN.ids
+    except AttributeError:
+        _OPEN.ids = []
+        return _OPEN.ids
+
+
+def _stats(meta):
+    """Metadata as the profiler's event stats take it: numbers and
+    strings (a request id may be any hashable)."""
+    return {k: (v if isinstance(v, (int, float, str)) else str(v))
+            for k, v in meta.items()}
 
 
 class _Span:
-    __slots__ = ("name", "meta", "_t0")
+    __slots__ = ("name", "meta", "id", "parent", "_t0", "_ann")
 
     def __init__(self, name, meta):
         self.name = name
         self.meta = meta
-        self._t0 = None
+        self.id = self.parent = self._t0 = self._ann = None
+
+    def set(self, **meta):
+        """Add metadata known only once the work is under way (a count
+        of what was done, the slot that was picked)."""
+        if self.meta is None:
+            self.meta = meta
+        else:
+            self.meta.update(meta)
+        if self._ann is not None:
+            self._ann.set_metadata(**_stats(meta))
+        return self
 
     def __enter__(self):
+        open_ids = _open_ids()
+        self.parent = open_ids[-1] if open_ids else None
+        self.id = next(_IDS)
+        open_ids.append(self.id)
+        if _profiling():
+            self._ann = _Annotation(self.name, **_stats(self.meta or {}))
+            self._ann.__enter__()
         self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc):
         t1 = time.perf_counter_ns()
-        t0 = self._t0
-        if t0 is None:
+        if self._t0 is None:
             return False
-        tid = threading.get_ident()
-        if _ACTIVE[0]:
-            rec = (self.name, t0, t1, tid, trace_rank(), self.meta)
-            with _LOCK:
-                _RING.append(rec)
-        buf = _PROF_BUFFER[0]
-        if buf is not None and buf.enabled:
-            # keep the legacy Profiler flow seeing the same spans
-            buf.add(self.name, t0, t1, tid)
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+        open_ids = _open_ids()
+        if open_ids and open_ids[-1] == self.id:
+            open_ids.pop()
+        elif self.id in open_ids:        # closed out of order
+            open_ids.remove(self.id)
+        rec = (self.id, self.parent, self.name, self._t0, t1,
+               threading.get_ident(), trace_rank(), self.meta)
+        with _LOCK:
+            _RING.append(rec)
         return False
 
 
@@ -187,36 +254,66 @@ def set_track_name(tid, name, sort_index=None):
 
 
 def record_span(name, t0_ns, t1_ns, tid=None, meta=None):
-    """Record an already-timed span into the ring (the legacy
-    profiler.RecordEvent path bridges through this so hand-rolled spans
-    land in merged traces too). No-op when tracing is disabled."""
-    if not _ACTIVE[0]:
+    """Record an already-timed span into the ring (profiler.RecordEvent,
+    the per-request tracks and the compile listener come through here).
+    Its times are the caller's, so it cannot be put into a profiler
+    session's trace: ring only. On the calling thread (`tid` None) its
+    parent is the span open there; on a synthetic track it has none.
+    No-op when nothing records."""
+    if not recording():
         return
-    rec = (name, int(t0_ns), int(t1_ns),
-           threading.get_ident() if tid is None else tid,
+    parent = None
+    if tid is None:
+        tid = threading.get_ident()
+        open_ids = _open_ids()
+        parent = open_ids[-1] if open_ids else None
+    rec = (next(_IDS), parent, name, int(t0_ns), int(t1_ns), tid,
            trace_rank(), meta)
     with _LOCK:
         _RING.append(rec)
 
 
 def span(name, **meta):
-    """Open a trace span: `with span("grad_sync:b3", bucket=3): ...`.
+    """Open a trace span: `with span("serve:admit", rid=7) as sp: ...;
+    sp.set(tokens=1)`.
 
-    Disabled path = one bool read + a shared null context. A span is
-    recorded when EITHER the tracer ring is armed or a legacy Profiler
-    is recording (the bridge that subsumes the old RecordEvent sites)."""
-    if not _ACTIVE[0]:
-        buf = _PROF_BUFFER[0]
-        if not (buf and buf.enabled):
-            return _NULL
+    With nothing recording this is one bool read, one `is_enabled()` and
+    a shared null context. A span is recorded when the ring is armed or
+    a JAX profiler session is recording (module docstring)."""
+    if not (_ACTIVE[0] or _profiling()):
+        return _NULL
     return _Span(name, meta or None)
+
+
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+
+def _on_duration(event, seconds, fun_name="", **_):
+    """`jax.monitoring` listener: one `xla:compile` span per backend
+    compile (or persistent-cache retrieval) while the tracer records —
+    on the plain jit path a prefill bucket that compiles mid-serve is
+    otherwise invisible from inside the program. The event arrives when
+    the compile ends: start = end - seconds in the ring; in a profiler
+    session's trace a zero-length marker at the end carries the
+    seconds."""
+    if event != _COMPILE_EVENT or not recording():
+        return
+    t1 = time.perf_counter_ns()
+    meta = {"fun_name": str(fun_name), "seconds": float(seconds)}
+    if _profiling():
+        with _Annotation("xla:compile", **meta):
+            pass
+    record_span("xla:compile", t1 - int(seconds * 1e9), t1, meta=meta)
+
+
+jax.monitoring.register_event_duration_secs_listener(_on_duration)
 
 
 # -- introspection -----------------------------------------------------------
 def _as_dict(rec):
-    name, t0, t1, tid, rank, meta = rec
-    d = {"name": name, "t0_ns": t0, "dur_ns": t1 - t0,
-         "tid": tid, "rank": rank}
+    sid, parent, name, t0, t1, tid, rank, meta = rec
+    d = {"id": sid, "parent": parent, "name": name, "t0_ns": t0,
+         "dur_ns": t1 - t0, "tid": tid, "rank": rank}
     if meta:
         d["meta"] = meta
     return d
@@ -276,6 +373,8 @@ def chrome_events(spans=None, pid=None, rank=None, include_metadata=True):
               "ts": (s["t0_ns"] + off) / 1e3, "dur": s["dur_ns"] / 1e3,
               "pid": pid, "tid": s["tid"],
               "args": {"rank": s.get("rank", rank)}}
+        if s.get("id") is not None:
+            ev["args"].update(id=s["id"], parent=s.get("parent"))
         if s.get("meta"):
             ev["args"].update(s["meta"])
         events.append(ev)

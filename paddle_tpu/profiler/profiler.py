@@ -4,7 +4,6 @@ from __future__ import annotations
 import enum
 import json
 import os
-import threading
 import time
 from collections import defaultdict
 
@@ -38,35 +37,10 @@ class SummaryView(enum.Enum):
     MemoryView = 6
 
 
-class _HostEventBuffer:
-    """Thread-safe span store (the HostTracer role)."""
-
-    def __init__(self):
-        self._lock = threading.Lock()
-        self.events = []
-        self.enabled = False
-
-    def add(self, name, t0, t1, tid):
-        if not self.enabled:
-            return
-        with self._lock:
-            self.events.append((name, t0, t1, tid))
-
-    def clear(self):
-        with self._lock:
-            self.events = []
-
-
-_BUFFER = _HostEventBuffer()
-
-# register the buffer with the observability span tracer: call sites
-# that moved from bare RecordEvent to tracing.span() keep feeding a
-# recording Profiler through this bridge (tracing never imports us)
-try:
-    from ..observability import tracing as _obs_tracing
-    _obs_tracing._PROF_BUFFER[0] = _BUFFER
-except Exception:  # pragma: no cover - bootstrap ordering
-    pass
+# Host spans have ONE store: the observability tracer's ring. A
+# recording Profiler arms it, RecordEvent lands there through
+# record_span, and exports read it back with tail().
+from ..observability import tracing as _obs_tracing
 
 
 def _native():
@@ -74,9 +48,9 @@ def _native():
     return native_runtime.lib()
 
 
-def _all_events():
-    """Python-buffer events + native-tracer events as (name, t0, t1, tid)."""
-    events = list(_BUFFER.events)
+def _native_events():
+    """The native tracer's events as (name, t0, t1, tid)."""
+    events = []
     lib = _native()
     if lib is not None and lib.pht_event_count() > 0:
         import tempfile
@@ -120,13 +94,8 @@ class RecordEvent:
             self._t0 = None
             return
         if self._t0 is not None:
-            t1 = time.perf_counter_ns()
-            tid = threading.get_ident()
-            _BUFFER.add(self.name, self._t0, t1, tid)
-            try:
-                _obs_tracing.record_span(self.name, self._t0, t1, tid)
-            except Exception:
-                pass
+            _obs_tracing.record_span(self.name, self._t0,
+                                     time.perf_counter_ns())
             self._t0 = None
 
     def __enter__(self):
@@ -200,10 +169,14 @@ class Profiler:
         self._device_trace_dir = None
         self._device_tracing = False
         self._last_export = None
+        # the ring's spans with a larger id are this cycle's; whether
+        # this Profiler armed the ring (and so has to disarm it)
+        self._mark = 0
+        self._armed_ring = False
 
     # -- lifecycle ---------------------------------------------------------
     def start(self):
-        _BUFFER.clear()
+        self._mark = _obs_tracing.new_span_id()
         lib = _native()
         if lib is not None:
             lib.pht_clear()
@@ -213,14 +186,12 @@ class Profiler:
     def stop(self):
         if self._device_tracing:
             self._stop_device_trace()
-        _BUFFER.enabled = False
+        self._arm_ring(False)
         lib = _native()
         if lib is not None:
             lib.pht_enable(0)
         # export whatever the final (possibly partial) cycle recorded
-        if self._on_trace_ready is not None and (
-                _BUFFER.events or (lib is not None
-                                   and lib.pht_event_count() > 0)):
+        if self._on_trace_ready is not None and self._events():
             self._last_export = self._on_trace_ready(self)
         self._state = ProfilerState.CLOSED
 
@@ -229,12 +200,10 @@ class Profiler:
         # cycle's events and reset the buffer so cycles don't bleed into
         # each other (reference contract: one trace per repeat cycle)
         if self._state is ProfilerState.RECORD_AND_RETURN:
-            lib = _native()
-            has_events = bool(_BUFFER.events) or (
-                lib is not None and lib.pht_event_count() > 0)
-            if self._on_trace_ready is not None and has_events:
+            if self._on_trace_ready is not None and self._events():
                 self._last_export = self._on_trace_ready(self)
-            _BUFFER.clear()
+            self._mark = _obs_tracing.new_span_id()
+            lib = _native()
             if lib is not None:
                 lib.pht_clear()
         prev = self._state
@@ -253,14 +222,32 @@ class Profiler:
     def _apply_state(self):
         recording = self._state in (ProfilerState.RECORD,
                                     ProfilerState.RECORD_AND_RETURN)
-        _BUFFER.enabled = recording and not self._timer_only
+        host_spans = recording and not self._timer_only
+        self._arm_ring(host_spans)
         lib = _native()
         if lib is not None:
-            lib.pht_enable(1 if _BUFFER.enabled else 0)
+            lib.pht_enable(1 if host_spans else 0)
         if recording and not self._timer_only and not self._device_tracing:
             self._start_device_trace()
         elif not recording and self._device_tracing:
             self._stop_device_trace()
+
+    def _arm_ring(self, on):
+        """Arm the tracer's ring for a RECORD state and disarm it after,
+        unless someone else had it armed already."""
+        if on and not _obs_tracing.tracing_enabled():
+            _obs_tracing.enable_tracing()
+            self._armed_ring = True
+        elif not on and self._armed_ring:
+            _obs_tracing.disable_tracing()
+            self._armed_ring = False
+
+    def _events(self):
+        """This cycle's host spans as (name, t0, t1, tid): the ring's
+        since the cycle's watermark, plus the native tracer's."""
+        return [(s["name"], s["t0_ns"], s["t0_ns"] + s["dur_ns"], s["tid"])
+                for s in _obs_tracing.tail()
+                if s["id"] > self._mark] + _native_events()
 
     def _start_device_trace(self):
         try:
@@ -283,7 +270,7 @@ class Profiler:
     # -- output ------------------------------------------------------------
     def _export_chrome(self, path):
         events = []
-        for name, t0, t1, tid in _all_events():
+        for name, t0, t1, tid in self._events():
             events.append({
                 "name": name, "ph": "X", "cat": "host",
                 "ts": t0 / 1e3, "dur": (t1 - t0) / 1e3,
@@ -301,7 +288,7 @@ class Profiler:
                 time_unit="ms"):
         """Aggregated host-span table (profiler_statistic.py role)."""
         agg = defaultdict(lambda: [0, 0.0, 0.0])  # count, total, max
-        for name, t0, t1, tid in _all_events():
+        for name, t0, t1, tid in self._events():
             d = (t1 - t0) / 1e6  # ms
             a = agg[name]
             a[0] += 1

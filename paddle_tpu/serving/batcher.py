@@ -97,7 +97,6 @@ def serve_loop(eng, requests, *, max_new_tokens=32, eos_token_id=None,
                     and pipe_teeth != "force_sync")
     cache = eng.prefix_cache
     telemetry = _obs.enabled()
-    ledger = None
     if telemetry:
         if getattr(eng, "_serve_ledger", None) is None:
             from ..observability.attribution import StepLedger
@@ -105,10 +104,14 @@ def serve_loop(eng, requests, *, max_new_tokens=32, eos_token_id=None,
         # per-CALL classification: idle time between two serve()
         # invocations is the caller's, not this call's data_wait
         eng._serve_ledger._prev_end = None
-        from ..observability.requests import RequestLedger
-        if eng.request_ledger is None:
-            eng.request_ledger = RequestLedger("serve")
-        ledger = eng.request_ledger
+    # the request ledger is the loop's own accounting, fed whatever
+    # telemetry says (a few clock reads and one small call per slot and
+    # chunk): queue wait, TTFT and TPOT are those of the program users
+    # run. Only its export (registry quantiles, JSONL, per-request
+    # tracks) is conditional, inside the ledger
+    if eng.request_ledger is None:
+        eng.request_ledger = _obs.requests.RequestLedger("serve")
+    ledger = eng.request_ledger
     recovery = bool(_flag("serve_fault_recovery"))
     quarantine_on = bool(_flag("serve_logit_quarantine"))
     replays = ReplayTracker(max_restarts, replay_backoff_s)
@@ -118,11 +121,10 @@ def serve_loop(eng, requests, *, max_new_tokens=32, eos_token_id=None,
     t_start = time.perf_counter()
     queue = AdmissionQueue(t_start)
     quads = queue.load(requests, max_new_tokens)
-    if ledger is not None:
-        # register at the scheduled ABSOLUTE arrival: queue wait and
-        # TTFT start on the user's clock, not at admission
-        for rid, prompt, mnt, arr in quads:
-            ledger.arrival(rid, _plen(prompt), mnt, ts=t_start + arr)
+    # register at the scheduled ABSOLUTE arrival: queue wait and
+    # TTFT start on the user's clock, not at admission
+    for rid, prompt, mnt, arr in quads:
+        ledger.arrival(rid, _plen(prompt), mnt, ts=t_start + arr)
     # cache-on engines serve from persistent pools — cached KV written
     # by THIS call must outlive it. Cache-off engines keep the
     # historical fresh-pools-per-call behavior (and its zeroed-pool
@@ -224,8 +226,6 @@ def serve_loop(eng, requests, *, max_new_tokens=32, eos_token_id=None,
         registered-but-unfinished requests haunting the ledger's
         in-flight table — the flight recorder would name them
         'stuck' forever on a decoder that outlives the call."""
-        if ledger is None:
-            return
         for rid, _, _, _ in queue:       # never admitted
             ledger.discard(rid)
         for s in eng._slots:             # admitted, mid-flight
@@ -239,8 +239,7 @@ def serve_loop(eng, requests, *, max_new_tokens=32, eos_token_id=None,
         results[rid] = finalize_tokens(replays.prefix(rid))
         eng.rejected_requests[cause] = \
             eng.rejected_requests.get(cause, 0) + 1
-        if ledger is not None:
-            ledger.reject(rid, cause, ts=now)
+        ledger.reject(rid, cause, ts=now)
 
     def finalize_tokens(toks):
         if eos_token_id is not None and eos_token_id in toks:
@@ -266,8 +265,7 @@ def serve_loop(eng, requests, *, max_new_tokens=32, eos_token_id=None,
             # NOW the chain is cold (rc==1, cache-only) — page the
             # overflow past the planner's resident budget to host
             cache_sync(cache.enforce_residency)
-        if ledger is not None:
-            ledger.retire(s.req_id, cause)
+        ledger.retire(s.req_id, cause)
         eng._slots[i] = _Slot(done=True)
         tables[i] = 0
         live[i] = False
@@ -285,7 +283,7 @@ def serve_loop(eng, requests, *, max_new_tokens=32, eos_token_id=None,
                     "paddle_tpu_request_replay_giveups_total",
                     "Requests abandoned (partial stream "
                     "delivered) after max_restarts replays").inc()
-            if ledger is not None and not admitted:
+            if not admitted:
                 # a never-admitted incarnation is still live in the
                 # ledger — close it out as a deferral-storm loss
                 ledger.reject(rid, "rejected_deferred", ts=now)
@@ -298,7 +296,7 @@ def serve_loop(eng, requests, *, max_new_tokens=32, eos_token_id=None,
                 "paddle_tpu_request_replays_total",
                 "Evicted/faulted requests re-admitted via "
                 "chunked-prefill replay").inc()
-        if ledger is not None and admitted:
+        if admitted:
             # the replay is a NEW ledger incarnation of the same
             # rid; its clock starts at the scheduled replay arrival
             # (the prior incarnation retired evicted/quarantined)
@@ -321,8 +319,7 @@ def serve_loop(eng, requests, *, max_new_tokens=32, eos_token_id=None,
         mark_state_dirty()
         if cause == "evicted":
             eng.evictions += 1
-        if ledger is not None:
-            ledger.retire(rid, cause, ts=now)
+        ledger.retire(rid, cause, ts=now)
         requeue(rid, prompt, mnt_orig, prefix, now, admitted=True)
 
     def pick_victim():
@@ -355,10 +352,9 @@ def serve_loop(eng, requests, *, max_new_tokens=32, eos_token_id=None,
                      "tokens_generated": len(s.emitted)})
         except Exception:
             pass
-        if ledger is not None:
-            # the poisoned pass still occupied the slot: bill its
-            # wall to the request (0 tokens kept)
-            ledger.chunk(s.req_id, t0c, t1c, 0)
+        # the poisoned pass still occupied the slot: bill its
+        # wall to the request (0 tokens kept)
+        ledger.chunk(s.req_id, t0c, t1c, 0)
         evict(i, "quarantined", now)
 
     def advance(i, emit, t0c, t1c):
@@ -373,10 +369,9 @@ def serve_loop(eng, requests, *, max_new_tokens=32, eos_token_id=None,
         s.budget -= take
         seqlens[i] += take
         tokens[i] = emit[-1]
-        if ledger is not None:
-            # the whole pass wall is this request's decode cost —
-            # its slot rode the batch for all of it
-            ledger.chunk(s.req_id, t0c, t1c, take)
+        # the whole pass wall is this request's decode cost —
+        # its slot rode the batch for all of it
+        ledger.chunk(s.req_id, t0c, t1c, take)
         hit_eos = (eos_token_id is not None
                    and eos_token_id in s.emitted)
         if s.budget <= 0 or hit_eos:
@@ -447,6 +442,7 @@ def serve_loop(eng, requests, *, max_new_tokens=32, eos_token_id=None,
                     coins[i] = True
         if pipe_teeth == "force_sync":
             mark_state_dirty()
+        uploads = 0
         if dev["state"] is None:
             tok_up = tokens.copy()
             if pipe_teeth == "mutate_feedback" and live.any():
@@ -466,11 +462,13 @@ def serve_loop(eng, requests, *, max_new_tokens=32, eos_token_id=None,
                             jnp.asarray(live.copy()),
                             jnp.asarray(budg), jnp.asarray(coins))
             poison_mirror[:] = coins
-            note_uploads(6)
+            uploads = 6
         elif not np.array_equal(coins, poison_mirror):
             dev["state"] = dev["state"][:5] + (jnp.asarray(coins),)
             poison_mirror[:] = coins
-            note_uploads(1)
+            uploads = 1
+        if uploads:
+            note_uploads(uploads)
         st = dev["state"]
         args = (eng._params,) + st + (kpool, vpool)
         if telemetry:
@@ -487,7 +485,9 @@ def serve_loop(eng, requests, *, max_new_tokens=32, eos_token_id=None,
         if telemetry and after_n is None and last_ready[0] is not None:
             gap = max(0.0, t_disp - last_ready[0] - dev_busy[0])
         dev_busy[0] = 0.0
-        with _obs.span("serve:chunk", steps=int(n)):
+        with _obs.span("serve:chunk", steps=int(n),
+                       lookahead=int(after_n is not None),
+                       uploads=uploads):
             if telemetry:
                 (toks, bad, tok_o, len_o, live_o, budg_o, kpool,
                  vpool) = fn(*args)
@@ -522,8 +522,10 @@ def serve_loop(eng, requests, *, max_new_tokens=32, eos_token_id=None,
         if n_eff is None:
             n_eff = serial_n(rec)
         t_w0 = time.perf_counter()
-        toks = np.asarray(rec["toks"])
-        bad = np.asarray(rec["bad"])
+        with _obs.span("serve:wait_chunk", steps=rec["n"]):
+            # the loop's wait for the device: the tokens are read here
+            toks = np.asarray(rec["toks"])
+            bad = np.asarray(rec["bad"])
         t_ready = time.perf_counter()
         if telemetry:
             # in the pipelined loop "execute" is the EXPOSED device
@@ -539,14 +541,19 @@ def serve_loop(eng, requests, *, max_new_tokens=32, eos_token_id=None,
             ct0 = max(ct0, last_ready[0])
         ct0 = min(ct0, t_ready)
         last_ready[0] = t_ready
-        for i, s_ref in rec["slots"]:
-            if not live[i] or eng._slots[i] is not s_ref:
-                continue
-            if quarantine_on and bad[i]:
-                quarantine(i, ct0, t_ready, time.perf_counter())
-                continue
-            take = min(n_eff, eng._slots[i].budget)
-            advance(i, [int(t) for t in toks[i, :take]], ct0, t_ready)
+        with _obs.span("serve:commit") as sp:
+            took, live_before = 0, int(live.sum())
+            for i, s_ref in rec["slots"]:
+                if not live[i] or eng._slots[i] is not s_ref:
+                    continue
+                if quarantine_on and bad[i]:
+                    quarantine(i, ct0, t_ready, time.perf_counter())
+                    continue
+                take = min(n_eff, eng._slots[i].budget)
+                advance(i, [int(t) for t in toks[i, :take]], ct0,
+                        t_ready)
+                took += take
+            sp.set(tokens=took, retired=live_before - int(live.sum()))
         if n_eff < rec["n"]:
             # the device ran the full overshot chunk — its state is
             # ahead of the trimmed mirrors; resync at next dispatch
@@ -554,7 +561,7 @@ def serve_loop(eng, requests, *, max_new_tokens=32, eos_token_id=None,
             # chunk re-derives, so rewriting them is value-identical)
             mark_state_dirty()
 
-    def admit_payload(i, req_id, payload, max_new, t_admit):
+    def admit_payload(i, req_id, payload, max_new, t_admit, sp):
         """Streamed-KV admission (prefill/decode disaggregation): the
         prefill worker already computed the prompt's KV and first
         token — import the blocks, write the table, and join the next
@@ -575,11 +582,9 @@ def serve_loop(eng, requests, *, max_new_tokens=32, eos_token_id=None,
         row = np.zeros(MB, np.int32)
         row[:len(blocks)] = blocks
         tables[i] = row
-        if ledger is not None:
-            ledger.admit(req_id, slot=i, blocks=len(blocks),
-                         ts=t_admit)
+        ledger.admit(req_id, slot=i, blocks=len(blocks), ts=t_admit)
         _faults.inject("prefill_chunk")
-        t0p = time.perf_counter() if telemetry else 0.0
+        t0p = time.perf_counter()
         used = blocks_needed(s0)
         with _obs.span("serve:kv_import", blocks=used):
             kpool, vpool = eng.import_blocks(
@@ -588,12 +593,11 @@ def serve_loop(eng, requests, *, max_new_tokens=32, eos_token_id=None,
         if telemetry:
             phase["execute"] += t1p - t0p
             dev_busy[0] += t1p - t0p
-            if ledger is not None:
-                # the import IS this request's prefill segment on this
-                # engine; every prompt token arrived cached
-                ledger.prefill(req_id, t0p, t1p, bucket=0,
-                               cached_tokens=s0)
-                ledger.first_token(req_id, ts=t1p)
+        # the import IS this request's prefill segment on this
+        # engine; every prompt token arrived cached
+        ledger.prefill(req_id, t0p, t1p, bucket=0, cached_tokens=s0)
+        ledger.first_token(req_id, ts=t1p)
+        sp.set(bucket=0, cached_tokens=s0, tokens=1)
         first = int(payload.first_token)
         slot.emitted.append(first)
         slot.budget -= 1
@@ -605,10 +609,17 @@ def serve_loop(eng, requests, *, max_new_tokens=32, eos_token_id=None,
             retire(i, "eos" if hit_eos else "budget_exhausted")
 
     def admit(i, req_id, prompt, max_new, t_admit):
+        """One admission as the loop pays for it: from the pop off the
+        queue to the slot joining the batch, the wait for the prefill's
+        first token included."""
+        by = admit_payload if isinstance(prompt, KVBlockPayload) \
+            else admit_prompt
+        with _obs.span("serve:admit", rid=req_id, slot=i,
+                       prompt_tokens=_plen(prompt)) as sp:
+            by(i, req_id, prompt, max_new, t_admit, sp)
+
+    def admit_prompt(i, req_id, prompt, max_new, t_admit, sp):
         nonlocal kpool, vpool
-        if isinstance(prompt, KVBlockPayload):
-            admit_payload(i, req_id, prompt, max_new, t_admit)
-            return
         mark_state_dirty()
         prompt = list(map(int, prompt))
         # chunked-prefill replay: a previously evicted incarnation
@@ -644,9 +655,7 @@ def serve_loop(eng, requests, *, max_new_tokens=32, eos_token_id=None,
         row = np.zeros(MB, np.int32)
         row[:len(blocks)] = blocks
         tables[i] = row
-        if ledger is not None:
-            ledger.admit(req_id, slot=i, blocks=len(blocks),
-                         ts=t_admit)
+        ledger.admit(req_id, slot=i, blocks=len(blocks), ts=t_admit)
         # chaos site: prefill execution failure — fires BEFORE the
         # device call (pools untouched, donation not yet consumed),
         # the window where recovery is clean unwind + replay
@@ -668,14 +677,16 @@ def serve_loop(eng, requests, *, max_new_tokens=32, eos_token_id=None,
                 # the AOT build pays trace+compile OUTSIDE the call —
                 # billed exactly (the warm call below is pure execute)
                 phase["compile"] += time.perf_counter() - t0b
-            t0p = time.perf_counter() if telemetry else 0.0
+            t0p = time.perf_counter()
             with _obs.span("serve:prefill", bucket=bucket):
                 enc, kpool, vpool = fn(*args_p)
-                # ONE int32 on the wire (ISSUE 20 tentpole c): the
-                # argmax AND the finiteness probe are fused on device
-                # — a 128k-vocab f32 row used to cross per admission
+            # ONE int32 on the wire (ISSUE 20 tentpole c): the
+            # argmax AND the finiteness probe are fused on device
+            # — a 128k-vocab f32 row used to cross per admission.
+            # Reading it is where the loop waits for the prefill
+            with _obs.span("serve:wait_first_token", rid=req_id):
                 first, nonfinite = eng.decode_first_token(enc)
-                bad_prefill = quarantine_on and nonfinite
+            bad_prefill = quarantine_on and nonfinite
             eng.prefill_device_calls += 1
             eng.prefill_tokens_computed += s0
         else:
@@ -718,7 +729,7 @@ def serve_loop(eng, requests, *, max_new_tokens=32, eos_token_id=None,
                 if telemetry and built:
                     phase["compile"] += time.perf_counter() - t0b
                 if off == 0:
-                    t0p = time.perf_counter() if telemetry else 0.0
+                    t0p = time.perf_counter()
                     if cow_src is not None:
                         # fully-cached prompt: fork the boundary block
                         # before the one-token suffix recompute writes
@@ -737,7 +748,8 @@ def serve_loop(eng, requests, *, max_new_tokens=32, eos_token_id=None,
             # only the LAST window's fused first-token matters (the
             # earlier windows exist for their KV writes) — one int32
             # carries both the argmax and the finiteness probe
-            first, nonfinite = eng.decode_first_token(enc)
+            with _obs.span("serve:wait_first_token", rid=req_id):
+                first, nonfinite = eng.decode_first_token(enc)
             bad_prefill = quarantine_on and nonfinite
             eng.prefill_tokens_computed += ns
             cache.record_admission(cached, kb, cow=cow_src is not None)
@@ -745,9 +757,10 @@ def serve_loop(eng, requests, *, max_new_tokens=32, eos_token_id=None,
         if telemetry:
             phase["execute"] += t1p - t0p
             dev_busy[0] += t1p - t0p
-            if ledger is not None:
-                ledger.prefill(req_id, t0p, t1p, bucket=bucket,
-                               cached_tokens=cached)
+        ledger.prefill(req_id, t0p, t1p, bucket=bucket,
+                       cached_tokens=cached)
+        sp.set(bucket=bucket, cached_tokens=cached,
+               tokens=0 if bad_prefill else 1)
         if bad_prefill:
             # non-finite prefill logits: same quarantine contract
             # as a poisoned decode pass (host-side detection — the
@@ -756,8 +769,7 @@ def serve_loop(eng, requests, *, max_new_tokens=32, eos_token_id=None,
             # and the discarded argmax never counts as generated
             quarantine(i, t1p, t1p, t1p)
             return
-        if telemetry and ledger is not None:
-            ledger.first_token(req_id, ts=t1p)
+        ledger.first_token(req_id, ts=t1p)
         slot.emitted.append(first)
         slot.budget -= 1
         tokens[i] = first
@@ -778,345 +790,358 @@ def serve_loop(eng, requests, *, max_new_tokens=32, eos_token_id=None,
         payloads) into the queue at their delivery time."""
         if feed is None:
             return
-        for rid, body, mnt in feed():
-            now_abs = time.perf_counter()
-            if ledger is not None:
+        with _obs.span("serve:feed") as sp:
+            pushed = 0
+            for rid, body, mnt in feed():
+                now_abs = time.perf_counter()
                 ledger.arrival(rid, _plen(body), mnt, ts=now_abs)
-            queue.push(rid, body, mnt, now_abs - t_start)
+                queue.push(rid, body, mnt, now_abs - t_start)
+                pushed += 1
+            sp.set(pushed=pushed)
 
     feeding = (lambda: False) if feed_active is None else feed_active
 
     try:
         while queue or live.any() or feeding():
-            it0 = time.perf_counter() if telemetry else 0.0
-            phase["compile"] = phase["execute"] = 0.0
-            phase["host_gap"] = 0.0
-            drain_feed()
-            now = time.perf_counter()
-            # drain on peer death (ISSUE 14): once the watchdog
-            # declares a peer dead, the pod is degraded — reject
-            # everything still queued so the in-flight slots can
-            # retire cleanly, and admit nothing new
-            if queue:
-                drain = eng._drain_reason()
-                if drain is not None:
-                    drained = queue.drain()
-                    for rid_d, _, _, arr_d in drained:
-                        reject(rid_d, "rejected_draining",
-                               max(now, t_start + arr_d))
-                    eng.drained_rejections += len(drained)
-                    if telemetry:
-                        _obs.registry().counter(
-                            "paddle_tpu_serving_drain_rejections"
-                            "_total",
-                            "Queued requests rejected because the "
-                            "watchdog declared a peer dead",
-                        ).inc(len(drained))
-                    try:
-                        from ..observability import (
-                            flight_recorder as _fr)
-                        _fr.trip_once(
-                            f"serving_drain:{drain}",
-                            {"reason": drain,
-                             "rejected": len(drained),
-                             "in_flight": int(live.sum())})
-                    except Exception:
-                        pass
-            # admission: fill free slots while blocks allow
-            deferred_scan = False
-            for i in range(eng.max_slots):
-                shed_heads(now)
-                if not queue:
-                    break
-                rid, prompt, mnt, arr = queue.head()
-                if t_start + arr > now:
-                    break                # next arrival is in the future
-                if not eng._slots[i].done:
-                    continue
-                need = blocks_needed(_plen(prompt) + mnt)
-                if need > eng.allocator.free_count:
-                    # pool pressure: cold cache entries go first —
-                    # LRU leaves whose blocks only the tree holds;
-                    # live tables are untouchable by construction
-                    if cache is not None:
-                        cache_sync(cache.evict,
-                                   need - eng.allocator.free_count)
+            with _obs.span("serve:iteration", live=int(live.sum()),
+                           queued=len(queue)):
+                it0 = time.perf_counter() if telemetry else 0.0
+                phase["compile"] = phase["execute"] = 0.0
+                phase["host_gap"] = 0.0
+                drain_feed()
+                now = time.perf_counter()
+                # drain on peer death (ISSUE 14): once the watchdog
+                # declares a peer dead, the pod is degraded — reject
+                # everything still queued so the in-flight slots can
+                # retire cleanly, and admit nothing new
+                if queue:
+                    drain = eng._drain_reason()
+                    if drain is not None:
+                        drained = queue.drain()
+                        for rid_d, _, _, arr_d in drained:
+                            reject(rid_d, "rejected_draining",
+                                   max(now, t_start + arr_d))
+                        eng.drained_rejections += len(drained)
+                        if telemetry:
+                            _obs.registry().counter(
+                                "paddle_tpu_serving_drain_rejections"
+                                "_total",
+                                "Queued requests rejected because the "
+                                "watchdog declared a peer dead",
+                            ).inc(len(drained))
+                        try:
+                            from ..observability import (
+                                flight_recorder as _fr)
+                            _fr.trip_once(
+                                f"serving_drain:{drain}",
+                                {"reason": drain,
+                                 "rejected": len(drained),
+                                 "in_flight": int(live.sum())})
+                        except Exception:
+                            pass
+                # admission: fill free slots while blocks allow
+                deferred_scan = False
+                for i in range(eng.max_slots):
+                    shed_heads(now)
+                    if not queue:
+                        break
+                    rid, prompt, mnt, arr = queue.head()
+                    if t_start + arr > now:
+                        break                # next arrival is in the future
+                    if not eng._slots[i].done:
+                        continue
+                    need = blocks_needed(_plen(prompt) + mnt)
                     if need > eng.allocator.free_count:
-                        break            # backpressure: decode first
-                # the pool itself is preallocated — admitting consumes no
-                # pool HBM. What admission DOES allocate is transient: the
-                # bucketed prefill executable + its workspace, priced here
-                # by the prompt's KV footprint as a proxy. Worst case under
-                # sustained pressure is drain-to-empty serialization (live
-                # slots always keep decoding, and an empty batch bypasses
-                # the guard), never a mid-serve RESOURCE_EXHAUSTED.
-                prefill_est = blocks_needed(_plen(prompt)) * \
-                    eng.bytes_per_block()
-                if (eng.headroom_guard is not None and live.any()
-                        and not eng.headroom_guard.check(prefill_est)):
-                    eng.admission_deferrals += 1
-                    deferred_scan = True
-                    defer_counts[rid] = defer_counts.get(rid, 0) + 1
-                    if ledger is not None:
+                        # pool pressure: cold cache entries go first —
+                        # LRU leaves whose blocks only the tree holds;
+                        # live tables are untouchable by construction
+                        if cache is not None:
+                            cache_sync(cache.evict,
+                                       need - eng.allocator.free_count)
+                        if need > eng.allocator.free_count:
+                            break            # backpressure: decode first
+                    # the pool itself is preallocated — admitting consumes no
+                    # pool HBM. What admission DOES allocate is transient: the
+                    # bucketed prefill executable + its workspace, priced here
+                    # by the prompt's KV footprint as a proxy. Worst case under
+                    # sustained pressure is drain-to-empty serialization (live
+                    # slots always keep decoding, and an empty batch bypasses
+                    # the guard), never a mid-serve RESOURCE_EXHAUSTED.
+                    prefill_est = blocks_needed(_plen(prompt)) * \
+                        eng.bytes_per_block()
+                    if (eng.headroom_guard is not None and live.any()
+                            and not eng.headroom_guard.check(prefill_est)):
+                        eng.admission_deferrals += 1
+                        deferred_scan = True
+                        defer_counts[rid] = defer_counts.get(rid, 0) + 1
                         ledger.defer(rid)
-                    if _obs.enabled():
-                        _obs.registry().counter(
-                            "paddle_tpu_paged_admission_deferrals_total",
-                            "Admissions deferred by the headroom guard"
-                        ).inc()
-                    if recovery and defer_counts[rid] >= max_deferrals:
-                        # deferral storm: degrade to rejection —
-                        # the queue must not wedge behind a head
-                        # the guard will never let in
-                        queue.pop()
-                        reject(rid, "rejected_deferred",
-                               time.perf_counter())
-                        continue
-                    if (recovery and defer_counts[rid]
-                            == evict_after_deferrals):
-                        # sustained pressure: free a victim's
-                        # blocks so the head (or the next loop's
-                        # empty-batch bypass) can make progress.
-                        # Cold cache subtrees are the cheapest
-                        # victims (no work thrown away); a live
-                        # slot pays only when the cache has nothing
-                        # cold. Exactly ONCE per head's deferral
-                        # streak: organic HBM pressure is not
-                        # relieved by freeing preallocated pool
-                        # blocks, so a persisting violation must
-                        # escalate to the max_deferrals rejection
-                        # above, not serially evict the whole live
-                        # batch
-                        freed = cache_sync(cache.evict, need) \
-                            if cache is not None else 0
-                        if not freed:
-                            v = pick_victim()
-                            if v is not None:
-                                evict(v, "evicted", time.perf_counter())
-                    break
-                queue.pop()
-                try:
-                    admit(i, rid, prompt, mnt, time.perf_counter())
-                    defer_counts.pop(rid, None)
-                except (_faults.InjectedFault, MemoryError):
-                    if not recovery:
-                        raise
-                    # transient admission failure (injected pool /
-                    # prefill fault): unwind the incarnation and
-                    # schedule its replay
-                    t_fail = time.perf_counter()
-                    s = eng._slots[i]
-                    plain = (list(prompt.prompt)
-                             if isinstance(prompt, KVBlockPayload)
-                             else list(map(int, prompt)))
-                    if not s.done and s.req_id == rid:
-                        evict(i, "evicted", t_fail)
-                    else:
-                        requeue(rid, plain, mnt, replays.prefix(rid),
-                                t_fail, admitted=False)
-            if not live.any():
-                # an empty batch ends the pipelined stream: whatever
-                # happens next (idle sleep, admission scan) the next
-                # dispatch opens a fresh device-idle window — a gap
-                # measured across the break would bill queue idle
-                # (data_wait by the step ledger's clock) as host_gap
-                last_ready[0] = None
-                dev_busy[0] = 0.0
-                if not queue:
-                    if feeding():
-                        # disaggregation: prefill workers still
-                        # running — idle until a payload lands
-                        time.sleep(0.002)
-                        continue
-                    break
-                if deferred_scan:
-                    # the guard deferred the head but the eviction
-                    # (or retirements) just emptied the batch — an
-                    # empty batch bypasses the guard, so re-scan
-                    # with a fresh clock instead of misreading the
-                    # deferral as pool-too-small
-                    continue
-                next_arrival = t_start + queue.head()[3]
-                fresh = time.perf_counter()
-                if next_arrival > fresh:
-                    # open-loop idle: nothing live, next arrival in the
-                    # future — sleep to it (the serve ledger bills the
-                    # gap as data_wait, which it is)
-                    time.sleep(next_arrival - fresh)
-                    continue
-                if next_arrival > now:
-                    # the head arrived BETWEEN the admission scan's
-                    # clock and this check — the scan never saw it;
-                    # retry with a fresh clock instead of
-                    # misdiagnosing an admittable head as
-                    # pool-too-small
-                    continue
-                if cache is not None and cache.held_blocks:
-                    # last resort before declaring the pool too small:
-                    # drop the whole cache (it holds blocks the head
-                    # needs) and re-scan
-                    cache_sync(cache.evict, cache.held_blocks)
-                    continue
-                raise MemoryError(
-                    "pool too small for even one pending request")
-            budgets = np.asarray(
-                [eng._slots[i].budget if live[i] else 0
-                 for i in range(eng.max_slots)], np.int32)
-            # chaos site: a failed/stuck decode pass. Fires BEFORE
-            # the device call (pools intact): recovery is bounded
-            # retry with backoff — the batch re-runs the same pass
-            if _faults.active():
-                try:
-                    _faults.inject("decode_chunk")
-                except _faults.InjectedFault:
-                    if not recovery:
-                        raise
-                    chunk_failures += 1
-                    if chunk_failures > max_chunk_retries:
-                        raise
-                    time.sleep(min(
-                        replay_backoff_s
-                        * (2 ** (chunk_failures - 1)), 0.5))
-                    continue
-                chunk_failures = 0
-            if spec_cfg is not None:
-                # the chaos harness's logits-poison lane: one coin per
-                # live slot per decode pass, applied ON DEVICE so the
-                # non-finite detection path is exercised end to end
-                # (the fused path fires its coins inside
-                # dispatch_chunk — one set per dispatched chunk,
-                # lookahead chunks included)
-                poison = np.zeros(eng.max_slots, bool)
-                if _faults.active():
-                    for i in range(eng.max_slots):
-                        if live[i] and _faults.fire("logits_poison"):
-                            poison[i] = True
-                # draft-propose -> batched-verify instead of a fused
-                # chunk: one target forward prices k+1 candidate
-                # tokens per slot against ONE pass over the KV pool
-                K = spec_cfg.k
-                toks_in = np.zeros((eng.max_slots, K + 1), np.int32)
-                toks_in[:, 0] = tokens
-                for i in range(eng.max_slots):
-                    if live[i]:
-                        s = eng._slots[i]
-                        toks_in[i, 1:] = np.asarray(draft.propose(
-                            s.prompt + s.emitted, K), np.int32)
-                # device-resident reuse (ISSUE 20 satellite): only the
-                # per-pass candidate tokens and positions upload every
-                # verify; tables/live/budgets/poison ride cached device
-                # copies refreshed on host-value change (the verify
-                # executable donates only the pools, so they survive)
-                args_s = (eng._params, jnp.asarray(toks_in),
-                          jnp.asarray(seqlens),
-                          spec_dev_arr("tables", tables),
-                          spec_dev_arr("live", live),
-                          spec_dev_arr("budgets", budgets),
-                          spec_dev_arr("poison", poison), kpool, vpool)
-                note_uploads(2)
-                if telemetry:
-                    t0b = time.perf_counter()
-                    fn, built = eng._spec_exec(K + 1, args_s)
-                    if built:
-                        phase["compile"] += time.perf_counter() - t0b
-                t0c = time.perf_counter() if telemetry else 0.0
-                if telemetry:
-                    if last_ready[0] is not None:
-                        phase["host_gap"] += max(
-                            0.0, t0c - last_ready[0] - dev_busy[0])
-                    dev_busy[0] = 0.0
-                with _obs.span("serve:spec_verify", k=int(K)):
-                    if telemetry:
-                        g, bad, kpool, vpool = fn(*args_s)
-                        jax.block_until_ready(g)
-                    else:
-                        g, bad, kpool, vpool = eng._spec_verify_jit(
-                            *args_s)
-                t1c = time.perf_counter() if telemetry else 0.0
-                if telemetry:
-                    phase["execute"] += t1c - t0c
-                    last_ready[0] = t1c
-                eng.chunk_dispatches += 1
-                eng._record_traffic(seqlens, K + 1, live, budgets,
-                                    launches=1)
-                g = np.asarray(g)
-                bad = np.asarray(bad)
-                st = eng.spec_stats
-                st["verify_calls"] += 1
-                call_prop = call_acc = 0
-                for i in range(eng.max_slots):
-                    if not live[i]:
-                        continue
-                    if quarantine_on and bad[i]:
-                        quarantine(i, t0c, t1c,
+                        if _obs.enabled():
+                            _obs.registry().counter(
+                                "paddle_tpu_paged_admission_deferrals_total",
+                                "Admissions deferred by the headroom guard"
+                            ).inc()
+                        if recovery and defer_counts[rid] >= max_deferrals:
+                            # deferral storm: degrade to rejection —
+                            # the queue must not wedge behind a head
+                            # the guard will never let in
+                            queue.pop()
+                            reject(rid, "rejected_deferred",
                                    time.perf_counter())
+                            continue
+                        if (recovery and defer_counts[rid]
+                                == evict_after_deferrals):
+                            # sustained pressure: free a victim's
+                            # blocks so the head (or the next loop's
+                            # empty-batch bypass) can make progress.
+                            # Cold cache subtrees are the cheapest
+                            # victims (no work thrown away); a live
+                            # slot pays only when the cache has nothing
+                            # cold. Exactly ONCE per head's deferral
+                            # streak: organic HBM pressure is not
+                            # relieved by freeing preallocated pool
+                            # blocks, so a persisting violation must
+                            # escalate to the max_deferrals rejection
+                            # above, not serially evict the whole live
+                            # batch
+                            freed = cache_sync(cache.evict, need) \
+                                if cache is not None else 0
+                            if not freed:
+                                v = pick_victim()
+                                if v is not None:
+                                    evict(v, "evicted", time.perf_counter())
+                        break
+                    queue.pop()
+                    try:
+                        admit(i, rid, prompt, mnt, time.perf_counter())
+                        defer_counts.pop(rid, None)
+                    except (_faults.InjectedFault, MemoryError):
+                        if not recovery:
+                            raise
+                        # transient admission failure (injected pool /
+                        # prefill fault): unwind the incarnation and
+                        # schedule its replay
+                        t_fail = time.perf_counter()
+                        s = eng._slots[i]
+                        plain = (list(prompt.prompt)
+                                 if isinstance(prompt, KVBlockPayload)
+                                 else list(map(int, prompt)))
+                        if not s.done and s.req_id == rid:
+                            evict(i, "evicted", t_fail)
+                        else:
+                            requeue(rid, plain, mnt, replays.prefix(rid),
+                                    t_fail, admitted=False)
+                if not live.any():
+                    # an empty batch ends the pipelined stream: whatever
+                    # happens next (idle sleep, admission scan) the next
+                    # dispatch opens a fresh device-idle window — a gap
+                    # measured across the break would bill queue idle
+                    # (data_wait by the step ledger's clock) as host_gap
+                    last_ready[0] = None
+                    dev_busy[0] = 0.0
+                    if not queue:
+                        if feeding():
+                            # disaggregation: prefill workers still
+                            # running — idle until a payload lands
+                            time.sleep(0.002)
+                            continue
+                        break
+                    if deferred_scan:
+                        # the guard deferred the head but the eviction
+                        # (or retirements) just emptied the batch — an
+                        # empty batch bypasses the guard, so re-scan
+                        # with a fresh clock instead of misreading the
+                        # deferral as pool-too-small
                         continue
-                    s = eng._slots[i]
-                    # accept the longest draft prefix the target's
-                    # own argmax reproduces, then the bonus token —
-                    # exactly the plain-greedy stream
-                    emit = [int(g[i, 0])]
-                    j = 0
-                    while (j < K and len(emit) < s.budget
-                           and int(toks_in[i, j + 1]) == int(g[i, j])):
-                        j += 1
-                        emit.append(int(g[i, j]))
-                    call_prop += K
-                    call_acc += j
-                    st["emitted"] += len(emit)
-                    advance(i, emit, t0c, t1c)
-                st["proposed"] += call_prop
-                st["accepted"] += call_acc
+                    next_arrival = t_start + queue.head()[3]
+                    fresh = time.perf_counter()
+                    if next_arrival > fresh:
+                        # open-loop idle: nothing live, next arrival in the
+                        # future — sleep to it (the serve ledger bills the
+                        # gap as data_wait, which it is)
+                        time.sleep(next_arrival - fresh)
+                        continue
+                    if next_arrival > now:
+                        # the head arrived BETWEEN the admission scan's
+                        # clock and this check — the scan never saw it;
+                        # retry with a fresh clock instead of
+                        # misdiagnosing an admittable head as
+                        # pool-too-small
+                        continue
+                    if cache is not None and cache.held_blocks:
+                        # last resort before declaring the pool too small:
+                        # drop the whole cache (it holds blocks the head
+                        # needs) and re-scan
+                        cache_sync(cache.evict, cache.held_blocks)
+                        continue
+                    raise MemoryError(
+                        "pool too small for even one pending request")
+                budgets = np.asarray(
+                    [eng._slots[i].budget if live[i] else 0
+                     for i in range(eng.max_slots)], np.int32)
+                # chaos site: a failed/stuck decode pass. Fires BEFORE
+                # the device call (pools intact): recovery is bounded
+                # retry with backoff — the batch re-runs the same pass
+                if _faults.active():
+                    try:
+                        _faults.inject("decode_chunk")
+                    except _faults.InjectedFault:
+                        if not recovery:
+                            raise
+                        chunk_failures += 1
+                        if chunk_failures > max_chunk_retries:
+                            raise
+                        time.sleep(min(
+                            replay_backoff_s
+                            * (2 ** (chunk_failures - 1)), 0.5))
+                        continue
+                    chunk_failures = 0
+                if spec_cfg is not None:
+                    # the chaos harness's logits-poison lane: one coin per
+                    # live slot per decode pass, applied ON DEVICE so the
+                    # non-finite detection path is exercised end to end
+                    # (the fused path fires its coins inside
+                    # dispatch_chunk — one set per dispatched chunk,
+                    # lookahead chunks included)
+                    poison = np.zeros(eng.max_slots, bool)
+                    if _faults.active():
+                        for i in range(eng.max_slots):
+                            if live[i] and _faults.fire("logits_poison"):
+                                poison[i] = True
+                    # draft-propose -> batched-verify instead of a fused
+                    # chunk: one target forward prices k+1 candidate
+                    # tokens per slot against ONE pass over the KV pool
+                    K = spec_cfg.k
+                    toks_in = np.zeros((eng.max_slots, K + 1), np.int32)
+                    toks_in[:, 0] = tokens
+                    for i in range(eng.max_slots):
+                        if live[i]:
+                            s = eng._slots[i]
+                            toks_in[i, 1:] = np.asarray(draft.propose(
+                                s.prompt + s.emitted, K), np.int32)
+                    # device-resident reuse (ISSUE 20 satellite): only the
+                    # per-pass candidate tokens and positions upload every
+                    # verify; tables/live/budgets/poison ride cached device
+                    # copies refreshed on host-value change (the verify
+                    # executable donates only the pools, so they survive)
+                    args_s = (eng._params, jnp.asarray(toks_in),
+                              jnp.asarray(seqlens),
+                              spec_dev_arr("tables", tables),
+                              spec_dev_arr("live", live),
+                              spec_dev_arr("budgets", budgets),
+                              spec_dev_arr("poison", poison), kpool, vpool)
+                    note_uploads(2)
+                    if telemetry:
+                        t0b = time.perf_counter()
+                        fn, built = eng._spec_exec(K + 1, args_s)
+                        if built:
+                            phase["compile"] += time.perf_counter() - t0b
+                    t0c = time.perf_counter()
+                    if telemetry:
+                        if last_ready[0] is not None:
+                            phase["host_gap"] += max(
+                                0.0, t0c - last_ready[0] - dev_busy[0])
+                        dev_busy[0] = 0.0
+                    with _obs.span("serve:spec_verify", k=int(K)):
+                        if telemetry:
+                            g, bad, kpool, vpool = fn(*args_s)
+                            jax.block_until_ready(g)
+                        else:
+                            g, bad, kpool, vpool = eng._spec_verify_jit(
+                                *args_s)
+                    with _obs.span("serve:wait_chunk", steps=int(K + 1)):
+                        # the pass's results reach the host here (already
+                        # there under telemetry, which synced above)
+                        g = np.asarray(g)
+                        bad = np.asarray(bad)
+                    t1c = time.perf_counter()
+                    if telemetry:
+                        phase["execute"] += t1c - t0c
+                        last_ready[0] = t1c
+                    eng.chunk_dispatches += 1
+                    eng._record_traffic(seqlens, K + 1, live, budgets,
+                                        launches=1)
+                    st = eng.spec_stats
+                    st["verify_calls"] += 1
+                    call_prop = call_acc = 0
+                    with _obs.span("serve:commit") as sp:
+                        took, live_before = 0, int(live.sum())
+                        for i in range(eng.max_slots):
+                            if not live[i]:
+                                continue
+                            if quarantine_on and bad[i]:
+                                quarantine(i, t0c, t1c,
+                                           time.perf_counter())
+                                continue
+                            s = eng._slots[i]
+                            # accept the longest draft prefix the target's
+                            # own argmax reproduces, then the bonus token —
+                            # exactly the plain-greedy stream
+                            emit = [int(g[i, 0])]
+                            j = 0
+                            while (j < K and len(emit) < s.budget
+                                   and int(toks_in[i, j + 1])
+                                   == int(g[i, j])):
+                                j += 1
+                                emit.append(int(g[i, j]))
+                            call_prop += K
+                            call_acc += j
+                            st["emitted"] += len(emit)
+                            took += len(emit)
+                            advance(i, emit, t0c, t1c)
+                        sp.set(tokens=took,
+                               retired=live_before - int(live.sum()))
+                    st["proposed"] += call_prop
+                    st["accepted"] += call_acc
+                    if telemetry:
+                        reg = _obs.registry()
+                        reg.counter(
+                            "paddle_tpu_spec_decode_verify_calls_total",
+                            "speculative batched-verify passes").inc()
+                        reg.counter(
+                            "paddle_tpu_spec_decode_proposed_total",
+                            "draft tokens proposed").inc(call_prop)
+                        reg.counter(
+                            "paddle_tpu_spec_decode_accepted_total",
+                            "draft tokens accepted by greedy "
+                            "verification").inc(call_acc)
+                else:
+                    # pipelined fused-chunk path (ISSUE 20 tentpole b):
+                    # take the in-flight chunk if one exists, dispatch the
+                    # NEXT chunk off device-resident state before the
+                    # in-flight results reach the host, then consume. A
+                    # composition change (mark_state_dirty) forces
+                    # consume-before-reupload so the mirrors include the
+                    # in-flight chunk's takes before they are snapshot.
+                    fused_steps = 0
+                    rec = pending[0]
+                    pending[0] = None
+                    if rec is not None and dev["state"] is None:
+                        consume(rec)
+                        rec = None
+                    if rec is None and live.any():
+                        rec = dispatch_chunk(max(predict_n(), 1))
+                    if rec is not None:
+                        n_eff = serial_n(rec)
+                        fused_steps = n_eff
+                        if (lookahead_on and dev["state"] is not None
+                                and n_eff == rec["n"]):
+                            # no trim pending -> the device state ahead of
+                            # this chunk is exactly what the serial loop
+                            # would feed chunk N+1: launch it now
+                            n2 = predict_n(after_n=rec["n"])
+                            if n2 >= 1:
+                                pending[0] = dispatch_chunk(
+                                    n2, after_n=rec["n"])
+                        consume(rec, n_eff)
                 if telemetry:
-                    reg = _obs.registry()
-                    reg.counter(
-                        "paddle_tpu_spec_decode_verify_calls_total",
-                        "speculative batched-verify passes").inc()
-                    reg.counter(
-                        "paddle_tpu_spec_decode_proposed_total",
-                        "draft tokens proposed").inc(call_prop)
-                    reg.counter(
-                        "paddle_tpu_spec_decode_accepted_total",
-                        "draft tokens accepted by greedy "
-                        "verification").inc(call_acc)
-            else:
-                # pipelined fused-chunk path (ISSUE 20 tentpole b):
-                # take the in-flight chunk if one exists, dispatch the
-                # NEXT chunk off device-resident state before the
-                # in-flight results reach the host, then consume. A
-                # composition change (mark_state_dirty) forces
-                # consume-before-reupload so the mirrors include the
-                # in-flight chunk's takes before they are snapshot.
-                fused_steps = 0
-                rec = pending[0]
-                pending[0] = None
-                if rec is not None and dev["state"] is None:
-                    consume(rec)
-                    rec = None
-                if rec is None and live.any():
-                    rec = dispatch_chunk(max(predict_n(), 1))
-                if rec is not None:
-                    n_eff = serial_n(rec)
-                    fused_steps = n_eff
-                    if (lookahead_on and dev["state"] is not None
-                            and n_eff == rec["n"]):
-                        # no trim pending -> the device state ahead of
-                        # this chunk is exactly what the serial loop
-                        # would feed chunk N+1: launch it now
-                        n2 = predict_n(after_n=rec["n"])
-                        if n2 >= 1:
-                            pending[0] = dispatch_chunk(
-                                n2, after_n=rec["n"])
-                    consume(rec, n_eff)
-            if telemetry:
-                eng._serve_ledger.step(
-                    it0, time.perf_counter(), compile_s=phase["compile"],
-                    execute_s=phase["execute"],
-                    host_gap_s=phase["host_gap"],
-                    extra={"live_slots": int(live.sum()),
-                           "chunk_steps": (int(spec_cfg.k + 1)
-                                           if spec_cfg is not None
-                                           else int(fused_steps))})
+                    eng._serve_ledger.step(
+                        it0, time.perf_counter(), compile_s=phase["compile"],
+                        execute_s=phase["execute"],
+                        host_gap_s=phase["host_gap"],
+                        extra={"live_slots": int(live.sum()),
+                               "chunk_steps": (int(spec_cfg.k + 1)
+                                               if spec_cfg is not None
+                                               else int(fused_steps))})
     except BaseException:
         # the engine may be unusable, but the OBSERVABILITY
         # must stay truthful: drop this call's unfinished

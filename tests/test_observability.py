@@ -153,9 +153,12 @@ class TestTrainStepTelemetry:
             "paddle_tpu_train_step_tokens_total").value() == 24
         assert reg.gauge(
             "paddle_tpu_train_step_tokens_per_second").value() > 0
-        # cost_analysis FLOPs feed the MFU gauge (may be 0 on backends
-        # that report no flops, but the gauge must exist)
-        assert reg.get("paddle_tpu_train_step_mfu_percent") is not None
+        # cost_analysis FLOPs are a count and stay (may be 0 on backends
+        # that report no flops, but the gauge must exist); the MFU gauge
+        # priced from them on the synced path is gone — a third count
+        # that agreed with neither other one and that nothing read
+        assert reg.get("paddle_tpu_train_step_flops_per_step") is not None
+        assert reg.get("paddle_tpu_train_step_mfu_percent") is None
 
     def test_no_mfu_for_a_device_without_a_known_peak(self, telemetry,
                                                       tmp_path):
