@@ -274,6 +274,16 @@ class PagedDecoder(CachedDecoder):
         self.num_blocks = int(num_blocks or
                               (self.max_slots * self.blocks_per_seq) // 2
                               + 1)
+        # the programs index a pool's token rows flat over layers and
+        # blocks (`_flat_pools`), in int32
+        rows = (self.cfg.num_hidden_layers * self.num_blocks
+                * self.block_size)
+        if rows >= 2**31:
+            raise ValueError(
+                f"KV pool of {self.cfg.num_hidden_layers} layers x "
+                f"{self.num_blocks} blocks x {self.block_size} tokens = "
+                f"{rows} token rows, past the int32 row index (2**31): "
+                f"lower num_blocks")
         self.allocator = BlockAllocator(self.num_blocks)
         self._slots = [_Slot(done=True) for _ in range(self.max_slots)]
         # prefix/radix cache (ISSUE 18): opt-in — True/"radix" builds a
@@ -373,14 +383,20 @@ class PagedDecoder(CachedDecoder):
 
     # -- pools -------------------------------------------------------------
     def new_pools(self):
+        """Fresh zero K and V pools, [L, NB, bs, Hkv, D] each (int8
+        codes paired with [L, NB, bs] f32 scales under kv_quant). That
+        is the shape everything outside the jitted programs sees: axis 1
+        is the block axis for the cache, the transport and the pager.
+        The programs donate the pools and update them in place through
+        the flat view of `_flat_pools`."""
         cfg = self.cfg
         shape = (cfg.num_hidden_layers, self.num_blocks, self.block_size,
                  self.nkv, self.hd)
         if self.kv_quant:
             # codes + per-row scales as one pytree per side: every pool
-            # consumer (scan xs, jit donation, AOT shape keys) carries
-            # the pair without signature changes. Scales init to 1 so
-            # zero codes dequantize to the zero pool.
+            # consumer (the layer loop's carry, jit donation, AOT shape
+            # keys) carries the pair without signature changes. Scales
+            # init to 1 so zero codes dequantize to the zero pool.
             sshape = shape[:3]
             return ((jnp.zeros(shape, jnp.int8),
                      jnp.ones(sshape, jnp.float32)),
@@ -411,6 +427,31 @@ class PagedDecoder(CachedDecoder):
         return (2 * self.cfg.num_hidden_layers * self.block_size
                 * self.kv_token_bytes())
 
+    # -- the pools inside a program ---------------------------------------
+    @staticmethod
+    def _flat_pools(kpool, vpool):
+        """The pools as the layer loop carries them: every leaf viewed
+        flat over layers and blocks ([L, NB, ...] -> [L*NB, ...], a
+        reshape of a contiguous array: no data moves), so that layer l's
+        block b is block l*NB + b and its token row r is row
+        (l*NB + b)*bs + r. The loop updates that one buffer in place;
+        pools passed as the scan's xs and returned as its ys would be
+        sliced out and restacked whole, layer by layer. Returns (kflat,
+        vflat, NB, layer ids [L] int32)."""
+        L, NB = jax.tree_util.tree_leaves(kpool)[0].shape[:2]
+        flat = lambda x: x.reshape((L * NB,) + x.shape[2:])
+        return (jax.tree_util.tree_map(flat, kpool),
+                jax.tree_util.tree_map(flat, vpool), NB,
+                jnp.arange(L, dtype=jnp.int32))
+
+    @staticmethod
+    def _stacked_pools(flat, like):
+        """Undo `_flat_pools` on one pool: back to ``like``'s
+        [L, NB, ...] leaves, the shape every caller outside the jitted
+        programs addresses (axis 1 is the block axis)."""
+        return jax.tree_util.tree_map(
+            lambda f, x: f.reshape(x.shape), flat, like)
+
     # -- core step ---------------------------------------------------------
     def _attend(self, q, kw, vw, pos, dtype):
         """q [S, nh, hd]; kw/vw gathered windows [S, W, nkv, hd]; pos [S]
@@ -431,9 +472,13 @@ class PagedDecoder(CachedDecoder):
 
     def _pool_write(self, kc, vc, k, v, widx):
         """Scatter one K/V token row per query row into the pools at
-        flat pool-token index widx. Quantized pools ((codes, scales)
-        pairs) quantize at write time: a token's append touches exactly
-        its own codes and one f32 scale — no neighbor requantization."""
+        flat pool-token index widx: one scatter a pool leaf, in place
+        when the pool is a loop's carry. The layer loops hand in the
+        pools flat over layers ([L*NB, bs, Hkv, D], `_flat_pools`) and
+        a widx that already holds the layer's l*NB*bs. Quantized pools
+        ((codes, scales) pairs) quantize at write time: a token's
+        append touches exactly its own codes and one f32 scale — no
+        neighbor requantization."""
         if self.kv_quant:
             from ..kernels.pallas.ragged_paged_attention import (
                 kv_quantize_rows)
@@ -454,8 +499,12 @@ class PagedDecoder(CachedDecoder):
 
     def _pool_attend(self, q, kc, vc, tables, seqlens, dtype):
         """Attention for q [S, nh, hd] against the (possibly quantized)
-        pools. Ragged path: the Pallas kernel streams blocks through the
-        table (quantized variant dequantizes in VMEM after the fetch).
+        pools [blocks, bs, Hkv, D] through tables [S, MB] of ids on the
+        pools' block axis: the layer loops pass the flat pool and
+        tables offset by l*NB, so a layer reads its own blocks and no
+        kernel knows of layers. Ragged path: the Pallas kernel streams
+        blocks through the table (quantized variant dequantizes in VMEM
+        after the fetch).
         Dense path: gather the window — dequantizing it for a quantized
         pool — and run the reference math; this stays the exact
         numerical oracle for BOTH kernels (PR 2/5 pattern)."""
@@ -515,7 +564,14 @@ class PagedDecoder(CachedDecoder):
         [L, NB, bs, Hkv, D] donated; active [S] bool (optional) marks
         slots that really advance — inactive slots route their K/V
         writes to the trash block so an exhausted-budget slot can't
-        clobber valid pool KV. Returns (logits [S, V], pools)."""
+        clobber valid pool KV. Returns (logits [S, V], pools).
+
+        The layer scan carries (x, K pool, V pool) and scans over the
+        layers' weights and ids: each layer scatters its S rows into
+        the one flat buffer (`_flat_pools`) and attends through tables
+        offset to its blocks, so no layer of a pool is sliced out,
+        restacked or copied, and the step's pool traffic is the rows it
+        writes and the blocks the kernel reads."""
         S = tokens.shape[0]
         bs = self.block_size
         x = jnp.take(params["embed"], tokens, axis=0)       # [S, H]
@@ -532,8 +588,11 @@ class PagedDecoder(CachedDecoder):
             blk = jnp.where(active, blk, 0)
         widx = blk * bs + seqlens % bs                      # [S]
 
-        def layer(x, wl_kc_vc):
-            wl, kc, vc = wl_kc_vc          # kc/vc [NB, bs, Hkv, D]
+        kflat, vflat, NB, layer_ids = self._flat_pools(kpool, vpool)
+
+        def layer(carry, wl_l):
+            x, kc, vc = carry              # kc/vc [L*NB, bs, Hkv, D]
+            wl, l = wl_l
             h1 = _rms(x, wl["ln1"], self.eps)
             q = self._layer_mm(h1, wl["wq"], dtype).reshape(
                 S, self.nh, self.hd)
@@ -543,24 +602,31 @@ class PagedDecoder(CachedDecoder):
                 S, self.nkv, self.hd)
             q = self._rope_at(q, cos[:, None, :], sin[:, None, :])
             k = self._rope_at(k, cos[:, None, :], sin[:, None, :])
-            # scatter the new K/V into the pages (trash-block writes for
-            # retired slots collide harmlessly at index < bs); one scope
-            # per role (the layer axis is a scan — all layers share the
-            # body): the memory profiler's top-K table reads
-            # decode.kv_pool / decode.attend instead of fusion numbers
+            # scatter the new K/V into layer l's pages (trash-block
+            # writes for retired slots collide harmlessly at index < bs
+            # of that layer); one scope per role (the layer axis is a
+            # scan — all layers share the body): the memory profiler's
+            # top-K table reads decode.kv_pool / decode.attend instead
+            # of fusion numbers
             with jax.named_scope("decode.kv_pool"):
-                kc, vc = self._pool_write(kc, vc, k, v, widx)
-            o = self._pool_attend(q, kc, vc, tables, seqlens, dtype)
+                kc, vc = self._pool_write(kc, vc, k, v,
+                                          l * (NB * bs) + widx)
+            # layer l's blocks sit at l*NB.. in the flat pool. The
+            # offset tables are made here, outside decode.attend: that
+            # scope's device time is the kernel's alone
+            o = self._pool_attend(q, kc, vc, tables + l * NB, seqlens,
+                                  dtype)
             x = x + self._layer_mm(o, wl["wo"], dtype)
             h2 = _rms(x, wl["ln2"], self.eps)
             g = self._layer_mm(h2, wl["wg"], dtype)
             u = self._layer_mm(h2, wl["wu"], dtype)
             x = x + self._layer_mm(jax.nn.silu(g) * u, wl["wd"], dtype)
-            return x, (kc, vc)
+            return (x, kc, vc), None
 
-        x, (kpool, vpool) = jax.lax.scan(
-            lambda x, xs: layer(x, xs), x,
-            (params["layers"], kpool, vpool))
+        (x, kflat, vflat), _ = jax.lax.scan(
+            layer, (x, kflat, vflat), (params["layers"], layer_ids))
+        kpool = self._stacked_pools(kflat, kpool)
+        vpool = self._stacked_pools(vflat, vpool)
         x = _rms(x, params["norm"], self.eps)
         return self._head_logits(params, x), kpool, vpool
 
@@ -717,7 +783,9 @@ class PagedDecoder(CachedDecoder):
         """ids [S0pad] int32; true_len scalar; table [MB]. Writes K/V
         for positions < true_len, returns the ENCODED first token (the
         argmax of the logits at position true_len-1, fused on device —
-        one int32 transfers instead of a vocab-wide row)."""
+        one int32 transfers instead of a vocab-wide row). The pools ride
+        the layer scan's carry as in `_paged_step_impl`: a prefill moves
+        the prompt's rows, not the pool."""
         S0 = ids.shape[0]
         bs = self.block_size
         x = jnp.take(params["embed"], ids, axis=0)          # [S0, H]
@@ -732,8 +800,11 @@ class PagedDecoder(CachedDecoder):
         widx = blk * bs + pos % bs                          # [S0]
         causal = pos[None, :] <= pos[:, None]               # [S0, S0]
 
-        def layer(x, wl_kc_vc):
-            wl, kc, vc = wl_kc_vc
+        kflat, vflat, NB, layer_ids = self._flat_pools(kpool, vpool)
+
+        def layer(carry, wl_l):
+            x, kc, vc = carry              # kc/vc [L*NB, bs, Hkv, D]
+            wl, l = wl_l
             h1 = _rms(x, wl["ln1"], self.eps)
             q = self._layer_mm(h1, wl["wq"], dtype).reshape(
                 S0, self.nh, self.hd)
@@ -743,11 +814,13 @@ class PagedDecoder(CachedDecoder):
                 S0, self.nkv, self.hd)
             q = self._rope_at(q, cos[:, None, :], sin[:, None, :])
             k = self._rope_at(k, cos[:, None, :], sin[:, None, :])
-            # prompt K/V land in the pages quantized when the pool is
-            # (in-prompt attention below reads the FULL-PRECISION k/v:
-            # the prompt is resident here, so its own pass pays no
-            # quantization error — only later reads through the pool do)
-            kc, vc = self._pool_write(kc, vc, k, v, widx)
+            # prompt K/V land in layer l's pages, quantized when the
+            # pool is (in-prompt attention below reads the
+            # FULL-PRECISION k/v: the prompt is resident here, so its
+            # own pass pays no quantization error — only later reads
+            # through the pool do)
+            kc, vc = self._pool_write(kc, vc, k, v,
+                                      l * (NB * bs) + widx)
             # in-prompt causal attention (no window gather needed: the
             # prompt IS contiguous here)
             qg = q.reshape(S0, self.nkv, nrep, self.hd)
@@ -763,11 +836,12 @@ class PagedDecoder(CachedDecoder):
             g = self._layer_mm(h2, wl["wg"], dtype)
             u = self._layer_mm(h2, wl["wu"], dtype)
             x = x + self._layer_mm(jax.nn.silu(g) * u, wl["wd"], dtype)
-            return x, (kc, vc)
+            return (x, kc, vc), None
 
-        x, (kpool, vpool) = jax.lax.scan(
-            lambda x, xs: layer(x, xs), x,
-            (params["layers"], kpool, vpool))
+        (x, kflat, vflat), _ = jax.lax.scan(
+            layer, (x, kflat, vflat), (params["layers"], layer_ids))
+        kpool = self._stacked_pools(kflat, kpool)
+        vpool = self._stacked_pools(vflat, vpool)
         last = jnp.take(x, jnp.maximum(true_len - 1, 0), axis=0)
         last = _rms(last[None], params["norm"], self.eps)
         logits = self._head_logits(params, last)[0]
