@@ -58,8 +58,9 @@ import numpy as np
 
 from ._x64 import i32_trace
 
-__all__ = ["grouped_matmul", "grouped_metadata", "aligned_group_size",
-           "record_moe_dispatch", "DEFAULT_BM", "default_block_m"]
+__all__ = ["grouped_matmul", "grouped_matmul_sorted", "grouped_metadata",
+           "aligned_group_size", "record_moe_dispatch", "DEFAULT_BM",
+           "default_block_m"]
 
 
 def _interpret():
@@ -470,6 +471,76 @@ def grouped_matmul(x, w, b=None, *, group_offsets, group_counts,
     fn = _gmm_vjp(int(bm), int(bn), str(impl),
                   None if b is None else str(b.dtype))
     return fn(x, w, b, group_offsets, group_counts)
+
+
+# -- forward-only, rows sorted by group with no padding (serving) ------------
+
+def _sorted_tile(n, cap):
+    """Largest multiple of 128 that divides n and is <= cap; n itself
+    when it has none (a toy width)."""
+    for c in range(min(cap, n) // 128 * 128, 0, -128):
+        if n % c == 0:
+            return c
+    return n
+
+
+def _sorted_reference(x, w, group_sizes):
+    """out[r] = x[r] @ w[group of r]; rows past the groups give 0."""
+    ends = jnp.cumsum(group_sizes, dtype=jnp.int32)
+    rows = jnp.arange(x.shape[0], dtype=jnp.int32)
+    group = jnp.sum(rows[:, None] >= ends[None, :], axis=1, dtype=jnp.int32)
+    inside = group < w.shape[0]
+    wg = w[jnp.minimum(group, w.shape[0] - 1)]
+    out = jnp.einsum("mk,mkn->mn", x, wg,
+                     preferred_element_type=jnp.float32)
+    return jnp.where(inside[:, None], out, 0.0)
+
+
+def grouped_matmul_sorted(x, w, group_sizes, *, impl="auto"):
+    """Per-group matmul over rows SORTED by group with no padding
+    between groups: rows `sum(sizes[:e]) .. sum(sizes[:e+1])` of x [M, K]
+    meet w[e] of w [E, K, N]; `group_sizes` [E] int32 may sum to less
+    than M, and rows past that sum are not computed (their output is
+    unspecified: select them away, never scale them). Returns [M, N]
+    float32. Forward only: the serving path of an expert layer, where a
+    step brings a handful of rows to each of many experts and the
+    tile-aligned layout of `grouped_matmul` (a grid over every expert's
+    worst-case tiles) would spend the step skipping.
+
+    impl "auto": on a TPU the grouped kernel that ships with JAX
+    (`jax.experimental.pallas.ops.tpu.megablox.gmm`: a grid over the
+    row tiles that groups touch, the group's weights found through
+    scalar-prefetched ids, an expert's weights streamed once per
+    visit), elsewhere a gathered-weight XLA product. "kernel" forces
+    the kernel (interpreted off the TPU), "reference" the XLA product.
+    """
+    if impl == "reference" or (impl == "auto" and _interpret()):
+        return _sorted_reference(x, w, group_sizes)
+    import importlib
+    # the package's `gmm` attribute is its custom_vjp wrapper; the module
+    # of the same name holds the forward kernel itself
+    _gmm = importlib.import_module(
+        "jax.experimental.pallas.ops.tpu.megablox.gmm")
+    m, k = x.shape
+    n = w.shape[2]
+    tm = 128
+    pad = (-m) % tm
+    if pad:
+        x = jnp.pad(x, ((0, pad), (0, 0)))
+    # traced with x64 off: the kernel's tile counts and index maps must
+    # stay 32-bit (`_x64.i32_trace`). And at the default matmul
+    # precision: the package sets "highest" for the whole process, which
+    # Mosaic refuses for a kernel's bf16 operands ("Bad lhs type")
+    # (the plain function under JAX's own jit wrapper, so that the
+    # kernel's instruction carries the caller's `jax.named_scope`)
+    gmm = getattr(_gmm.gmm, "__wrapped__", _gmm.gmm)
+    with jax.default_matmul_precision("default"):
+        out = i32_trace(gmm)(
+            x, w, group_sizes.astype(jnp.int32),
+            preferred_element_type=jnp.float32,
+            tiling=(tm, _sorted_tile(k, 1024), _sorted_tile(n, 1024)),
+            interpret=_interpret())
+    return out[:m] if pad else out
 
 
 # -- host-side telemetry -----------------------------------------------------

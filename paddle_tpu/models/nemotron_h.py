@@ -1,0 +1,824 @@
+"""The `nemotron_h` family: Mamba-2 + attention + LatentMoE hybrids
+(NVIDIA-Nemotron-3-Super-120B-A12B is the published member served here).
+
+A block holds ONE mixer and no MLP: `x <- x + Mixer_l(RMSNorm(x))`, the
+mixer's kind read from `hybrid_override_pattern` (M: Mamba-2, *: GQA
+attention without a positional term, E: LatentMoE with a shared expert).
+The mixers are pure functions of (weights, activations), written once:
+`NemotronHForCausalLM.forward` (the dygraph model, full sequences) and
+`HybridPagedDecoder` (serving: prefill into the caches, then decode
+through them) call the same ones.
+
+Serving keeps two kinds of cache side by side: the paged K and V pools
+of the attention blocks only, found through the block tables, and a
+fixed-size recurrent state per slot for the Mamba blocks (the SSM state
+in float32 and the conv's last rows). `PagedDecoder.serve` drives both
+through the one `serving.batcher.serve_loop`.
+
+The expert layer is told which experts it holds (`experts_held = (first,
+count)`): it routes over the published router width, computes the part
+of the result its own experts give and adds the shared expert. On one
+chip there is no exchange and nothing stands in for the absent chips.
+"""
+from __future__ import annotations
+
+import math
+
+import numpy as np
+import jax
+import jax.numpy as jnp
+
+from ..framework.tensor import Parameter, Tensor
+from ..nn.layer.layers import Layer
+from .decode import _rms
+from .paged_decode import PagedDecoder
+
+__all__ = ["NemotronHConfig", "NemotronHForCausalLM", "HybridPagedDecoder",
+           "nemotron_h_tiny"]
+
+F32 = jnp.float32
+
+
+class NemotronHConfig:
+    """The published keys of a `nemotron_h` `config.json` that shape the
+    language model, under their own names, plus `experts_held` (which
+    routed experts this chip holds; default all) and `dtype`."""
+
+    def __init__(self, vocab_size=131072, hidden_size=4096,
+                 num_hidden_layers=None, hybrid_override_pattern="M*E",
+                 num_attention_heads=32, num_key_value_heads=2,
+                 head_dim=128, mamba_num_heads=128, mamba_head_dim=64,
+                 ssm_state_size=128, n_groups=8, conv_kernel=4,
+                 chunk_size=128, n_routed_experts=512,
+                 num_experts_per_tok=22, moe_latent_size=1024,
+                 moe_intermediate_size=2688,
+                 moe_shared_expert_intermediate_size=5376,
+                 routed_scaling_factor=5.0, norm_topk_prob=True,
+                 layer_norm_epsilon=1e-5, max_position_embeddings=4096,
+                 experts_held=None, dtype="float32"):
+        pattern = str(hybrid_override_pattern)
+        if set(pattern) - set("M*E") or not pattern:
+            raise ValueError(f"hybrid_override_pattern {pattern!r} holds "
+                             f"a block kind other than M, * and E")
+        if num_hidden_layers is not None and \
+                int(num_hidden_layers) != len(pattern):
+            raise ValueError(
+                f"num_hidden_layers {num_hidden_layers} against a pattern "
+                f"of {len(pattern)} blocks")
+        if mamba_num_heads % n_groups:
+            raise ValueError("mamba_num_heads must divide into n_groups")
+        self.vocab_size = vocab_size
+        self.hidden_size = hidden_size
+        self.hybrid_override_pattern = pattern
+        self.num_hidden_layers = len(pattern)
+        self.num_attention_heads = num_attention_heads
+        self.num_key_value_heads = num_key_value_heads
+        self.head_dim = head_dim
+        self.mamba_num_heads = mamba_num_heads
+        self.mamba_head_dim = mamba_head_dim
+        self.ssm_state_size = ssm_state_size
+        self.n_groups = n_groups
+        self.conv_kernel = conv_kernel
+        self.chunk_size = chunk_size
+        self.n_routed_experts = n_routed_experts
+        self.num_experts_per_tok = num_experts_per_tok
+        self.moe_latent_size = moe_latent_size
+        self.moe_intermediate_size = moe_intermediate_size
+        self.moe_shared_expert_intermediate_size = \
+            moe_shared_expert_intermediate_size
+        self.routed_scaling_factor = float(routed_scaling_factor)
+        self.norm_topk_prob = bool(norm_topk_prob)
+        self.layer_norm_epsilon = layer_norm_epsilon
+        self.max_position_embeddings = max_position_embeddings
+        first, count = experts_held or (0, n_routed_experts)
+        if not 0 <= first <= first + count <= n_routed_experts:
+            raise ValueError(f"experts_held {(first, count)} outside the "
+                             f"router's {n_routed_experts}")
+        self.experts_held = (int(first), int(count))
+        self.dtype = dtype
+
+    @property
+    def mamba_inner(self):
+        return self.mamba_num_heads * self.mamba_head_dim
+
+    @property
+    def conv_dim(self):
+        return self.mamba_inner + 2 * self.n_groups * self.ssm_state_size
+
+    def count(self, kind):
+        return self.hybrid_override_pattern.count(kind)
+
+    def param_shapes(self):
+        """Ordered {parameter name: (shape, float32 only?)}. Matrices are
+        [in, out]; an expert stack is [experts held, in, out]."""
+        h, v = self.hidden_size, self.vocab_size
+        heads, d_in = self.mamba_num_heads, self.mamba_inner
+        nh, nkv, ad = (self.num_attention_heads, self.num_key_value_heads,
+                       self.head_dim)
+        lat, f = self.moe_latent_size, self.moe_intermediate_size
+        fs = self.moe_shared_expert_intermediate_size
+        held = self.experts_held[1]
+        out = {"embed": ((v, h), False)}
+        for i, kind in enumerate(self.hybrid_override_pattern):
+            pre = f"layers.{i}."
+            out[pre + "norm"] = ((h,), False)
+            if kind == "M":
+                out.update({
+                    pre + "in_proj": ((h, d_in + self.conv_dim + heads),
+                                      False),
+                    pre + "conv_w": ((self.conv_dim, self.conv_kernel),
+                                     False),
+                    pre + "conv_b": ((self.conv_dim,), False),
+                    pre + "A_log": ((heads,), True),
+                    pre + "D": ((heads,), True),
+                    pre + "dt_bias": ((heads,), True),
+                    pre + "gnorm": ((d_in,), False),
+                    pre + "out_proj": ((d_in, h), False)})
+            elif kind == "*":
+                out.update({
+                    pre + "wq": ((h, nh * ad), False),
+                    pre + "wk": ((h, nkv * ad), False),
+                    pre + "wv": ((h, nkv * ad), False),
+                    pre + "wo": ((nh * ad, h), False)})
+            else:
+                out.update({
+                    pre + "router": ((h, self.n_routed_experts), True),
+                    pre + "b_corr": ((self.n_routed_experts,), True),
+                    pre + "w_down": ((h, lat), False),
+                    pre + "w_up": ((lat, h), False),
+                    pre + "w1": ((held, lat, f), False),
+                    pre + "w2": ((held, f, lat), False),
+                    pre + "ws1": ((h, fs), False),
+                    pre + "ws2": ((fs, h), False)})
+        out["norm"] = ((h,), False)
+        out["head"] = ((h, v), False)
+        return out
+
+
+def nemotron_h_tiny(**overrides):
+    """A size the CPU tests hold: every mechanism of the family (grouped
+    B and C, a conv of 4 taps, GQA, a router wider than the experts
+    held, a shared expert) at toy widths."""
+    kw = dict(vocab_size=256, hidden_size=64,
+              hybrid_override_pattern="MEM*E", num_attention_heads=4,
+              num_key_value_heads=2, head_dim=16, mamba_num_heads=8,
+              mamba_head_dim=8, ssm_state_size=16, n_groups=2,
+              conv_kernel=4, chunk_size=8, n_routed_experts=16,
+              num_experts_per_tok=4, moe_latent_size=32,
+              moe_intermediate_size=48,
+              moe_shared_expert_intermediate_size=96,
+              max_position_embeddings=256)
+    kw.update(overrides)
+    return NemotronHConfig(**kw)
+
+
+# -- the mixers, as pure functions ------------------------------------------------
+
+def relu2(x):
+    return jnp.square(jnp.maximum(x, 0))
+
+
+def mamba_project(cfg, p, u):
+    """`[z | xBC | dt] = u W_in` for u [T, H]."""
+    d_in, cd = cfg.mamba_inner, cfg.conv_dim
+    zxbcdt = u @ p["in_proj"].astype(u.dtype)
+    return (zxbcdt[:, :d_in], zxbcdt[:, d_in:d_in + cd],
+            zxbcdt[:, d_in + cd:])
+
+
+def mamba_split(cfg, xbc):
+    """conv output [T, conv_dim] -> x [T, heads, hd], B and C [T, G, N]."""
+    t, d_in = xbc.shape[0], cfg.mamba_inner
+    gn = cfg.n_groups * cfg.ssm_state_size
+    return (xbc[:, :d_in].reshape(t, cfg.mamba_num_heads,
+                                  cfg.mamba_head_dim),
+            xbc[:, d_in:d_in + gn].reshape(t, cfg.n_groups,
+                                           cfg.ssm_state_size),
+            xbc[:, d_in + gn:].reshape(t, cfg.n_groups,
+                                       cfg.ssm_state_size))
+
+
+def step_sizes(p, dt):
+    """(dt after softplus [T, heads], A [heads]), both float32."""
+    return (jax.nn.softplus(dt.astype(F32) + p["dt_bias"].astype(F32)),
+            -jnp.exp(p["A_log"].astype(F32)))
+
+
+def conv_sequence(xbc, w, b, true_len):
+    """Causal depthwise conv over one sequence xbc [T, C] (zeros before
+    the start) and silu. Also the conv state a decode step continues
+    from: the last K-1 rows before `true_len` (zeros where the sequence
+    is shorter), so a bucket padded behind hands over the state of
+    `true_len`, not of its padding."""
+    k = w.shape[1]
+    t = xbc.shape[0]
+    padded = jnp.concatenate(
+        [jnp.zeros((k - 1, xbc.shape[1]), xbc.dtype), xbc])
+    wf = w.astype(F32)
+    out = b.astype(F32)[None, :]
+    for j in range(k):
+        out = out + padded[j:j + t].astype(F32) * wf[:, j][None, :]
+    state = jax.lax.dynamic_slice_in_dim(padded, true_len, k - 1, axis=0)
+    return jax.nn.silu(out).astype(xbc.dtype), state
+
+
+def conv_step(state, xbc, w, b):
+    """One position for every slot: state [S, K-1, C] (the rows before),
+    xbc [S, C]. Returns (silu(conv) [S, C], the state one row on)."""
+    window = jnp.concatenate([state, xbc[:, None].astype(state.dtype)], 1)
+    out = jnp.einsum("skc,ck->sc", window.astype(F32), w.astype(F32)) \
+        + b.astype(F32)[None, :]
+    return jax.nn.silu(out).astype(xbc.dtype), window[:, 1:]
+
+
+def ssm_step(state, x, b, c, dt, a, d):
+    """The recurrence's one step for every slot. state [S, heads, hd, N]
+    float32; x [S, heads, hd]; b, c [S, G, N]; dt [S, heads] (after
+    softplus); a, d [heads]. `S_t = exp(dt A) S + dt x (x) B`,
+    `y = S_t C + D x`. Returns (y [S, heads, hd] float32, S_t)."""
+    s, heads, hd, n = state.shape
+    g = b.shape[1]
+    r = heads // g
+    st = state.reshape(s, g, r, hd, n)
+    xf = x.astype(F32).reshape(s, g, r, hd)
+    decay = jnp.exp(dt * a[None, :]).reshape(s, g, r, 1, 1)
+    dtx = dt.reshape(s, g, r, 1) * xf
+    new = st * decay + dtx[..., None] * b.astype(F32)[:, :, None, None, :]
+    y = jnp.sum(new * c.astype(F32)[:, :, None, None, :], axis=-1) \
+        + d.astype(F32).reshape(1, g, r, 1) * xf
+    return y.reshape(s, heads, hd), new.reshape(s, heads, hd, n)
+
+
+def ssd_chunked(x, b, c, dt, a, d, chunk, state0=None):
+    """The same sum as `ssm_step` over one whole sequence, in chunks
+    (the SSD form): inside a chunk a masked, decay-weighted product of
+    `C B^T` with x; between chunks the state carried by a short scan.
+    x [T, heads, hd]; b, c [T, G, N]; dt [T, heads] float32 after
+    softplus, 0 at padded positions (a position with dt = 0 neither
+    decays nor feeds the state); a, d [heads]. T is padded up to a
+    multiple of `chunk` here. Returns (y [T, heads, hd] float32, the
+    state after the last position [heads, hd, N] float32)."""
+    t, heads, hd = x.shape
+    g, n = b.shape[1], b.shape[2]
+    r = heads // g
+    q = min(int(chunk), t)
+    pad = (-t) % q
+    if pad:
+        x, b, c = (jnp.pad(v, ((0, pad), (0, 0), (0, 0))) for v in (x, b, c))
+        dt = jnp.pad(dt, ((0, pad), (0, 0)))
+    nc = (t + pad) // q
+    xf = x.astype(F32).reshape(nc, q, g, r, hd)
+    bf = b.astype(F32).reshape(nc, q, g, n)
+    cf = c.astype(F32).reshape(nc, q, g, n)
+    dtc = dt.reshape(nc, q, g, r)
+    cum = jnp.cumsum(dtc * a.reshape(1, 1, g, r), axis=1)  # [nc,q,g,r] <= 0
+    # inside a chunk: y_t = sum_{s<=t} exp(cum_t - cum_s) dt_s (C_t.B_s) x_s
+    cb = jnp.einsum("ctgn,csgn->cgts", cf, bf)
+    diff = cum.transpose(0, 2, 3, 1)[..., :, None] \
+        - cum.transpose(0, 2, 3, 1)[..., None, :]          # [nc,g,r,t,s]
+    mask = jnp.tril(jnp.ones((q, q), bool))
+    decay = jnp.exp(jnp.where(mask, diff, -jnp.inf))
+    m = decay * cb[:, :, None] * dtc.transpose(0, 2, 3, 1)[..., None, :]
+    y = jnp.einsum("cgrts,csgrp->ctgrp", m, xf)
+    # each chunk's own contribution to the state at its end
+    to_end = jnp.exp(cum[:, -1:] - cum) * dtc               # [nc,q,g,r]
+    own = jnp.einsum("csgr,csgrp,csgn->cgrpn", to_end, xf, bf)
+    total = jnp.exp(cum[:, -1])                             # [nc,g,r]
+    if state0 is None:
+        state0 = jnp.zeros((heads, hd, n), F32)
+
+    def carry(state, xs):
+        own_c, total_c = xs
+        return state * total_c[..., None, None] + own_c, state
+    last, before = jax.lax.scan(carry, state0.reshape(g, r, hd, n),
+                                (own, total))
+    # what the state before each chunk adds: exp(cum_t) C_t S_before
+    y = y + jnp.einsum("ctgn,cgrpn->ctgrp", cf, before) \
+        * jnp.exp(cum)[..., None]
+    y = y + d.astype(F32).reshape(1, 1, g, r, 1) * xf
+    return (y.reshape(nc * q, heads, hd)[:t], last.reshape(heads, hd, n))
+
+
+def gated_group_norm(y, z, w, groups, eps):
+    """`RMSNorm_group(y * silu(z); w)`: the gate first, then each of the
+    `groups` groups of channels normalised on its own. y, z [T, d_in]."""
+    t, width = y.shape
+    gated = (y.astype(F32) * jax.nn.silu(z.astype(F32))) \
+        .reshape(t, groups, width // groups)
+    gated = gated * jax.lax.rsqrt(
+        jnp.mean(gated * gated, -1, keepdims=True) + eps)
+    return gated.reshape(t, width) * w.astype(F32)
+
+
+def mamba_sequence(cfg, p, u, true_len):
+    """The Mamba-2 mixer over one sequence u [T, H] of which `true_len`
+    positions are real. Returns (out [T, H], SSM state at `true_len`,
+    conv state at `true_len`)."""
+    z, xbc, dt = mamba_project(cfg, p, u)
+    xbc, conv_state = conv_sequence(xbc, p["conv_w"], p["conv_b"], true_len)
+    x, b, c = mamba_split(cfg, xbc)
+    dt, a = step_sizes(p, dt)
+    valid = jnp.arange(u.shape[0], dtype=jnp.int32) < true_len
+    dt = jnp.where(valid[:, None], dt, 0.0)
+    with jax.named_scope("prefill.ssm_scan"):
+        y, state = ssd_chunked(x, b, c, dt, a, p["D"], cfg.chunk_size)
+    y = gated_group_norm(y.reshape(u.shape[0], -1), z, p["gnorm"],
+                         cfg.n_groups, cfg.layer_norm_epsilon)
+    return y.astype(u.dtype) @ p["out_proj"].astype(u.dtype), state, \
+        conv_state
+
+
+def mamba_decode(cfg, p, u, state, conv_state, active=None):
+    """One position for every slot: u [S, H], state [S, heads, hd, N]
+    float32, conv_state [S, K-1, C]. A slot that is not `active` keeps
+    its state. Returns (out [S, H], state, conv_state)."""
+    z, xbc, dt = mamba_project(cfg, p, u)
+    xbc, conv_new = conv_step(conv_state, xbc, p["conv_w"], p["conv_b"])
+    x, b, c = mamba_split(cfg, xbc)
+    dt, a = step_sizes(p, dt)
+    with jax.named_scope("decode.ssm_update"):
+        y, new = ssm_step(state, x, b, c, dt, a, p["D"])
+        if active is not None:
+            new = jnp.where(active[:, None, None, None], new, state)
+    if active is not None:
+        conv_new = jnp.where(active[:, None, None], conv_new, conv_state)
+    y = gated_group_norm(y.reshape(u.shape[0], -1), z, p["gnorm"],
+                         cfg.n_groups, cfg.layer_norm_epsilon)
+    return y.astype(u.dtype) @ p["out_proj"].astype(u.dtype), new, conv_new
+
+
+def attention_sequence(cfg, p, u):
+    """Causal GQA over one sequence u [T, H] with no positional term.
+    Returns (out [T, H], k [T, nkv, hd], v [T, nkv, hd])."""
+    t = u.shape[0]
+    nh, nkv, hd = (cfg.num_attention_heads, cfg.num_key_value_heads,
+                   cfg.head_dim)
+    q = (u @ p["wq"].astype(u.dtype)).reshape(t, nkv, nh // nkv, hd)
+    k = (u @ p["wk"].astype(u.dtype)).reshape(t, nkv, hd)
+    v = (u @ p["wv"].astype(u.dtype)).reshape(t, nkv, hd)
+    pos = jnp.arange(t, dtype=jnp.int32)
+    att = jnp.einsum("qgnd,kgd->gnqk", q.astype(F32), k.astype(F32)) \
+        / math.sqrt(hd)
+    att = jnp.where((pos[None, :] <= pos[:, None])[None, None], att, -1e30)
+    o = jnp.einsum("gnqk,kgd->qgnd", jax.nn.softmax(att, axis=-1),
+                   v.astype(F32)).astype(u.dtype)
+    return o.reshape(t, nh * hd) @ p["wo"].astype(u.dtype), k, v
+
+
+def moe_route(cfg, p, u):
+    """Routing over the whole published router: (expert ids [T, k],
+    weights [T, k] float32). `s = sigmoid(u W_r)` in float32; the
+    correction bias takes part in the choice only; the weights are `s`
+    normalised over all k chosen, held here or not, times the routed
+    scaling factor."""
+    logits = jnp.dot(u.astype(F32), p["router"].astype(F32),
+                     precision=jax.lax.Precision.HIGHEST)
+    s = jax.nn.sigmoid(logits)
+    _, idx = jax.lax.top_k(s + p["b_corr"].astype(F32)[None, :],
+                           cfg.num_experts_per_tok)
+    picked = jnp.take_along_axis(s, idx, axis=1)
+    if cfg.norm_topk_prob:
+        picked = picked / (jnp.sum(picked, -1, keepdims=True) + 1e-20)
+    return idx.astype(jnp.int32), picked * cfg.routed_scaling_factor
+
+
+def moe_experts(cfg, p, v, idx, weights, active=None):
+    """The held experts' part of the routed sum: `sum over chosen k held
+    here of w_k relu(v W1_k)^2 W2_k` for v [T, latent]. The token-expert
+    pairs are sorted by held expert (pairs of experts held elsewhere,
+    and of rows that are not `active`, go last and are not computed) and
+    the two products run grouped over exactly the rows each expert got:
+    no capacity, no dropped pair. Returns (r [T, latent] float32, counts
+    int32 [4]: pairs computed here, pairs routed anywhere, held experts
+    that got a row, the most rows one expert got; `merge_counts` adds
+    them up)."""
+    from ..kernels.pallas.grouped_matmul import grouped_matmul_sorted
+    first, count = cfg.experts_held
+    t, k = idx.shape
+    local = idx - first
+    here = (local >= 0) & (local < count)
+    rows = jnp.ones((t,), bool) if active is None else active
+    here = here & rows[:, None]
+    key = jnp.where(here, local, count).reshape(-1)          # [T*k]
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    sizes = jnp.zeros((count + 1,), jnp.int32).at[key].add(1)[:count]
+    with jax.named_scope("moe.experts"):
+        xs = jnp.take(v, order // k, axis=0)
+        h = grouped_matmul_sorted(xs, p["w1"], sizes)
+        y = grouped_matmul_sorted(relu2(h).astype(v.dtype), p["w2"], sizes)
+    n_here = jnp.sum(sizes, dtype=jnp.int32)
+    w_sorted = jnp.take(weights.reshape(-1), order)
+    # rows past the held pairs were never written: select, do not scale
+    wy = jnp.where((jnp.arange(t * k, dtype=jnp.int32) < n_here)[:, None],
+                   y.astype(F32) * w_sorted[:, None], 0.0)
+    back = jnp.zeros((t * k,), jnp.int32).at[order].set(
+        jnp.arange(t * k, dtype=jnp.int32))
+    r = jnp.sum(jnp.take(wy, back, axis=0).reshape(t, k, -1), axis=1)
+    counts = jnp.stack([n_here,
+                        jnp.sum(rows, dtype=jnp.int32) * jnp.int32(k),
+                        jnp.sum(sizes > 0, dtype=jnp.int32),
+                        jnp.max(sizes)])
+    return r, counts
+
+
+NO_COUNTS = np.zeros(4, np.int32)
+
+
+def merge_counts(a, b):
+    """Counts of `moe_experts` over two calls: sums, and the larger of
+    the two largest loads."""
+    return jnp.concatenate([a[:3] + b[:3], jnp.maximum(a[3:], b[3:])])
+
+
+def latent_moe(cfg, p, u, active=None):
+    """The LatentMoE mixer for u [T, H]: the routed experts work in the
+    latent space (`u W_down`, back through `W_up`), the shared expert on
+    the hidden state itself. Returns (out [T, H], counts)."""
+    with jax.named_scope("moe.route"):
+        idx, weights = moe_route(cfg, p, u)
+    v = u @ p["w_down"].astype(u.dtype)
+    r, counts = moe_experts(cfg, p, v, idx, weights, active)
+    shared = relu2(u @ p["ws1"].astype(u.dtype)) @ p["ws2"].astype(u.dtype)
+    return r.astype(u.dtype) @ p["w_up"].astype(u.dtype) + shared, counts
+
+
+def forward_sequence(cfg, params, ids):
+    """Full causal forward over one sequence ids [T]: logits [T, V]
+    float32."""
+    x = jnp.take(params["embed"], ids, axis=0)
+    t = ids.shape[0]
+    for i, kind in enumerate(cfg.hybrid_override_pattern):
+        p = params["layers"][i]
+        u = _rms(x, p["norm"], cfg.layer_norm_epsilon)
+        if kind == "M":
+            x = x + mamba_sequence(cfg, p, u, t)[0]
+        elif kind == "*":
+            x = x + attention_sequence(cfg, p, u)[0]
+        else:
+            x = x + latent_moe(cfg, p, u)[0]
+    x = _rms(x, params["norm"], cfg.layer_norm_epsilon)
+    return x.astype(F32) @ params["head"].astype(F32)
+
+
+# -- the dygraph model ----------------------------------------------------------------
+
+class NemotronHForCausalLM(Layer):
+    """The dygraph model: parameters under the names of
+    `NemotronHConfig.param_shapes`, `forward(input_ids [B, T])` gives
+    logits [B, T, V]. `arrays` ({name: jax array}) become the parameters
+    as they are, without a second copy on the device; without it the
+    parameters are drawn normal(0, 0.02) (norms and D one, a modest
+    seeded step size), which is what the CPU tests use."""
+
+    def __init__(self, config: NemotronHConfig, arrays=None):
+        super().__init__()
+        self.config = config
+        dt = jnp.bfloat16 if config.dtype == "bfloat16" else jnp.float32
+        shapes = config.param_shapes()
+        if arrays is not None:
+            missing = set(shapes) - set(arrays)
+            if missing:
+                raise KeyError(f"no array for {sorted(missing)}")
+        rng = np.random.default_rng(0)
+        self._names = {}
+        for name, (shape, f32_only) in shapes.items():
+            want = F32 if f32_only else dt
+            if arrays is not None:
+                data = arrays[name]
+                if tuple(data.shape) != tuple(shape) or data.dtype != want:
+                    raise ValueError(
+                        f"{name}: given {tuple(data.shape)} {data.dtype}, "
+                        f"the model wants {tuple(shape)} {want.__name__}")
+            else:
+                data = jnp.asarray(self._draw(rng, name, shape), want)
+            attr = name.replace(".", "_")
+            self._names[name] = attr
+            setattr(self, attr, Parameter(data))
+
+    @staticmethod
+    def _draw(rng, name, shape):
+        kind = name.rsplit(".", 1)[-1]
+        if kind in ("norm", "gnorm", "D"):
+            return np.ones(shape, np.float32)
+        if kind == "A_log":
+            return np.log(rng.uniform(1.0, 16.0, shape))
+        if kind == "dt_bias":
+            step = np.exp(rng.uniform(np.log(1e-3), np.log(1e-1), shape))
+            return step + np.log(-np.expm1(-step))
+        if kind in ("conv_w", "conv_b"):
+            return rng.uniform(-0.5, 0.5, shape)
+        if kind == "b_corr":
+            return rng.normal(0.0, 0.01, shape)
+        return rng.normal(0.0, 0.02, shape)
+
+    def param_tree(self):
+        """The parameters as the mixers take them: {"embed", "norm",
+        "head", "layers": [one dict a block]}; the arrays themselves,
+        no copy."""
+        tree = {"layers": [{} for _ in self.config.hybrid_override_pattern]}
+        for name, attr in self._names.items():
+            data = getattr(self, attr)._data
+            if name.startswith("layers."):
+                _, i, leaf = name.split(".")
+                tree["layers"][int(i)][leaf] = data
+            else:
+                tree[name] = data
+        return tree
+
+    def forward(self, input_ids):
+        ids = input_ids._data if isinstance(input_ids, Tensor) \
+            else jnp.asarray(input_ids)
+        cfg, params = self.config, self.param_tree()
+        logits = jax.vmap(lambda row: forward_sequence(cfg, params, row))(
+            ids.astype(jnp.int32))
+        return Tensor(logits)
+
+
+# -- serving: the paged pools and the recurrent state side by side -----------------------
+
+class HybridPagedDecoder(PagedDecoder):
+    """`PagedDecoder` for a `nemotron_h` model (`PagedDecoder(model)`
+    builds this class when the model's configuration carries a layer
+    pattern). The serve loop is the one every engine runs; what differs
+    is the cache it carries chunk to chunk:
+
+        (kpool, vpool,                       attention blocks only
+         ssm  [M layers, slots, heads, hd, N] float32,
+         conv [M layers, slots, K-1, conv_dim])
+
+    all four donated and updated in place. Admission overwrites the
+    slot's rows of `ssm` and `conv` with the prefill's state at the
+    prompt's true length; a slot that is not live may step, but its
+    state is never read before the next admission overwrites it.
+
+    The layer loop is unrolled over the pattern with static indices into
+    per-block weights: each block's arrays are the model's own, never
+    stacked or copied. What does not compose with a recurrent state
+    refuses at construction (or, for `serve()` options, at the call)
+    with a NotImplementedError that names the option."""
+
+    # a cache written past the host's view cannot be rewound: the loop
+    # must not run a look-ahead chunk whose length an eos may cut
+    _cache_rewinds = False
+
+    REFUSED = {
+        "weight_quant": "the per-kind weights have no quantized form",
+        "kv_quant": "the pools would quantize, the recurrent state not",
+        "prefix_cache": "a shared prefix has KV blocks to map but no "
+                        "snapshot of the recurrent state at its end",
+        "prefix_cache_blocks": "it sizes the prefix cache",
+        "attn_shards": "context-sharded attention has not been tried "
+                       "beside the state",
+        "shard_block_budget": "it picks attn_shards",
+        "prefill_chunk": "chunked prefill runs through the warm prefill",
+        "kv_offload": "page-out moves KV blocks, not recurrent state",
+        "hbm_budget_gib": "it prices kv_offload",
+    }
+
+    def __init__(self, model, max_len=None, block_size=64, num_blocks=None,
+                 max_slots=8, headroom_guard=None, ragged_kernel=None,
+                 pipelined_admission=False, **refused):
+        for name, value in refused.items():
+            if name not in self.REFUSED:
+                raise TypeError(f"unexpected argument {name!r}")
+            if value not in (None, False):
+                raise NotImplementedError(
+                    f"{name} does not compose with recurrent layers: "
+                    f"{self.REFUSED[name]}")
+        super().__init__(model, max_len=max_len, block_size=block_size,
+                         num_blocks=num_blocks, max_slots=max_slots,
+                         headroom_guard=headroom_guard,
+                         ragged_kernel=ragged_kernel,
+                         pipelined_admission=pipelined_admission)
+        # same programs as the parent's, with the state pools donated too
+        self._paged_chunk_state_jit = jax.jit(
+            self._paged_chunk_state_impl,
+            donate_argnums=(1, 2, 4, 5, 7, 8, 9, 10),
+            static_argnums=(11, 12))
+        # the parent's other programs (plain chunk, single step, verify,
+        # COW copy) serve options this engine refuses
+        self._paged_chunk_jit = self._paged_step_jit = None
+        self._spec_verify_jit = self._cow_copy_jit = None
+
+    def _prepare_weights(self, model, max_len, weight_quant):
+        cfg = model.config
+        self.cfg = cfg
+        self.max_len = int(max_len or cfg.max_position_embeddings)
+        self.nh, self.nkv = cfg.num_attention_heads, cfg.num_key_value_heads
+        self.hd, self.eps = cfg.head_dim, cfg.layer_norm_epsilon
+        self.weight_quant = None
+        self.kv_layers = cfg.count("*")
+        self.state_layers = cfg.count("M")
+        if not self.kv_layers:
+            raise NotImplementedError(
+                "a pattern without an attention block has no paged cache "
+                "for the block tables to address")
+        self._params = model.param_tree()
+        body = sum(x.size * x.dtype.itemsize for x in
+                   jax.tree_util.tree_leaves(self._params["layers"]))
+        body += self._params["head"].size * self._params["head"].dtype.itemsize
+        self.weight_stream_bytes = {"quant": int(body), "bf16eq": int(body)}
+
+    # -- the cache ----------------------------------------------------------------
+    _prefill_donate = (4, 5, 6, 7)
+
+    def new_pools(self):
+        cfg = self.cfg
+        kpool, vpool = super().new_pools()
+        dt = kpool.dtype
+        ssm = jnp.zeros((self.state_layers, self.max_slots,
+                         cfg.mamba_num_heads, cfg.mamba_head_dim,
+                         cfg.ssm_state_size), F32)
+        conv = jnp.zeros((self.state_layers, self.max_slots,
+                          cfg.conv_kernel - 1, cfg.conv_dim), dt)
+        return kpool, vpool, ssm, conv
+
+    @property
+    def slot_state_bytes(self):
+        """Bytes of recurrent state one slot holds (all Mamba blocks)."""
+        cfg = self.cfg
+        itemsize = 2 if cfg.dtype == "bfloat16" else 4
+        return self.state_layers * (
+            cfg.mamba_inner * cfg.ssm_state_size * 4
+            + (cfg.conv_kernel - 1) * cfg.conv_dim * itemsize)
+
+    def _prefill_extra(self, slot):
+        return (jnp.int32(slot),)
+
+    def _refuse(self, what, why):
+        raise NotImplementedError(
+            f"{what} does not compose with recurrent layers: {why}")
+
+    def export_blocks(self, *a, **kw):
+        self._refuse("block export", "a request's KV blocks are half of "
+                     "its cache; its recurrent state has no transport yet")
+
+    def import_blocks(self, *a, **kw):
+        self._refuse("block import", "a request's KV blocks are half of "
+                     "its cache; its recurrent state has no transport yet")
+
+    def page_out_blocks(self, *a, **kw):
+        self._refuse("page-out", "it moves KV blocks, not recurrent state")
+
+    def page_in_blocks(self, *a, **kw):
+        self._refuse("page-in", "it moves KV blocks, not recurrent state")
+
+    def serve(self, requests, spec_decode=None, **kw):
+        if spec_decode is not None:
+            self._refuse("spec_decode", "the verify pass would advance the "
+                         "recurrent state over drafts it then rejects")
+        return super().serve(requests, spec_decode=None, **kw)
+
+    # -- programs -------------------------------------------------------------------
+    def _hybrid_step(self, params, tokens, seqlens, tables, active, kpool,
+                     vpool, ssm, conv):
+        """One decode step for every slot through the pattern. Returns
+        (logits [S, V], the four pools, per-step MoE counts)."""
+        cfg, bs = self.cfg, self.block_size
+        S = tokens.shape[0]
+        x = jnp.take(params["embed"], tokens, axis=0)
+        dtype = x.dtype
+        blk = jnp.take_along_axis(tables, (seqlens // bs)[:, None],
+                                  axis=1)[:, 0]
+        blk = jnp.where(active, blk, 0)
+        widx = blk * bs + seqlens % bs
+        kflat, vflat, NB, _ = self._flat_pools(kpool, vpool)
+        m = a = 0
+        counts = jnp.asarray(NO_COUNTS)
+        for i, kind in enumerate(cfg.hybrid_override_pattern):
+            p = params["layers"][i]
+            u = _rms(x, p["norm"], self.eps)
+            if kind == "M":
+                out, s_new, c_new = mamba_decode(cfg, p, u, ssm[m], conv[m],
+                                                 active)
+                ssm = ssm.at[m].set(s_new)
+                conv = conv.at[m].set(c_new)
+                m += 1
+            elif kind == "*":
+                q = (u @ p["wq"].astype(dtype)).reshape(S, self.nh, self.hd)
+                k = (u @ p["wk"].astype(dtype)).reshape(S, self.nkv, self.hd)
+                v = (u @ p["wv"].astype(dtype)).reshape(S, self.nkv, self.hd)
+                with jax.named_scope("decode.kv_pool"):
+                    kflat, vflat = self._pool_write(
+                        kflat, vflat, k, v, a * (NB * bs) + widx)
+                o = self._pool_attend(q, kflat, vflat, tables + a * NB,
+                                      seqlens, dtype)
+                out = o @ p["wo"].astype(dtype)
+                a += 1
+            else:
+                out, c = latent_moe(cfg, p, u, active)
+                counts = merge_counts(counts, c)
+            x = x + out
+        kpool = self._stacked_pools(kflat, kpool)
+        vpool = self._stacked_pools(vflat, vpool)
+        x = _rms(x, params["norm"], self.eps)
+        return (self._head_logits(params, x), kpool, vpool, ssm, conv,
+                counts)
+
+    def _paged_chunk_state_impl(self, params, tok0, seqlens0, tables, live,
+                                budgets, poison, kpool, vpool, ssm, conv,
+                                n, eos_id):
+        """The state-carrying chunk of `PagedDecoder` (same arithmetic
+        of liveness, budgets and eos), with the four pools in the step
+        loop's carry and, after them in what it returns, the chunk's
+        counters `COUNTERS` (int32 [5]) that ride home with the tokens."""
+        def body(carry, i):
+            tok, lens, bad, eos, stats, rows, pools = carry
+            act = live & (i < budgets)
+            logits, *pools, c = self._hybrid_step(
+                params, tok, lens, tables, act, *pools)
+            logits = jnp.where(poison[:, None],
+                               jnp.asarray(jnp.nan, logits.dtype), logits)
+            bad = bad | (act & jnp.any(~jnp.isfinite(logits), axis=-1))
+            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            nxt = jnp.where(act, nxt, tok)
+            lens = jnp.where(act, lens + 1, lens)
+            if eos_id >= 0:
+                eos = eos | (act & (nxt == jnp.int32(eos_id)))
+            rows = rows + jnp.sum(act, dtype=jnp.int32) \
+                * jnp.int32(self.state_layers)
+            return (nxt, lens, bad, eos, merge_counts(stats, c), rows,
+                    tuple(pools)), nxt
+
+        bad0 = jnp.zeros(tok0.shape, bool)
+        (tok, lens, bad, eos, stats, rows, pools), toks = jax.lax.scan(
+            body, (tok0, seqlens0, bad0, jnp.zeros_like(bad0),
+                   jnp.asarray(NO_COUNTS), jnp.int32(0),
+                   (kpool, vpool, ssm, conv)),
+            jnp.arange(n, dtype=jnp.int32))
+        took = jnp.minimum(jnp.int32(n), jnp.maximum(budgets, 0))
+        budgets = jnp.where(live, budgets - took, budgets)
+        live_out = live & (budgets > 0) & ~eos
+        return (jnp.swapaxes(toks, 0, 1), bad, tok, lens, live_out,
+                budgets) + tuple(pools) \
+            + (jnp.concatenate([stats, rows[None]]),)
+
+    COUNTERS = ("moe_pairs_here", "moe_pairs_all", "moe_experts_touched",
+                "moe_max_load", "ssm_rows")
+
+    def chunk_counters(self, aux):
+        """The chunk's counters as `serve:commit` metadata; `aux` is
+        what the chunk program returned after the pools, already on the
+        host's side of the token read."""
+        return dict(zip(self.COUNTERS, (int(v) for v in np.asarray(aux[0]))))
+
+    def _prefill_paged(self, params, ids, true_len, table, kpool, vpool,
+                       ssm, conv, slot):
+        """Prefill one bucket-padded prompt: K and V of the attention
+        blocks into the slot's pages, the state of every Mamba block at
+        `true_len` (padded positions contribute nothing) into the
+        slot's rows of `ssm` and `conv`. Returns int32 [5] (the encoded
+        first token, then the prompt's MoE counts as `moe_experts` gives
+        them, merged over the expert blocks) and the pools."""
+        cfg, bs = self.cfg, self.block_size
+        S0 = ids.shape[0]
+        x = jnp.take(params["embed"], ids, axis=0)
+        pos = jnp.arange(S0, dtype=jnp.int32)
+        valid = pos < true_len
+        blk = jnp.where(valid, jnp.take(table, pos // bs), 0)
+        widx = blk * bs + pos % bs
+        kflat, vflat, NB, _ = self._flat_pools(kpool, vpool)
+        m = a = 0
+        counts = jnp.asarray(NO_COUNTS)
+        for i, kind in enumerate(cfg.hybrid_override_pattern):
+            p = params["layers"][i]
+            u = _rms(x, p["norm"], self.eps)
+            if kind == "M":
+                out, state, cstate = mamba_sequence(cfg, p, u, true_len)
+                at = (jnp.int32(m), slot) + (jnp.int32(0),) * 3
+                ssm = jax.lax.dynamic_update_slice(
+                    ssm, state[None, None], at)
+                conv = jax.lax.dynamic_update_slice(
+                    conv, cstate[None, None].astype(conv.dtype), at[:4])
+                m += 1
+            elif kind == "*":
+                out, k, v = attention_sequence(cfg, p, u)
+                kflat, vflat = self._pool_write(
+                    kflat, vflat, k, v, a * (NB * bs) + widx)
+                a += 1
+            else:
+                out, c = latent_moe(cfg, p, u, valid)
+                counts = merge_counts(counts, c)
+            x = x + out
+        kpool = self._stacked_pools(kflat, kpool)
+        vpool = self._stacked_pools(vflat, vpool)
+        last = jnp.take(x, jnp.maximum(true_len - 1, 0), axis=0)
+        last = _rms(last[None], params["norm"], self.eps)
+        logits = self._head_logits(params, last)[0]
+        enc = jnp.concatenate([self._encode_first_token(logits)[None],
+                               counts])
+        return enc, kpool, vpool, ssm, conv
+
+    def decode_first_token(self, enc):
+        """The first token as `PagedDecoder` encodes it, and behind it on
+        the same wire the prompt's MoE counts, kept for
+        `admit_metadata`."""
+        v = np.asarray(enc)
+        self._admit_counts = [int(c) for c in v[1:]]
+        return super().decode_first_token(v[0])
+
+    def admit_metadata(self):
+        """The slot's recurrent state that the prefill overwrote, and
+        the prompt's MoE counts under the chunk counters' names."""
+        return {"state_bytes": self.slot_state_bytes,
+                **dict(zip(self.COUNTERS[:4], self._admit_counts))}

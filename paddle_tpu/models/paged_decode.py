@@ -161,7 +161,29 @@ class PagedDecoder(CachedDecoder):
 
     Weight preparation (stacking, optional int8) is inherited from
     CachedDecoder; the cache machinery is replaced wholesale.
+
+    What the serve loop carries chunk to chunk is the tuple `new_pools()`
+    returns, `(kpool, vpool)` here: every program takes it as its last
+    array arguments and returns it in the same order. An engine whose
+    model keeps further per-slot state (models/nemotron_h.py) makes the
+    tuple longer; `PagedDecoder(model)` builds that engine when the
+    model's configuration carries a layer pattern.
     """
+
+    # writes past the host's view of a slot are harmless here: the next
+    # chunk rewrites the same K and V. An engine with a recurrent state
+    # says False, and the loop then keeps a look-ahead chunk's length
+    # out of an eos's reach
+    _cache_rewinds = True
+    # the pools' places among `_prefill_paged`'s arguments
+    _prefill_donate = (4, 5)
+
+    def __new__(cls, model, *args, **kwargs):
+        if cls is PagedDecoder and getattr(
+                model.config, "hybrid_override_pattern", None):
+            from .nemotron_h import HybridPagedDecoder
+            cls = HybridPagedDecoder
+        return super().__new__(cls)
 
     def __init__(self, model, max_len=None, weight_quant=None,
                  block_size=64, num_blocks=None, max_slots=8,
@@ -169,8 +191,21 @@ class PagedDecoder(CachedDecoder):
                  prefix_cache=None, prefix_cache_blocks=None,
                  attn_shards=None, shard_block_budget=None,
                  prefill_chunk=None, kv_offload=None,
-                 hbm_budget_gib=None):
-        super().__init__(model, max_len=max_len, weight_quant=weight_quant)
+                 hbm_budget_gib=None, pipelined_admission=False):
+        self._prepare_weights(model, max_len, weight_quant)
+        # pipelined admission: an admission scan of `serve` dispatches
+        # the prefill of every prompt it admits before it reads the
+        # first of their first tokens, so the device runs them back to
+        # back instead of idling through the host's round trip after
+        # each (and through whatever holds the host up meanwhile). Off
+        # by default: the loop then reads each first token before it
+        # admits the next prompt, as it always has
+        self.pipelined_admission = bool(pipelined_admission)
+        if self.pipelined_admission and prefix_cache not in (None, False):
+            raise NotImplementedError(
+                "pipelined_admission with prefix_cache: a scan's later "
+                "prompts would be planned against a tree that its "
+                "earlier ones have not joined")
         # kv_quant="int8": pool blocks are int8 codes + one f32 scale per
         # token row (kernels/pallas/ragged_paged_attention.kv_quantize_
         # rows), quantized at write time and dequantized INSIDE the
@@ -276,11 +311,10 @@ class PagedDecoder(CachedDecoder):
                               + 1)
         # the programs index a pool's token rows flat over layers and
         # blocks (`_flat_pools`), in int32
-        rows = (self.cfg.num_hidden_layers * self.num_blocks
-                * self.block_size)
+        rows = self.kv_layers * self.num_blocks * self.block_size
         if rows >= 2**31:
             raise ValueError(
-                f"KV pool of {self.cfg.num_hidden_layers} layers x "
+                f"KV pool of {self.kv_layers} layers x "
                 f"{self.num_blocks} blocks x {self.block_size} tokens = "
                 f"{rows} token rows, past the int32 row index (2**31): "
                 f"lower num_blocks")
@@ -381,6 +415,23 @@ class PagedDecoder(CachedDecoder):
         self._spec_aot = {}
         _LIVE_DECODERS.add(self)
 
+    def _prepare_weights(self, model, max_len, weight_quant):
+        """The engine's weights as its programs take them (`_params`)
+        and the sizes the cache machinery reads: `CachedDecoder`'s
+        stacked Llama layers here, every one of which has K and V."""
+        CachedDecoder.__init__(self, model, max_len=max_len,
+                               weight_quant=weight_quant)
+        self.kv_layers = self.cfg.num_hidden_layers
+
+    def _prefill_extra(self, slot):
+        """Arguments `_prefill_paged` takes after the pools."""
+        return ()
+
+    def chunk_counters(self, aux):
+        """`serve:commit` metadata from what the chunk program returned
+        after the pools (nothing here)."""
+        return {}
+
     # -- pools -------------------------------------------------------------
     def new_pools(self):
         """Fresh zero K and V pools, [L, NB, bs, Hkv, D] each (int8
@@ -390,7 +441,7 @@ class PagedDecoder(CachedDecoder):
         The programs donate the pools and update them in place through
         the flat view of `_flat_pools`."""
         cfg = self.cfg
-        shape = (cfg.num_hidden_layers, self.num_blocks, self.block_size,
+        shape = (self.kv_layers, self.num_blocks, self.block_size,
                  self.nkv, self.hd)
         if self.kv_quant:
             # codes + per-row scales as one pytree per side: every pool
@@ -417,14 +468,14 @@ class PagedDecoder(CachedDecoder):
         return self.nkv * self.hd * itemsize
 
     def pool_bytes(self):
-        return (2 * self.cfg.num_hidden_layers * self.num_blocks
+        return (2 * self.kv_layers * self.num_blocks
                 * self.block_size * self.kv_token_bytes())
 
     def bytes_per_block(self):
         """K+V bytes one pool block holds across all layers — the unit the
         headroom guard prices admissions in (quantized-aware: the same
         guard limit admits proportionally more int8 blocks)."""
-        return (2 * self.cfg.num_hidden_layers * self.block_size
+        return (2 * self.kv_layers * self.block_size
                 * self.kv_token_bytes())
 
     # -- the pools inside a program ---------------------------------------
@@ -778,6 +829,11 @@ class PagedDecoder(CachedDecoder):
         v = int(np.asarray(enc))
         return (-v - 1, True) if v < 0 else (v, False)
 
+    def admit_metadata(self):
+        """What the engine adds to the `serve:admit` span of the
+        admission whose first token `decode_first_token` just read."""
+        return {}
+
     # prefill into pages: true_len is traced, bucket length is static
     def _prefill_paged(self, params, ids, true_len, table, kpool, vpool):
         """ids [S0pad] int32; true_len scalar; table [MB]. Writes K/V
@@ -1006,7 +1062,8 @@ class PagedDecoder(CachedDecoder):
             built = bucket not in self._prefill_cache
             if built:
                 self._prefill_cache[bucket] = jax.jit(
-                    self._prefill_paged, donate_argnums=(4, 5))
+                    self._prefill_paged,
+                    donate_argnums=self._prefill_donate)
             return self._prefill_cache[bucket], built
         key = (bucket, self._pool_sig(args[4]))
         compiled = self._prefill_aot.get(key)
@@ -1016,7 +1073,8 @@ class PagedDecoder(CachedDecoder):
             with _obs.span("serve:compile", what=f"prefill_b{bucket}"):
                 compiled, _ = _cc.get_or_compile(
                     jax.jit(self._prefill_paged,
-                            donate_argnums=(4, 5)).lower(*args),
+                            donate_argnums=self._prefill_donate
+                            ).lower(*args),
                     tag=f"serve_prefill_b{bucket}")
             self._prefill_aot[key] = compiled
             from ..observability import memory_profile as _mp
@@ -1178,7 +1236,7 @@ class PagedDecoder(CachedDecoder):
                     "paddle_tpu_sharded_attn_calls_total",
                     "decode attention passes served by the context-"
                     "length-sharded partials kernel").inc(
-                        self.cfg.num_hidden_layers * n)
+                        self.kv_layers * n)
         from ..kernels.pallas.ragged_paged_attention import (
             record_ragged_step)
         record_ragged_step(
@@ -1186,7 +1244,7 @@ class PagedDecoder(CachedDecoder):
             self.nkv, self.hd,
             1 if self.kv_quant else
             (2 if self.cfg.dtype == "bfloat16" else 4),
-            layers=self.cfg.num_hidden_layers, steps=steps,
+            layers=self.kv_layers, steps=steps,
             live=live, budgets=budgets,
             scale_bytes=4 if self.kv_quant else 0, launches=launches)
 
@@ -1271,6 +1329,13 @@ class PagedDecoder(CachedDecoder):
         position). Greedy verification is exact — the emitted stream is
         token-identical to plain decode; accept tallies land in
         `self.spec_stats` and the paddle_tpu_spec_decode_* counters.
+
+        Pipelined admission (engines built with
+        pipelined_admission=True): each admission scan dispatches all
+        its prefills, then reads their first tokens in the same order;
+        the tokens served are the same, `serve:prefill` then lies
+        outside `serve:admit`, which spans the wait for the first token
+        and the slot joining the batch.
 
         Prefix cache (ISSUE 18; engines built with prefix_cache=True):
         admission matches the prompt against the radix tree over the

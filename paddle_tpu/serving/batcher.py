@@ -93,8 +93,12 @@ def serve_loop(eng, requests, *, max_new_tokens=32, eos_token_id=None,
             "pipeline=None/False with spec_decode (the verify path "
             "still reuses device-resident tables/budgets/poison).")
     pipe_teeth = os.environ.get("PT_PIPE_TEETH", "")
+    # an eos can cut a look-ahead chunk short after the device has run
+    # it whole (`serial_n`): K and V written past the cut are rewritten,
+    # a recurrent state stepped past it could not be taken back
     lookahead_on = (pipeline is not False and spec_cfg is None
-                    and pipe_teeth != "force_sync")
+                    and pipe_teeth != "force_sync"
+                    and (eos_token_id is None or eng._cache_rewinds))
     cache = eng.prefix_cache
     telemetry = _obs.enabled()
     if telemetry:
@@ -129,10 +133,13 @@ def serve_loop(eng, requests, *, max_new_tokens=32, eos_token_id=None,
     # by THIS call must outlive it. Cache-off engines keep the
     # historical fresh-pools-per-call behavior (and its zeroed-pool
     # determinism) untouched.
+    # `pools` is the cache the loop carries: the engine's tuple of
+    # device arrays ((kpool, vpool) for a PagedDecoder), donated to and
+    # returned by every program in the same order
     if cache is not None:
-        kpool, vpool = eng.ensure_pools()
+        pools = tuple(eng.ensure_pools())
     else:
-        kpool, vpool = eng.new_pools()
+        pools = tuple(eng.new_pools())
     results = {}
     bs = eng.block_size
     MB = eng.blocks_per_seq
@@ -157,6 +164,11 @@ def serve_loop(eng, requests, *, max_new_tokens=32, eos_token_id=None,
     last_ready = [None]
     dev_busy = [0.0]
     spec_mirror = {}
+    # pipelined admission (`PagedDecoder(pipelined_admission=True)`): the
+    # prefills an admission scan has dispatched and not yet read
+    stage_admissions = bool(getattr(eng, "pipelined_admission", False))
+    staged = []
+    joined = [0.0]           # when the last first token was read
 
     def note_uploads(k):
         eng.h2d_uploads += k
@@ -207,12 +219,12 @@ def serve_loop(eng, requests, *, max_new_tokens=32, eos_token_id=None,
         Hand the pager the live pools for the duration of the call,
         then take back whatever a page-in rebound. No-op (and
         byte-identical history) when no pager is armed."""
-        nonlocal kpool, vpool
+        nonlocal pools
         if cache is None or cache.pager is None:
             return fn(*a, **kw)
-        eng._persistent_pools = (kpool, vpool)
+        eng._persistent_pools = pools
         out = fn(*a, **kw)
-        kpool, vpool = eng.ensure_pools()
+        pools = tuple(eng.ensure_pools())
         return out
 
     def never_fits(prompt, mnt):
@@ -424,7 +436,7 @@ def serve_loop(eng, requests, *, max_new_tokens=32, eos_token_id=None,
         set swaps that single component. With ``after_n`` set this is
         the LOOKAHEAD dispatch — chunk N+1 launched off chunk N's
         device outputs before the host has seen N's tokens."""
-        nonlocal kpool, vpool
+        nonlocal pools
         budg = np.asarray(
             [eng._slots[i].budget if live[i] else 0
              for i in range(eng.max_slots)], np.int32)
@@ -470,7 +482,7 @@ def serve_loop(eng, requests, *, max_new_tokens=32, eos_token_id=None,
         if uploads:
             note_uploads(uploads)
         st = dev["state"]
-        args = (eng._params,) + st + (kpool, vpool)
+        args = (eng._params,) + st + pools
         if telemetry:
             t0b = time.perf_counter()
             fn, built = eng._chunk_state_exec(n, eos_dev, args)
@@ -488,12 +500,13 @@ def serve_loop(eng, requests, *, max_new_tokens=32, eos_token_id=None,
         with _obs.span("serve:chunk", steps=int(n),
                        lookahead=int(after_n is not None),
                        uploads=uploads):
-            if telemetry:
-                (toks, bad, tok_o, len_o, live_o, budg_o, kpool,
-                 vpool) = fn(*args)
-            else:
-                (toks, bad, tok_o, len_o, live_o, budg_o, kpool,
-                 vpool) = eng._paged_chunk_state_jit(*args, n, eos_dev)
+            out = fn(*args) if telemetry else \
+                eng._paged_chunk_state_jit(*args, n, eos_dev)
+        # the batch state, the pools in their order, then whatever
+        # counters the engine's program sends home with the tokens
+        toks, bad, tok_o, len_o, live_o, budg_o = out[:6]
+        aux = tuple(out[6 + len(pools):])
+        pools = tuple(out[6:6 + len(pools)])
         dev["state"] = (tok_o, len_o, st[2], live_o, budg_o, st[5])
         eng.chunk_dispatches += 1
         if after_n is not None:
@@ -504,7 +517,7 @@ def serve_loop(eng, requests, *, max_new_tokens=32, eos_token_id=None,
                     "lookahead dispatches: chunk N+1 launched before "
                     "chunk N's tokens reached the host").inc()
         eng._record_traffic(lens_now, n, live, budg)
-        return {"toks": toks, "bad": bad, "n": int(n),
+        return {"toks": toks, "bad": bad, "aux": aux, "n": int(n),
                 "lookahead": after_n is not None, "t_disp": t_disp,
                 "gap": gap,
                 "slots": [(i, eng._slots[i])
@@ -526,6 +539,7 @@ def serve_loop(eng, requests, *, max_new_tokens=32, eos_token_id=None,
             # the loop's wait for the device: the tokens are read here
             toks = np.asarray(rec["toks"])
             bad = np.asarray(rec["bad"])
+            counters = eng.chunk_counters(rec["aux"])
         t_ready = time.perf_counter()
         if telemetry:
             # in the pipelined loop "execute" is the EXPOSED device
@@ -553,7 +567,8 @@ def serve_loop(eng, requests, *, max_new_tokens=32, eos_token_id=None,
                 advance(i, [int(t) for t in toks[i, :take]], ct0,
                         t_ready)
                 took += take
-            sp.set(tokens=took, retired=live_before - int(live.sum()))
+            sp.set(tokens=took, retired=live_before - int(live.sum()),
+                   **counters)
         if n_eff < rec["n"]:
             # the device ran the full overshot chunk — its state is
             # ahead of the trimmed mirrors; resync at next dispatch
@@ -567,7 +582,7 @@ def serve_loop(eng, requests, *, max_new_tokens=32, eos_token_id=None,
         token — import the blocks, write the table, and join the next
         decode chunk. ZERO prefill device work here (the counter gate
         the disaggregation drill reads)."""
-        nonlocal kpool, vpool
+        nonlocal pools
         mark_state_dirty()
         prompt = list(map(int, payload.prompt))
         s0 = len(prompt)
@@ -587,8 +602,8 @@ def serve_loop(eng, requests, *, max_new_tokens=32, eos_token_id=None,
         t0p = time.perf_counter()
         used = blocks_needed(s0)
         with _obs.span("serve:kv_import", blocks=used):
-            kpool, vpool = eng.import_blocks(
-                kpool, vpool, blocks[:used], payload.kv)
+            pools = tuple(eng.import_blocks(
+                *pools, blocks[:used], payload.kv))
         t1p = time.perf_counter()
         if telemetry:
             phase["execute"] += t1p - t0p
@@ -611,15 +626,38 @@ def serve_loop(eng, requests, *, max_new_tokens=32, eos_token_id=None,
     def admit(i, req_id, prompt, max_new, t_admit):
         """One admission as the loop pays for it: from the pop off the
         queue to the slot joining the batch, the wait for the prefill's
-        first token included."""
-        by = admit_payload if isinstance(prompt, KVBlockPayload) \
-            else admit_prompt
+        first token included. With `eng.pipelined_admission` a prompt's
+        prefill is only dispatched here; `join_staged` reads the first
+        tokens once the scan has dispatched them all, so the device runs
+        the scan's prefills back to back and a host that is slow or
+        held up between two of them leaves it no gap."""
+        if stage_admissions and not isinstance(prompt, KVBlockPayload):
+            staged.append(prefill_prompt(i, req_id, prompt, max_new,
+                                         t_admit))
+            return
         with _obs.span("serve:admit", rid=req_id, slot=i,
                        prompt_tokens=_plen(prompt)) as sp:
-            by(i, req_id, prompt, max_new, t_admit, sp)
+            if isinstance(prompt, KVBlockPayload):
+                admit_payload(i, req_id, prompt, max_new, t_admit, sp)
+            else:
+                join_prompt(prefill_prompt(i, req_id, prompt, max_new,
+                                           t_admit), sp)
 
-    def admit_prompt(i, req_id, prompt, max_new, t_admit, sp):
-        nonlocal kpool, vpool
+    def join_staged():
+        """The second half of a pipelined scan's admissions, in the
+        order of their dispatch: `serve:admit` spans the loop's wait
+        for each first token and the slot joining the batch."""
+        for rec in staged:
+            i, slot = rec[0], rec[1]
+            with _obs.span("serve:admit", rid=slot.req_id, slot=i,
+                           prompt_tokens=len(slot.prompt)) as sp:
+                join_prompt(rec, sp)
+        staged.clear()
+
+    def prefill_prompt(i, req_id, prompt, max_new, t_admit):
+        """Allocate the slot and dispatch the prompt's prefill. Returns
+        what `join_prompt` needs to read its first token."""
+        nonlocal pools
         mark_state_dirty()
         prompt = list(map(int, prompt))
         # chunked-prefill replay: a previously evicted incarnation
@@ -670,7 +708,8 @@ def serve_loop(eng, requests, *, max_new_tokens=32, eos_token_id=None,
             ids = np.full(bucket, pad_token_id, np.int32)
             ids[:s0] = ids_full
             args_p = (eng._params, jnp.asarray(ids), jnp.int32(s0),
-                      jnp.asarray(tables[i]), kpool, vpool)
+                      jnp.asarray(tables[i])) + pools \
+                + eng._prefill_extra(i)
             t0b = time.perf_counter() if telemetry else 0.0
             fn, built = eng._prefill_exec(bucket, args_p, telemetry)
             if telemetry and built:
@@ -679,14 +718,8 @@ def serve_loop(eng, requests, *, max_new_tokens=32, eos_token_id=None,
                 phase["compile"] += time.perf_counter() - t0b
             t0p = time.perf_counter()
             with _obs.span("serve:prefill", bucket=bucket):
-                enc, kpool, vpool = fn(*args_p)
-            # ONE int32 on the wire (ISSUE 20 tentpole c): the
-            # argmax AND the finiteness probe are fused on device
-            # — a 128k-vocab f32 row used to cross per admission.
-            # Reading it is where the loop waits for the prefill
-            with _obs.span("serve:wait_first_token", rid=req_id):
-                first, nonfinite = eng.decode_first_token(enc)
-            bad_prefill = quarantine_on and nonfinite
+                enc, *out = fn(*args_p)
+            pools = tuple(out)
             eng.prefill_device_calls += 1
             eng.prefill_tokens_computed += s0
         else:
@@ -723,7 +756,7 @@ def serve_loop(eng, requests, *, max_new_tokens=32, eos_token_id=None,
                 ids[:npiece] = piece
                 args_w = (eng._params, jnp.asarray(ids),
                           jnp.int32(cached + off), jnp.int32(npiece),
-                          jnp.asarray(tables[i]), kpool, vpool)
+                          jnp.asarray(tables[i])) + pools
                 t0b = time.perf_counter() if telemetry else 0.0
                 fn, built = eng._warmfill_exec(bucket, args_w, telemetry)
                 if telemetry and built:
@@ -735,32 +768,47 @@ def serve_loop(eng, requests, *, max_new_tokens=32, eos_token_id=None,
                         # before the one-token suffix recompute writes
                         # into it (timed inside the prefill window —
                         # COW is prefill cost)
-                        kpool, vpool = eng._cow_copy_jit(
-                            kpool, vpool, jnp.int32(cow_src),
-                            jnp.int32(fresh[0]))
+                        pools = tuple(eng._cow_copy_jit(
+                            *pools, jnp.int32(cow_src),
+                            jnp.int32(fresh[0])))
                         # rebuild args against the post-COW pools (the
                         # copy donated the ones args_w captured)
-                        args_w = args_w[:5] + (kpool, vpool)
+                        args_w = args_w[:5] + pools
                 with _obs.span("serve:warm_prefill", bucket=bucket,
                                cached=cached + off):
-                    enc, kpool, vpool = fn(*args_w)
+                    enc, *out = fn(*args_w)
+                pools = tuple(out)
                 eng.prefill_device_calls += 1
             # only the LAST window's fused first-token matters (the
-            # earlier windows exist for their KV writes) — one int32
-            # carries both the argmax and the finiteness probe
-            with _obs.span("serve:wait_first_token", rid=req_id):
-                first, nonfinite = eng.decode_first_token(enc)
-            bad_prefill = quarantine_on and nonfinite
+            # earlier windows exist for their KV writes)
             eng.prefill_tokens_computed += ns
             cache.record_admission(cached, kb, cow=cow_src is not None)
+        return i, slot, s0, enc, t0p, bucket, cached
+
+    def join_prompt(rec, sp):
+        """Read the first token of a dispatched prefill and let the
+        slot join the batch."""
+        i, slot, s0, enc, t0p, bucket, cached = rec
+        req_id = slot.req_id
+        # ONE int32 on the wire (ISSUE 20 tentpole c): the argmax AND
+        # the finiteness probe are fused on device — a 128k-vocab f32
+        # row used to cross per admission. Reading it is where the loop
+        # waits for the prefill
+        with _obs.span("serve:wait_first_token", rid=req_id):
+            first, nonfinite = eng.decode_first_token(enc)
+        bad_prefill = quarantine_on and nonfinite
         t1p = time.perf_counter()
         if telemetry:
-            phase["execute"] += t1p - t0p
-            dev_busy[0] += t1p - t0p
+            # staged prefills overlap on the host's clock: each is
+            # billed from where the one before it was read
+            since = max(t0p, joined[0])
+            phase["execute"] += t1p - since
+            dev_busy[0] += t1p - since
+        joined[0] = t1p
         ledger.prefill(req_id, t0p, t1p, bucket=bucket,
                        cached_tokens=cached)
         sp.set(bucket=bucket, cached_tokens=cached,
-               tokens=0 if bad_prefill else 1)
+               tokens=0 if bad_prefill else 1, **eng.admit_metadata())
         if bad_prefill:
             # non-finite prefill logits: same quarantine contract
             # as a poisoned decode pass (host-side detection — the
@@ -869,7 +917,8 @@ def serve_loop(eng, requests, *, max_new_tokens=32, eos_token_id=None,
                     # the guard), never a mid-serve RESOURCE_EXHAUSTED.
                     prefill_est = blocks_needed(_plen(prompt)) * \
                         eng.bytes_per_block()
-                    if (eng.headroom_guard is not None and live.any()
+                    if (eng.headroom_guard is not None
+                            and (live.any() or staged)
                             and not eng.headroom_guard.check(prefill_est)):
                         eng.admission_deferrals += 1
                         deferred_scan = True
@@ -930,6 +979,7 @@ def serve_loop(eng, requests, *, max_new_tokens=32, eos_token_id=None,
                         else:
                             requeue(rid, plain, mnt, replays.prefix(rid),
                                     t_fail, admitted=False)
+                join_staged()
                 if not live.any():
                     # an empty batch ends the pipelined stream: whatever
                     # happens next (idle sleep, admission scan) the next
@@ -1028,7 +1078,7 @@ def serve_loop(eng, requests, *, max_new_tokens=32, eos_token_id=None,
                               spec_dev_arr("tables", tables),
                               spec_dev_arr("live", live),
                               spec_dev_arr("budgets", budgets),
-                              spec_dev_arr("poison", poison), kpool, vpool)
+                              spec_dev_arr("poison", poison)) + pools
                     note_uploads(2)
                     if telemetry:
                         t0b = time.perf_counter()
@@ -1043,11 +1093,11 @@ def serve_loop(eng, requests, *, max_new_tokens=32, eos_token_id=None,
                         dev_busy[0] = 0.0
                     with _obs.span("serve:spec_verify", k=int(K)):
                         if telemetry:
-                            g, bad, kpool, vpool = fn(*args_s)
+                            g, bad, *out = fn(*args_s)
                             jax.block_until_ready(g)
                         else:
-                            g, bad, kpool, vpool = eng._spec_verify_jit(
-                                *args_s)
+                            g, bad, *out = eng._spec_verify_jit(*args_s)
+                        pools = tuple(out)
                     with _obs.span("serve:wait_chunk", steps=int(K + 1)):
                         # the pass's results reach the host here (already
                         # there under telemetry, which synced above)
@@ -1155,7 +1205,7 @@ def serve_loop(eng, requests, *, max_new_tokens=32, eos_token_id=None,
     if cache is not None:
         # the loop's final pool bindings ARE the persistent pools now
         # (every device call rebound them through donation)
-        eng._persistent_pools = (kpool, vpool)
+        eng._persistent_pools = pools
     return results
 
 

@@ -39,9 +39,10 @@ def for_the_chip(monkeypatch):
     from jax.experimental.compilation_cache import compilation_cache
     from paddle_tpu.kernels.pallas import (flash_attention,
                                            fused_elementwise,
+                                           grouped_matmul,
                                            ragged_paged_attention, rms_norm)
-    for mod in (flash_attention, fused_elementwise, ragged_paged_attention,
-                rms_norm):
+    for mod in (flash_attention, fused_elementwise, grouped_matmul,
+                ragged_paged_attention, rms_norm):
         monkeypatch.setattr(mod, "_interpret", lambda: False)
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
@@ -86,9 +87,10 @@ def test_flash_attention_fwd_bwd(one_chip, for_the_chip):
     assert text.count("tpu_custom_call") >= 3       # fwd, dq, dk/dv
 
 
-@pytest.mark.parametrize("nkv", [32, 8])
+@pytest.mark.parametrize("nkv", [32, 8, 2])
 def test_ragged_paged_attention(one_chip, for_the_chip, nkv):
-    """16 slots x 32 heads x 128 against a paged pool, MHA and GQA."""
+    """16 slots x 32 heads x 128 against a paged pool: MHA, GQA, and the
+    hybrid's 2 KV heads of 16 query heads each."""
     from paddle_tpu.kernels.pallas.ragged_paged_attention import (
         ragged_paged_attention)
     slots, blocks, block_size, blocks_per_seq = 16, 257, 64, 32
@@ -106,3 +108,99 @@ def test_rope(one_chip, for_the_chip):
                           ((2048, 128), jnp.float32),
                           ((2048, 128), jnp.float32))
     assert "tpu_custom_call" in text
+
+
+# -- the hybrid (Mamba-2 + attention + LatentMoE) at its cell's widths -----------
+
+@pytest.mark.parametrize("m,k,n", [(2816, 1024, 2688), (2816, 2688, 1024)])
+def test_grouped_matmul_sorted(one_chip, for_the_chip, m, k, n):
+    """A decode step's 128 rows x top-22 pairs over 128 held experts,
+    both products of an expert."""
+    from paddle_tpu.kernels.pallas.grouped_matmul import grouped_matmul_sorted
+    text = _compiled_text(grouped_matmul_sorted, one_chip, ((m, k), BF16),
+                          ((128, k, n), BF16), ((128,), jnp.int32))
+    assert "tpu_custom_call" in text
+
+
+@pytest.fixture(scope="module")
+def hybrid(one_chip):
+    """The serving engine of `nemotron3_super_120b_ep4_l11` with its
+    4.65 B parameters described, not made: (decoder, pools, one_chip)."""
+    from paddle_tpu.models.nemotron_h import (HybridPagedDecoder,
+                                              NemotronHConfig)
+    cfg = NemotronHConfig(
+        vocab_size=32768, hybrid_override_pattern="MEMEMEMEM*E",
+        n_routed_experts=512, experts_held=(0, 128), dtype="bfloat16",
+        max_position_embeddings=262144)
+
+    def described(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+
+    class Described:
+        config = cfg
+
+        def param_tree(self):
+            tree = {"layers": [{} for _ in cfg.hybrid_override_pattern]}
+            for name, (shape, f32) in cfg.param_shapes().items():
+                leaf = described(shape, jnp.float32 if f32 else BF16)
+                if name.startswith("layers."):
+                    _, i, key = name.split(".")
+                    tree["layers"][int(i)][key] = leaf
+                else:
+                    tree[name] = leaf
+            return tree
+
+    dec = HybridPagedDecoder(Described(), max_len=2048, block_size=64,
+                             num_blocks=4097, max_slots=128,
+                             ragged_kernel=True)
+    pools = tuple(described(p.shape, p.dtype)
+                  for p in jax.eval_shape(dec.new_pools))
+    return dec, pools, described
+
+
+def _kernels_and_spare(compiled, dec):
+    """The compiled program's text, and its temporaries against the
+    smallest thing it must never hold twice."""
+    spare = compiled.memory_analysis().temp_size_in_bytes
+    cfg = dec.cfg
+    one_state_layer = (dec.max_slots * cfg.mamba_inner
+                       * cfg.ssm_state_size * 4)
+    one_expert_stack = (cfg.experts_held[1] * cfg.moe_latent_size
+                        * cfg.moe_intermediate_size * 2)
+    assert spare < min(one_state_layer, one_expert_stack), \
+        f"{spare} bytes of temporaries: a second copy of a layer of " \
+        f"state ({one_state_layer}) or of an expert stack " \
+        f"({one_expert_stack}) would fit in them"
+    return compiled.as_text()
+
+
+def test_hybrid_chunk_program(hybrid, for_the_chip):
+    """8 decode steps for 128 slots: the grouped expert kernel and the
+    ragged paged kernel are in the program, its 12.3 GB of arguments fit
+    the chip, and its temporaries are too small for a second copy of a
+    layer of state or of an expert stack."""
+    dec, pools, described = hybrid
+    S, MB = dec.max_slots, dec.blocks_per_seq
+    i32, flag = jnp.int32, jnp.bool_
+    compiled = dec._paged_chunk_state_jit.lower(
+        dec._params, described((S,), i32), described((S,), i32),
+        described((S, MB), i32), described((S,), flag),
+        described((S,), i32), described((S,), flag), *pools, 8, -1).compile()
+    text = _kernels_and_spare(compiled, dec)
+    assert "%moe.experts" in text and "%decode.attend" in text
+    assert text.count("tpu_custom_call") >= 11     # 5 x 2 products + attend
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes \
+        < 15.75 * 2**30
+
+
+def test_hybrid_prefill_bucket(hybrid, for_the_chip):
+    """The 128-token prefill bucket: it writes one slot's state in place."""
+    dec, pools, described = hybrid
+    compiled = jax.jit(
+        dec._prefill_paged, donate_argnums=dec._prefill_donate).lower(
+        dec._params, described((128,), jnp.int32),
+        described((), jnp.int32), described((dec.blocks_per_seq,), jnp.int32),
+        *pools, described((), jnp.int32)).compile()
+    text = _kernels_and_spare(compiled, dec)
+    assert "%moe.experts" in text

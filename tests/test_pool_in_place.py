@@ -210,3 +210,59 @@ def test_layer_rows_land_in_their_layer(variant):
     # the trash block took them (and the prefill's pad rows), layer by layer
     for pool in dec.export_blocks(kp, vp, [0]):
         assert _dequantized(pool).reshape(LAYERS, -1).any(axis=1).all()
+
+
+# -- the hybrid engine's second cache and its expert stacks ----------------------
+
+def _hybrid_programs():
+    from paddle_tpu.models.nemotron_h import (NemotronHForCausalLM,
+                                              nemotron_h_tiny)
+    model = NemotronHForCausalLM(nemotron_h_tiny(
+        experts_held=(4, 8)))
+    dec = PagedDecoder(model, max_len=MAX_LEN, block_size=BLOCK,
+                       max_slots=SLOTS, num_blocks=BLOCKS)
+    S, MB = SLOTS, dec.blocks_per_seq
+    pools = dec.new_pools()
+    i32 = lambda *shape: jnp.zeros(shape, jnp.int32)
+    flag = lambda *shape: jnp.zeros(shape, bool)
+    return dec, pools, {
+        "state_chunk": (dec._paged_chunk_state_jit,
+                        (dec._params, i32(S), i32(S), i32(S, MB), flag(S),
+                         i32(S), flag(S)) + pools + (2, -1)),
+        "cold_prefill": (jax.jit(dec._prefill_paged,
+                                 donate_argnums=dec._prefill_donate),
+                         (dec._params, i32(BUCKET), i32(), i32(MB)) + pools
+                         + (i32(),)),
+    }
+
+
+@pytest.mark.parametrize("program", ["state_chunk", "cold_prefill"])
+def test_hybrid_program_copies_no_state_pool_and_no_expert_stack(program):
+    """The recurrent state rides the step loop's carry as the KV pools
+    do, and every block's weights are the model's own arrays: no program
+    copies or rebuilds the SSM state pool, a layer of it, or an expert
+    stack (an update in place, a `dynamic-update-slice` of the carry, is
+    the write itself). The CPU backend copies a step loop's carried
+    state once around the loop, so the chunk is held here to its expert
+    stacks and its KV pool; that the chip's compiler leaves no room for a
+    second copy of a layer of state, at the cell's widths, is
+    `tests/test_chip_compile.py::test_hybrid_chunk_program`'s."""
+    dec, pools, programs = _hybrid_programs()
+    fn, args = programs[program]
+    text = compiled_text(fn, *args)
+    ssm = pools[2]
+    w1 = dec._params["layers"][1]["w1"]
+    w2 = dec._params["layers"][1]["w2"]
+    state = (ssm.shape, ssm.shape[1:], (1,) + ssm.shape[1:]) \
+        if program == "cold_prefill" else ()
+    whole = {shape_str("f32", dims)
+             for dims in state + (w1.shape, w2.shape)}
+    hits = []
+    for line in text.splitlines():
+        instr = _parse_instr(line)
+        if instr and instr["op"] in ("copy", "broadcast") and \
+                instr["shape"].split("{")[0] in whole:
+            hits.append(line.strip()[:160])
+    assert hits == []
+    # the KV pool holds the attention block only, and is not moved either
+    assert pool_moves(text, (1, BLOCKS, BLOCK, dec.nkv, dec.hd)) == []

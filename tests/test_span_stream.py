@@ -87,6 +87,35 @@ def _serve(dec, **kw):
                      feed_active=lambda: bool(late), **kw)
 
 
+def test_pipelined_admission_on_the_dense_engine(quiet):
+    """The option the hybrid cell's adapter turns on, on a
+    `PagedDecoder` of Llama blocks: the same tokens; a scan's prefills
+    are dispatched under the iteration, ahead of the first read."""
+    reference = _serve(_tiny_decoder())
+    tracing.enable_tracing()
+    out = _serve(_tiny_decoder(pipelined_admission=True))
+    tracing.disable_tracing()
+    assert out == reference
+    spans = tracing.tail()
+    by = _by_name(spans)
+    ids = {s["id"]: s for s in spans}
+    assert {ids[s["parent"]]["name"] for s in by["serve:prefill"]} \
+        == {"serve:iteration"}
+    assert {ids[s["parent"]]["name"]
+            for s in by["serve:wait_first_token"]} == {"serve:admit"}
+    assert len(by["serve:admit"]) == len(by["serve:prefill"]) == 5
+    first_read = min(s["t0_ns"] for s in by["serve:wait_first_token"])
+    assert sum(s["t0_ns"] < first_read for s in by["serve:prefill"]) == 2
+    tokens = sum(s["meta"]["tokens"]
+                 for s in by["serve:commit"] + by["serve:admit"])
+    assert tokens == sum(len(t) for t in out.values())
+
+
+def test_pipelined_admission_refuses_the_prefix_cache():
+    with pytest.raises(NotImplementedError, match="pipelined_admission"):
+        _tiny_decoder(pipelined_admission=True, prefix_cache=True)
+
+
 def _tiny_step():
     pt.seed(0)
     net = nn.Linear(4, 3)
