@@ -5,8 +5,14 @@ TPU-native replacement for the reference's dynloaded FlashAttention-v2
 the fused attention kernels in phi/kernels/fusion/gpu. Layout contract
 matches paddle's flash_attention python API: [batch, seq, heads, head_dim].
 
-Kernels compute in fp32 (MXU preferred_element_type), carry running
-(max, sum) per row, and save the log-sum-exp for the backward. The
+Every product takes its operands in the dtype they are stored in and
+accumulates in float32 (`_dot`): bf16 q, k, v, do go to the MXU as bf16,
+one pass, and `p` / `ds` are rounded to that dtype for the four products
+that take them (`P V`, `P^T dO`, `dS K`, `dS^T Q`), as the outputs are
+when stored; float32 inputs keep float32 products. All else is float32
+whatever the inputs: the scores (`scale` multiplies them, never an
+operand), the mask, the running (max, sum) per row, `exp`, the
+accumulators, `delta`, and the log-sum-exp saved for the backward. The
 backward is the standard two-pass flash backward: one kernel accumulates
 dq over kv blocks, one accumulates (dk, dv) over q blocks; both recompute
 p from the saved lse. Causal scheduling prunes fully-masked blocks via
@@ -76,6 +82,38 @@ def _block_sizes(s, d, dtype=None):
     return bq, bk
 
 
+def _dot(a, b, contract):
+    """`a . b` over the dimensions `contract` = ((a's,), (b's,)), float32
+    accumulated. Operands go to the MXU in the dtype they are stored in:
+    a bf16 x bf16 product is exact in its float32 accumulator, so an
+    upcast would add five passes and no information. float32 operands
+    keep the process-wide precision (`highest`: six passes); narrower
+    ones name the one-pass product themselves, because Mosaic refuses a
+    float32 contract precision on them."""
+    precision = None if a.dtype == jnp.float32 else lax.Precision.DEFAULT
+    return lax.dot_general(a, b, (contract, ((), ())), precision=precision,
+                           preferred_element_type=jnp.float32)
+
+
+_NT = ((1,), (1,))  # a b^T
+_NN = ((1,), (0,))  # a b
+_TN = ((0,), (0,))  # a^T b
+
+
+def _scores(q, k, scale, causal_from=None):
+    """float32 scores `scale * q k^T` of one [bq, bk] block pair. `scale`
+    is applied to the float32 scores, never to an operand that is then
+    rounded. `causal_from` = (first row, first column) of the pair masks
+    what lies above the diagonal."""
+    st = _dot(q, k, _NT) * scale
+    if causal_from is not None:
+        row0, col0 = causal_from
+        row = row0 + lax.broadcasted_iota(jnp.int32, st.shape, 0)
+        col = col0 + lax.broadcasted_iota(jnp.int32, st.shape, 1)
+        st = jnp.where(row >= col, st, NEG_INF)
+    return st
+
+
 # -- forward -----------------------------------------------------------------
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal, bq, bk):
@@ -83,7 +121,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal, bq, bk):
     qi = pl.program_id(1)
     d = q_ref.shape[-1]
     s = k_ref.shape[0]
-    q = q_ref[:].astype(jnp.float32) * scale
+    q = q_ref[:]
 
     m0 = jnp.full((bq, 1), NEG_INF, jnp.float32)
     l0 = jnp.zeros((bq, 1), jnp.float32)
@@ -91,21 +129,14 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal, bq, bk):
 
     def body(j, carry):
         m, l, acc = carry
-        k = k_ref[pl.ds(j * bk, bk), :].astype(jnp.float32)
-        v = v_ref[pl.ds(j * bk, bk), :].astype(jnp.float32)
-        st = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-        if causal:
-            row = qi * bq + lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-            col = j * bk + lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-            st = jnp.where(row >= col, st, NEG_INF)
+        k = k_ref[pl.ds(j * bk, bk), :]
+        v = v_ref[pl.ds(j * bk, bk), :]
+        st = _scores(q, k, scale, (qi * bq, j * bk) if causal else None)
         m_new = jnp.maximum(m, st.max(axis=-1, keepdims=True))
         p = jnp.exp(st - m_new)
         alpha = jnp.exp(m - m_new)
         l = l * alpha + p.sum(axis=-1, keepdims=True)
-        acc = acc * alpha + lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        acc = acc * alpha + _dot(p.astype(v.dtype), v, _NN)
         return m_new, l, acc
 
     nk = s // bk
@@ -185,23 +216,15 @@ def _fwd_kernel_stream(q_ref, k_ref, v_ref, o_ref, lse_ref,
 
     @pl.when(live)
     def _step():
-        q = q_ref[:].astype(jnp.float32) * scale
-        k = k_ref[:].astype(jnp.float32)
-        v = v_ref[:].astype(jnp.float32)
-        st = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-        if causal:
-            row = qi * bq + lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-            col = j * bk + lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-            st = jnp.where(row >= col, st, NEG_INF)
+        v = v_ref[:]
+        st = _scores(q_ref[:], k_ref[:], scale,
+                     (qi * bq, j * bk) if causal else None)
         m = m_sc[:]
         m_new = jnp.maximum(m, st.max(axis=-1, keepdims=True))
         p = jnp.exp(st - m_new)
         alpha = jnp.exp(m - m_new)
         l_sc[:] = l_sc[:] * alpha + p.sum(axis=-1, keepdims=True)
-        acc_sc[:] = acc_sc[:] * alpha + lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        acc_sc[:] = acc_sc[:] * alpha + _dot(p.astype(v.dtype), v, _NN)
         m_sc[:] = m_new
 
     @pl.when(j == nk - 1)
@@ -255,25 +278,15 @@ def _dq_kernel_stream(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     @pl.when(live)
     def _step():
-        q = q_ref[:].astype(jnp.float32) * scale
-        do = do_ref[:].astype(jnp.float32)
         lse = lse_ref[0, :][:, None]
         delta = delta_ref[0, :][:, None]
-        k = k_ref[:].astype(jnp.float32)
-        v = v_ref[:].astype(jnp.float32)
-        st = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-        if causal:
-            row = qi * bq + lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-            col = j * bk + lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-            st = jnp.where(row >= col, st, NEG_INF)
+        k = k_ref[:]
+        st = _scores(q_ref[:], k, scale,
+                     (qi * bq, j * bk) if causal else None)
         p = jnp.exp(st - lse)
-        dp = lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
+        dp = _dot(do_ref[:], v_ref[:], _NT)
         ds = p * (dp - delta) * scale
-        dq_sc[:] = dq_sc[:] + lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        dq_sc[:] = dq_sc[:] + _dot(ds.astype(k.dtype), k, _NN)
 
     @pl.when(j == nk - 1)
     def _finish():
@@ -296,33 +309,22 @@ def _dkv_kernel_stream(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     @pl.when(live)
     def _step():
-        k = k_ref[:].astype(jnp.float32)
-        v = v_ref[:].astype(jnp.float32)
-        q = q_ref[:].astype(jnp.float32) * scale
-        do = do_ref[:].astype(jnp.float32)
+        q = q_ref[:]
+        do = do_ref[:]
         lse = lse_ref[0, :][:, None]
         delta = delta_ref[0, :][:, None]
-        st = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-        if causal:
-            row = i * bq + lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-            col = ki * bk + lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-            st = jnp.where(row >= col, st, NEG_INF)
+        st = _scores(q, k_ref[:], scale,
+                     (i * bq, ki * bk) if causal else None)
         p = jnp.exp(st - lse)
-        dv_sc[:] = dv_sc[:] + lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dp = lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
+        dv_sc[:] = dv_sc[:] + _dot(p.astype(do.dtype), do, _TN)
+        dp = _dot(do, v_ref[:], _NT)
         ds = p * (dp - delta) * scale
-        dk_sc[:] = dk_sc[:] + lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        dk_sc[:] = dk_sc[:] + _dot(ds.astype(q.dtype), q, _TN)
 
     @pl.when(i == nq - 1)
     def _finish():
-        # q was pre-scaled; ds carries scale — divide one factor out
-        dk_ref[:] = (dk_sc[:] / scale).astype(dk_ref.dtype)
+        # q entered unscaled and ds carries `scale` once: nothing to undo
+        dk_ref[:] = dk_sc[:].astype(dk_ref.dtype)
         dv_ref[:] = dv_sc[:].astype(dv_ref.dtype)
 
 
@@ -392,26 +394,19 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, *,
     qi = pl.program_id(1)
     d = q_ref.shape[-1]
     s = k_ref.shape[0]
-    q = q_ref[:].astype(jnp.float32) * scale
-    do = do_ref[:].astype(jnp.float32)
+    q = q_ref[:]
+    do = do_ref[:]
     lse = lse_ref[0, :][:, None]
     delta = delta_ref[0, :][:, None]
 
     def body(j, dq):
-        k = k_ref[pl.ds(j * bk, bk), :].astype(jnp.float32)
-        v = v_ref[pl.ds(j * bk, bk), :].astype(jnp.float32)
-        st = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-        if causal:
-            row = qi * bq + lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-            col = j * bk + lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-            st = jnp.where(row >= col, st, NEG_INF)
+        k = k_ref[pl.ds(j * bk, bk), :]
+        v = v_ref[pl.ds(j * bk, bk), :]
+        st = _scores(q, k, scale, (qi * bq, j * bk) if causal else None)
         p = jnp.exp(st - lse)
-        dp = lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
+        dp = _dot(do, v, _NT)
         ds = p * (dp - delta) * scale
-        return dq + lax.dot_general(ds, k, (((1,), (0,)), ((), ())),
-                                    preferred_element_type=jnp.float32)
+        return dq + _dot(ds.astype(k.dtype), k, _NN)
 
     nk = s // bk
     hi = jnp.minimum(nk, (qi * bq + bq + bk - 1) // jnp.int32(bk)) if causal else nk
@@ -426,29 +421,21 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     ki = pl.program_id(1)
     d = k_ref.shape[-1]
     s = q_ref.shape[0]
-    k = k_ref[:].astype(jnp.float32)
-    v = v_ref[:].astype(jnp.float32)
+    k = k_ref[:]
+    v = v_ref[:]
 
     def body(i, carry):
         dk, dv = carry
-        q = q_ref[pl.ds(i * bq, bq), :].astype(jnp.float32) * scale
-        do = do_ref[pl.ds(i * bq, bq), :].astype(jnp.float32)
+        q = q_ref[pl.ds(i * bq, bq), :]
+        do = do_ref[pl.ds(i * bq, bq), :]
         lse = lse_ref[0, pl.ds(i * bq, bq)][:, None]
         delta = delta_ref[0, pl.ds(i * bq, bq)][:, None]
-        st = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-        if causal:
-            row = i * bq + lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-            col = ki * bk + lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-            st = jnp.where(row >= col, st, NEG_INF)
+        st = _scores(q, k, scale, (i * bq, ki * bk) if causal else None)
         p = jnp.exp(st - lse)
-        dv = dv + lax.dot_general(p, do, (((0,), (0,)), ((), ())),
-                                  preferred_element_type=jnp.float32)
-        dp = lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
+        dv = dv + _dot(p.astype(do.dtype), do, _TN)
+        dp = _dot(do, v, _NT)
         ds = p * (dp - delta) * scale
-        dk = dk + lax.dot_general(ds, q, (((0,), (0,)), ((), ())),
-                                  preferred_element_type=jnp.float32)
+        dk = dk + _dot(ds.astype(q.dtype), q, _TN)
         return dk, dv
 
     nq = s // bq
@@ -456,10 +443,10 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     dk0 = jnp.zeros((bk, d), jnp.float32)
     dv0 = jnp.zeros((bk, d), jnp.float32)
     dk, dv = lax.fori_loop(jnp.int32(lo), jnp.int32(nq), body, (dk0, dv0))
-    # ds carries one factor of `scale`, and q was pre-scaled by `scale`;
-    # dk = ds^T (q*scale) / scale — the two cancel into a single factor,
-    # so divide the pre-scaling back out.
-    dk_ref[:] = (dk / scale).astype(dk_ref.dtype)
+    # the scores are `scale * q k^T` with q and k as stored, so ds carries
+    # `scale` once (d st / d (q k^T)) and dq = ds k, dk = ds^T q need no
+    # correction
+    dk_ref[:] = dk.astype(dk_ref.dtype)
     dv_ref[:] = dv.astype(dv_ref.dtype)
 
 

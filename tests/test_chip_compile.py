@@ -73,8 +73,16 @@ def test_rms_norm_fwd_bwd(one_chip, for_the_chip, rows, width):
     assert text.count("tpu_custom_call") >= 2       # forward and backward
 
 
-def test_flash_attention_fwd_bwd(one_chip, for_the_chip):
-    """[batch 6 x 32 heads, seq 2048, head_dim 128]: the train step's."""
+@pytest.mark.parametrize("bh,seq,dtype", [
+    (192, 2048, BF16),          # batch 6 x 32 heads: bench.py's train step
+    (96, 4096, BF16),           # batch 3 x 32 heads: mistral_7b_train_seq4k
+    (8, 16384, BF16),           # past the resident limit: the streaming kernels
+    (8, 2048, jnp.float32),     # float32 operands keep their six-pass products
+])
+def test_flash_attention_fwd_bwd(one_chip, for_the_chip, bh, seq, dtype):
+    """Mosaic takes the products as the kernels name them: bf16 operands with
+    the one-pass precision (it refuses the package's process-wide `highest`
+    on them), also where `p` and `ds` are contracted over their rows."""
     from paddle_tpu.kernels.pallas.flash_attention import _flash_bhsd
 
     def fwd_bwd(q, k, v):
@@ -82,7 +90,7 @@ def test_flash_attention_fwd_bwd(one_chip, for_the_chip):
             q, k, v, True, 128 ** -0.5).astype(jnp.float32).sum(),
             argnums=(0, 1, 2))(q, k, v)
 
-    qkv = ((192, 2048, 128), BF16)
+    qkv = ((bh, seq, 128), dtype)
     text = _compiled_text(fwd_bwd, one_chip, qkv, qkv, qkv)
     assert text.count("tpu_custom_call") >= 3       # fwd, dq, dk/dv
 
