@@ -111,8 +111,8 @@ def main():
     # for the artifact; the registry dump rides along as its own line.
     # resilience surfaces (ISSUE 11) ride the instrumented segment: the
     # persistent AOT compile cache lives in a fixed subdirectory of the
-    # one compile-cache root (the telemetry-path compile goes through it
-    # — hits+misses must be live; a second run hits), and ONE bounded
+    # one compile-cache root (telemetry's analysis compile goes through
+    # it — hits+misses must be live; a second run hits), and ONE bounded
     # async checkpoint measures its critical-path exposure (the
     # snapshot+gather wall the attribution ledger bills to `checkpoint`;
     # the write itself is off-path, so this should be ~0)

@@ -150,15 +150,15 @@ def paged_serving(model, cfg, pt, ctx, new_tokens, n_requests, max_slots,
 
     # per-request TTFT/TPOT from the lifecycle ledger (ISSUE 12),
     # reported NEXT TO the step-ratio rows: a second serve pass over the
-    # same request mix with telemetry armed (the AOT/sync path — timed
-    # separately so the throughput row above keeps its async dispatch).
-    # The telemetry path uses its OWN AOT executable caches, distinct
-    # from the jit caches the passes above warmed — warm them first or
-    # the percentiles measure XLA compiles, not serving
+    # same request mix with telemetry armed (the same programs, with the
+    # ledgers' exports on — a pass of its own so the throughput row above
+    # stays an unobserved run). Telemetry analyses each program before
+    # its first telemetry-on call — warm that first or the percentiles
+    # measure the analysis, not serving
     import paddle_tpu.observability as obs
     from paddle_tpu.observability.requests import RequestLedger
     obs.enable()
-    dec.serve([(f"aotwarm{b}", p) for b, p in buckets.items()],
+    dec.serve([(f"warm{b}", p) for b, p in buckets.items()],
               max_new_tokens=new_tokens, chunk=16)
     dec.request_ledger = RequestLedger("serve")
     # pipelined-decode books (ISSUE 20): the timed pass owns them
@@ -177,8 +177,8 @@ def paged_serving(model, cfg, pt, ctx, new_tokens, n_requests, max_slots,
         "metric": "llama_paged_request_latency",
         "value": summ["p50_ttft_s"],
         "unit": f"p50 TTFT s over {summ['completed']} requests "
-                f"(ledger pass: telemetry-on serve, AOT+synced — "
-                f"latency truth, not the throughput row)",
+                f"(ledger pass: telemetry-on serve — latency truth, "
+                f"not the throughput row)",
         "p50_ttft_s": summ["p50_ttft_s"],
         "p99_ttft_s": summ["p99_ttft_s"],
         "p50_tpot_s": summ["p50_tpot_s"],
@@ -233,19 +233,21 @@ def paged_serving(model, cfg, pt, ctx, new_tokens, n_requests, max_slots,
             tables[i, :len(blocks)] = blocks
         if lens_arr is None:
             lens_arr = np.full(max_slots, ctx, np.int32)
-        lens = jnp.asarray(lens_arr, jnp.int32)
-        live = jnp.ones((max_slots,), bool)
-        budgets = jnp.full((max_slots,), 2 * n, jnp.int32)
         poison = jnp.zeros((max_slots,), bool)
-        _, _, kp, vp = pag._paged_chunk_jit(pag._params, toks0, lens,
-                                            jnp.asarray(tables), live,
-                                            budgets, poison, kp, vp, n)
+
+        def state(lens):
+            # the chunk donates tokens, lengths, liveness and budgets:
+            # fresh copies a call (`asarray` may alias the host's)
+            return (jnp.array(toks0), jnp.array(lens, jnp.int32),
+                    jnp.asarray(tables), jnp.ones((max_slots,), bool),
+                    jnp.full((max_slots,), 2 * n, jnp.int32), poison)
+
+        *_, kp, vp = pag._paged_chunk_state_jit(
+            pag._params, *state(lens_arr), kp, vp, n, -1)
+        warm = state(lens_arr + n)
         t0 = time.perf_counter()
-        toks, _, kp, vp = pag._paged_chunk_jit(pag._params, toks0,
-                                               lens + n,
-                                               jnp.asarray(tables), live,
-                                               budgets, poison, kp, vp,
-                                               n)
+        toks, *_, kp, vp = pag._paged_chunk_state_jit(
+            pag._params, *warm, kp, vp, n, -1)
         toks = np.asarray(toks)
         dt = time.perf_counter() - t0
         active = pag.use_ragged_kernel

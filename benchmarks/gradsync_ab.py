@@ -49,9 +49,8 @@ def run_grad_sync_ab(make_model_opt, loss_fn, ids_np, labels_np,
     mesh = Mesh(np.array(jax.devices()), ("dp",))
     saved_mesh = mesh_mod._global_mesh[0]
     mesh_mod.set_mesh(mesh)
-    # telemetry on for BOTH runs (the registry feeds the ratio and the
-    # execution path must match — with it on, TrainStep routes through
-    # per-signature AOT executables)
+    # telemetry on for BOTH runs (the registry feeds the ratio; each
+    # step is then synced, so the two walls are comparable)
     was_enabled = obs.enabled()
     obs.enable()
     try:

@@ -194,8 +194,8 @@ def sharding_assertions(step, plan, batch):
     stacks only at their pp x ep x mp-sharded shape."""
     from paddle_tpu.analysis import hlo_lint
     from paddle_tpu.distributed import mesh as mesh_mod
-    compiled = list(step._compiled_by_sig.values())
-    assert compiled, "telemetry compile path did not cache an executable"
+    compiled = list(step.analysed_executables().values())
+    assert compiled, "telemetry analysed no executable"
     text = compiled[-1].runtime_executable().hlo_modules()[0].to_string()
     mesh = mesh_mod.get_mesh()
     M = plan.microbatches
@@ -279,7 +279,7 @@ def main():
                           (global_batch, SEQ))
 
     obs.reset()
-    obs.enable()          # telemetry path caches the AOT executable
+    obs.enable()          # telemetry keeps the analysed executable
     model, crit, step, stack = build_model(plan)
     if teeth == "break_parity":
         # CI mutation: perturb ONE weight so the parity gate must trip

@@ -2,11 +2,12 @@
 training and serving survive what actually happens at scale — preempted
 slices, killed ranks, cold restarts.
 
-- **compile_cache**: persistent on-disk AOT executable cache keyed by
-  (HLO fingerprint, jax/backend version, topology). A restarted process
-  deserializes yesterday's executables instead of re-paying XLA
-  compilation — PR 1's telemetry counts recompiles; this eliminates
-  their cost across process lifetimes.
+- **compile_cache**: `FLAGS_compile_cache_dir` turns on JAX's own
+  persistent cache under that directory, so a restarted process
+  retrieves the programs it runs instead of re-paying XLA, and beside
+  it a store of serialized executables keyed by (HLO fingerprint,
+  jax/backend version, topology) for the copies telemetry compiles to
+  read (observability/programs.py), whose counters prove the restart.
 - **checkpoint_manager**: step-numbered atomic checkpoints over the
   hardened distributed/checkpoint stack (manifest + checksums +
   rename-commit). `latest_committed()` is the restore contract: a torn

@@ -1,11 +1,19 @@
-"""Persistent AOT compile cache: cold restarts skip XLA compilation.
+"""Persistent compile cache: cold restarts skip XLA compilation.
 
-The per-signature AOT executables TrainStep (jit/train_step.py) and
-PagedDecoder (models/paged_decode.py) build on their telemetry paths are
-serialized to disk (jax.experimental.serialize_executable) the first
-time a signature compiles, and deserialized — not recompiled — by every
-later process that lowers the same program on the same toolchain and
-topology.
+`FLAGS_compile_cache_dir=/path` (env or set_flags) is the one switch. It
+turns on two stores under that directory:
+
+- `<dir>/xla`: JAX's own persistent compilation cache, every entry kept
+  (`_sync_jax_cache`). It serves the programs that RUN (`TrainStep`'s
+  and the serve loop's jitted calls, telemetry on or off), so a
+  restarted process retrieves them instead of compiling.
+- `<dir>/*.ptcc`: serialized executables (`get_or_compile`). Its one
+  caller in the package is the copy telemetry compiles to read
+  (`observability/programs.py`), so its hit / miss counters say whether
+  a restart found its programs on disk; dropping it for JAX's is D14.
+
+`enable_jax_cache()` turns JAX's cache on without the flag, at
+`cache_root()` (what the benchmark's `setup_s` measures).
 
 Keying. An entry's key is a sha256 over:
 
@@ -43,9 +51,8 @@ errors}_total and _bytes_{read,written}_total when the registry is
 enabled; module-local stats() always (the preemption drill's cold-start
 gate runs with telemetry off in the restarted process).
 
-Enable with FLAGS_compile_cache_dir=/path (env or set_flags); empty
-disables (every lookup is a non-counted no-op and compilation proceeds
-as before).
+Empty disables: every lookup is a non-counted no-op, JAX's cache goes
+back to where the process had it, and compilation proceeds as before.
 """
 from __future__ import annotations
 
@@ -62,9 +69,39 @@ __all__ = ["enabled", "cache_dir", "cache_key", "load", "store",
            "get_or_compile", "stats", "reset_stats", "cache_root",
            "enable_jax_cache"]
 
+_JAX_PRIOR = {}       # JAX's cache settings while the flag holds them
+
+
+def _sync_jax_cache(d):
+    """FLAGS_compile_cache_dir's side effect (framework/flags.py fires
+    it on the env path and on set_flags): the programs that run are
+    jitted calls, which only JAX's own persistent cache can serve, so
+    the flag points it at `<d>/xla` and keeps every entry, as the
+    serialized store always did. Where JAX_COMPILATION_CACHE_DIR is set
+    that directory stays. Emptied, the flag gives JAX's settings back."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as jcc
+    if d:
+        want = {"jax_persistent_cache_min_compile_time_secs": 0.0}
+        if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+            want["jax_compilation_cache_dir"] = os.path.join(d, "xla")
+        for k in want:
+            _JAX_PRIOR.setdefault(k, getattr(jax.config, k))
+    elif _JAX_PRIOR:
+        want = dict(_JAX_PRIOR)
+        _JAX_PRIOR.clear()
+    else:
+        return
+    for k, v in want.items():
+        jax.config.update(k, v)
+    # JAX decides at its first compile whether it has a cache
+    jcc.reset_cache()
+
+
 define_flag("compile_cache_dir", "",
-            "directory for the persistent AOT executable cache "
-            "(empty = disabled)")
+            "directory of the persistent compile cache: JAX's own for "
+            "the programs that run, serialized executables for "
+            "telemetry's analysis copies (empty = disabled)")
 define_flag("compile_cache_multiprocess", False,
             "serve persistent-cache hits for executables compiled under "
             "a multi-process runtime (TPU pods). UNSAFE on the gloo CPU "
@@ -287,11 +324,12 @@ def _topology_supported():
 
 
 def get_or_compile(lowered, tag=""):
-    """The one call site the AOT compile paths use: cache-or-compile a
-    jax Lowered. Returns (compiled, info) where info carries
-    {"cache": "hit"|"miss"|"off"|"unsupported", "key": hex|None} —
-    callers feed "hit" into their compile-phase ledgers (a hit's wall
-    is deserialization, orders of magnitude below XLA)."""
+    """Cache-or-compile a jax Lowered (telemetry's analysis compile
+    is the caller). Returns (compiled, info) where info carries
+    {"cache": "hit"|"miss"|"off"|"unsupported", "key": hex|None}. A hit
+    deserializes a copy to read; it saves the program that runs nothing
+    (that one is retrieved from `<dir>/xla`), it says the restart found
+    this program on disk."""
     if not enabled():
         return lowered.compile(), {"cache": "off", "key": None}
     if not _topology_supported():

@@ -47,6 +47,12 @@ def _apply_flag_hooks(name: str, value: Any) -> None:
         obs = sys.modules.get("paddle_tpu.observability.registry")
         if obs is not None:  # else picked up at observability import
             obs._set_enabled(value)
+    elif name == "compile_cache_dir":
+        import sys
+        cc = sys.modules.get(
+            "paddle_tpu.distributed.resilience.compile_cache")
+        if cc is not None:  # the module that defines the flag
+            cc._sync_jax_cache(value)
     elif name == "allocator_strategy":
         from .memory import apply_allocator_policy
         apply_allocator_policy(strategy=value)
@@ -139,9 +145,6 @@ define_flag("jit_cache_capacity", 4096, "Max cached compiled executables in the 
 define_flag("enable_telemetry", False,
             "Turn on the runtime metrics registry (step/memory/collective "
             "telemetry; near-zero overhead when off).")
-define_flag("telemetry_sync_timing", True,
-            "Block on the step result when telemetry is on so step wall "
-            "times are device-accurate (off: dispatch time only).")
 
 # kernels
 define_flag("use_autotune", False, "Enable kernel autotune (pallas block-size search).")

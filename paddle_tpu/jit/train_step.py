@@ -25,6 +25,7 @@ import jax.numpy as jnp
 from ..framework.tensor import Tensor
 from ..framework import autograd, random as random_mod
 from .. import observability as _obs
+from ..observability.programs import profile_program
 from .trace import trace_scope
 
 __all__ = ["TrainStep"]
@@ -156,42 +157,31 @@ class TrainStep:
         # executables instead of silently reusing the first-traced one
         self._jitted = jax.jit(self._traced, donate_argnums=(1, 2, 3),
                                static_argnums=(0,))
-        # telemetry: abstract-shape signatures this step has compiled for.
-        # Tracked even with telemetry off (a set lookup per call) so the
-        # retrace counter/warning never misses the first storm; the
-        # compile split / FLOPs / AOT executables are telemetry-only.
-        # The recompile counter keys on SHAPES (train_mode + input/label
-        # abstract shapes): the accums-materialize retrace on step 2 is
-        # expected exactly once and is not a shape instability.
+        # abstract-shape signatures this step has compiled for. Tracked
+        # even with telemetry off (a set lookup per call) so the retrace
+        # counter/warning never misses the first storm. The recompile
+        # counter keys on SHAPES (train_mode + input/label abstract
+        # shapes): the accums-materialize retrace on step 2 is expected
+        # exactly once and is not a shape instability.
         self._shape_sigs = set()
         self.recompile_count = 0
-        # tokens per __call__ for tokens/s + MFU; derived from the first
+        # tokens per __call__ for tokens/s; derived from the first
         # input's leading dims unless the caller sets it explicitly
         self.tokens_per_call = None
-        self._flops_by_sig = {}
-        self._compiled_by_sig = {}
+        # telemetry's analysis records (observability/programs.py), one a
+        # signature (shape key + accumulator structure): FLOPs, modeled
+        # exposed-collective seconds (the SAME hlo_analysis pricing
+        # tools/overlap_evidence.py --mode gradsync/mp gate on), HBM
+        # ledger, roofline record; bench.py reads the *_summary() views
+        self._analysed = {}
         # goodput attribution (observability/attribution.py): built
         # lazily on the first telemetry-enabled call; classifies every
         # step's wall into {data_wait, compile, dispatch, execute,
         # grad_sync_exposed, checkpoint, other} and emits the ledger to
-        # the JSONL sink. _exposed_by_sig holds the per-executable
-        # modeled exposed-collective seconds (the SAME hlo_analysis
-        # pricing tools/overlap_evidence.py --mode gradsync/mp gate on).
+        # the JSONL sink
         self._ledger = None
-        self._exposed_by_sig = {}
-        self._last_phases = (0.0, 0.0, 0.0)
-        # per-executable HBM ledgers (observability/memory_profile.py):
-        # memory_analysis buckets + named-scope live-range attribution,
-        # recorded once per compile; memory_summary() is bench.py's
-        # peak_hbm_bytes artifact surface
-        self._hbm_by_sig = {}
-        # per-executable roofline records (observability/roofline.py):
-        # op-level compute/HBM/ICI/host pricing against cost_model's
-        # chip rates + the per-scope MFU-gap waterfall, recorded once
-        # per compile; roofline_summary() is bench.py's surface
-        self._roofline_by_sig = {}
-        # how the last AOT build was satisfied ("hit"/"miss"/"off"):
-        # the persistent compile cache's per-step surface
+        # how the last analysis compile was satisfied ("hit"/"miss"/
+        # "off"): the persistent compile cache's per-step surface
         self.compile_cache_last = None
 
     # -- helpers -----------------------------------------------------------
@@ -371,10 +361,7 @@ class TrainStep:
         self._jitted = jax.jit(self._traced, donate_argnums=(1, 2, 3),
                                static_argnums=(0,))
         self._shape_sigs.clear()
-        self._flops_by_sig.clear()
-        self._compiled_by_sig.clear()
-        self._hbm_by_sig.clear()
-        self._roofline_by_sig.clear()
+        self._analysed.clear()
         return self
 
     # -- telemetry ---------------------------------------------------------
@@ -383,16 +370,26 @@ class TrainStep:
         (None before the first one) — bench.py's artifact surface."""
         return None if self._ledger is None else self._ledger.summary()
 
+    def _records(self, layer):
+        """{label: that layer's record} over the analysed signatures."""
+        return {rec["label"]: rec[layer]
+                for rec in self._analysed.values()
+                if rec is not None and rec[layer] is not None}
+
+    def analysed_executables(self):
+        """{label: the executable telemetry compiled to analyse that
+        signature} — for tools that lint the compiled HLO (shardings).
+        Empty before the first telemetry-enabled call."""
+        return self._records("executable")
+
     def memory_summary(self):
-        """Per-executable HBM ledgers recorded at compile time (None
-        before the first telemetry-enabled compile): {executable label:
+        """Per-executable HBM ledgers recorded on a signature's first
+        telemetry-enabled call (None before it): {executable label:
         {peak_bytes, temp_bytes, argument_bytes, output_bytes,
         peak_live_bytes}} plus the max peak — bench.py's
         peak_hbm_bytes artifact surface, gated by tools/bench_smoke.py."""
-        if not self._hbm_by_sig:
-            return None
         per = {}
-        for label, led in self._hbm_by_sig.values():
+        for label, led in self._records("hbm").items():
             live = led.get("live") or {}
             b = led["buckets"]
             per[label] = {
@@ -402,21 +399,21 @@ class TrainStep:
                 "output_bytes": b["output"],
                 "peak_live_bytes": live.get("peak_live_bytes"),
             }
+        if not per:
+            return None
         return {"executables": per,
                 "max_peak_bytes": max(v["peak_bytes"]
                                       for v in per.values())}
 
     def roofline_summary(self):
-        """Per-executable roofline records captured at compile time
-        (None before the first telemetry-enabled compile): modeled step
+        """Per-executable roofline records captured on a signature's
+        first telemetry-enabled call (None before it): modeled step
         wall, modeled MFU, bound-class fractions, the per-scope MFU-gap
         waterfall, and the top ops by gap seconds — bench.py's roofline
         artifact surface, telescoping-gated by tools/bench_smoke.py and
         tools/roofline_report.py."""
-        if not self._roofline_by_sig:
-            return None
         per = {}
-        for label, rec in self._roofline_by_sig.values():
+        for label, rec in self._records("roofline").items():
             per[label] = {
                 "total_modeled_s": rec["total_modeled_s"],
                 "ideal_compute_s": rec["ideal_compute_s"],
@@ -434,7 +431,7 @@ class TrainStep:
                                                "gap_s")}
                             for o in rec["top_ops"][:5]],
             }
-        return {"executables": per}
+        return {"executables": per} if per else None
 
     def _shape_key(self, train_mode, in_arrays, lab_arrays):
         """Cheap abstract-shape signature of what can legitimately vary
@@ -475,84 +472,33 @@ class TrainStep:
             "retraces mean unstable input shapes — pad or bucket "
             "inputs."), stacklevel=4)
 
-    def _obs_call(self, sig, args):
-        """Telemetry execution path: per-signature AOT executables give an
-        exact compile-vs-execute split plus cost_analysis() FLOPs (the jit
-        call cache is separate from the AOT cache, so routing through
-        self._jitted here would compile everything twice)."""
-        from ..framework.flags import flag
+    def _observe(self, rec, t0_ns, compiled0, compiled1, inputs):
+        """Telemetry's record of the step whose program was called at
+        `t0_ns` and has just been synced. `rec`: the signature's analysis
+        record; `compiled0/1`: the compile listener's total before that
+        analysis and before the call (what the call compiled is not its
+        execution). Returns the step ledger's (compile, execute, modeled
+        exposed) seconds."""
+        t1_ns = time.perf_counter_ns()
+        _obs.tracing.record_span("train_step:execute", t0_ns, t1_ns)
+        now = _obs.tracing.compile_seconds()
+        compile_dt = now - compiled0
+        dt = max((t1_ns - t0_ns) * 1e-9 - (now - compiled1), 0.0)
         reg = _obs.registry()
-        compile_dt = 0.0
-        compiled = self._compiled_by_sig.get(sig)
-        if compiled is None:
-            # persistent AOT cache (distributed/resilience): a restarted
-            # process deserializes the executable instead of re-paying
-            # XLA — the lowering itself stays (it IS the fingerprint)
-            from ..distributed.resilience import compile_cache as _cc
-            t0 = time.perf_counter()
-            with _obs.span("train_step:compile"):
-                compiled, cc_info = _cc.get_or_compile(
-                    self._jitted.lower(*args), tag="train_step")
-            compile_dt = time.perf_counter() - t0
-            self.compile_cache_last = cc_info["cache"]
-            self._compiled_by_sig[sig] = compiled
-            reg.histogram("paddle_tpu_train_step_duration_seconds",
-                          "TrainStep wall time by phase",
-                          ("phase",)).observe(compile_dt, phase="compile")
+        phases = reg.histogram("paddle_tpu_train_step_duration_seconds",
+                               "TrainStep wall time by phase", ("phase",))
+        if compile_dt > 0:
+            phases.observe(compile_dt, phase="compile")
             reg.histogram("paddle_tpu_train_step_compile_seconds",
-                          "TrainStep trace+compile time").observe(
-                              compile_dt)
-            flops = 0.0
-            try:
-                ca = compiled.cost_analysis()
-                ca = ca[0] if isinstance(ca, (list, tuple)) else ca
-                flops = float(ca.get("flops", 0.0))
-            except Exception:
-                pass
-            self._flops_by_sig[sig] = flops
+                          "TrainStep backend compile time (trace and "
+                          "lowering are the step ledger's `dispatch`)"
+                          ).observe(compile_dt)
+        phases.observe(dt, phase="execute")
+        if rec is not None:
+            self.compile_cache_last = rec["cache"]
             reg.gauge("paddle_tpu_train_step_flops_per_step",
                       "Compiled-executable FLOPs per step "
-                      "(cost_analysis)").set(flops)
-            # exposed-collective pricing from THIS executable's scheduled
-            # HLO — the shared overlap_evidence definition, priced once
-            # per compile (attribution.modeled_exposed_seconds)
-            from ..observability.attribution import modeled_exposed_seconds
-            self._exposed_by_sig[sig] = modeled_exposed_seconds(compiled)
-            # HBM ledger, once per compile: gauges
-            # paddle_tpu_hbm_{args,temps,outputs,peak}_bytes + the
-            # forensics store the flight recorder snapshots. Must never
-            # take the step down — profile failure degrades to no ledger
-            from ..observability import memory_profile as _mp
-            try:
-                label = _mp.sig_label(sig)
-                self._hbm_by_sig[sig] = (
-                    label, _mp.record_executable("train_step", label,
-                                                 compiled))
-            except Exception:
-                pass
-            # roofline record, once per compile: per-op compute/HBM/ICI
-            # pricing + the per-scope MFU-gap waterfall (gauges
-            # paddle_tpu_roofline_*). Same degrade-to-nothing contract
-            from ..observability import roofline as _rl
-            try:
-                label = _mp.sig_label(sig)
-                rec = _rl.record_executable("train_step", label,
-                                            compiled)
-                if rec is not None:
-                    self._roofline_by_sig[sig] = (label, rec)
-            except Exception:
-                pass
-        t0 = time.perf_counter()
-        with _obs.span("train_step:execute"):
-            out = compiled(*args[1:])     # static train_mode is baked in
-            if flag("telemetry_sync_timing"):
-                jax.block_until_ready(out[0])
-        dt = time.perf_counter() - t0
-        self._last_phases = (compile_dt, dt,
-                             self._exposed_by_sig.get(sig, 0.0))
-        reg.histogram("paddle_tpu_train_step_duration_seconds",
-                      "TrainStep wall time by phase",
-                      ("phase",)).observe(dt, phase="execute")
+                      "(cost_analysis)").set(rec["flops"])
         # register the family even before the first retrace (incremented
         # at the transition in _note_shape_key)
         reg.counter("paddle_tpu_train_step_recompiles_total",
@@ -560,7 +506,7 @@ class TrainStep:
                     "signatures")
         tokens = self.tokens_per_call
         if tokens is None:
-            ins = jax.tree_util.tree_leaves(args[7])
+            ins = jax.tree_util.tree_leaves(inputs)
             if ins:
                 shape = ins[0].shape
                 # integer inputs are token ids [batch, seq]; float inputs
@@ -581,7 +527,7 @@ class TrainStep:
                        "step": int(self.opt._step_count),
                        "wall_s": dt, "tokens_per_s": tps,
                        "recompiles": self.recompile_count})
-        return out
+        return compile_dt, dt, rec["exposed_s"] if rec else 0.0
 
     def __call__(self, inputs, labels=()):
         """One fused step: loss = loss_fn(model(*inputs), *labels).
@@ -611,15 +557,24 @@ class TrainStep:
         args = (self.model.training, params, buffers, accums, lr, step_idx,
                 key, in_arrays, lab_arrays)
         if telemetry:
-            # the AOT executable cache additionally keys on the optimizer
-            # accumulator structure (it changes once, when accums
+            # an analysis record a signature: the shapes and the optimizer
+            # accumulators' structure (it changes once, when they
             # materialize after the first step)
             sig = (shape_key, tuple(sorted(accums)))
-            loss, new_params, new_buffers, new_accums, outs = \
-                self._obs_call(sig, args)
-        else:
-            loss, new_params, new_buffers, new_accums, outs = \
-                self._jitted(*args)
+            compiled0 = _obs.tracing.compile_seconds()
+            rec = profile_program(
+                self._analysed, sig, "train_step",
+                lambda: _obs.memory_profile.sig_label(sig),
+                self._jitted, args)
+            compiled1 = _obs.tracing.compile_seconds()
+            t0_ns = time.perf_counter_ns()
+        loss, new_params, new_buffers, new_accums, outs = \
+            self._jitted(*args)
+        if telemetry:
+            # the sync that makes `execute` the step's device time
+            jax.block_until_ready(loss)
+            phases = self._observe(rec, t0_ns, compiled0, compiled1,
+                                   in_arrays)
         with autograd.no_grad():
             for k, p in self._params.items():
                 p._data = new_params[k]
@@ -642,8 +597,7 @@ class TrainStep:
             if self._ledger is None:
                 from ..observability.attribution import StepLedger
                 self._ledger = StepLedger("train_step")
-            compile_s, execute_s, exposed_s = self._last_phases
-            self._last_phases = (0.0, 0.0, 0.0)
+            compile_s, execute_s, exposed_s = phases
             self._ledger.step(
                 t_call0, time.perf_counter(), compile_s=compile_s,
                 execute_s=execute_s, modeled_exposed_s=exposed_s,

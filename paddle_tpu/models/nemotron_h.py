@@ -595,9 +595,8 @@ class HybridPagedDecoder(PagedDecoder):
             self._paged_chunk_state_impl,
             donate_argnums=(1, 2, 4, 5, 7, 8, 9, 10),
             static_argnums=(11, 12))
-        # the parent's other programs (plain chunk, single step, verify,
-        # COW copy) serve options this engine refuses
-        self._paged_chunk_jit = self._paged_step_jit = None
+        # the parent's other programs (verify, COW copy) serve options
+        # this engine refuses
         self._spec_verify_jit = self._cow_copy_jit = None
 
     def _prepare_weights(self, model, max_len, weight_quant):

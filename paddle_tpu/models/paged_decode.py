@@ -366,12 +366,7 @@ class PagedDecoder(CachedDecoder):
         # counter reads, not assertions about internals
         self.prefill_device_calls = 0
         self.prefill_tokens_computed = 0
-        self._paged_step_jit = jax.jit(
-            self._paged_step_impl, donate_argnums=(4, 5))
-        self._paged_chunk_jit = jax.jit(
-            self._paged_chunk_impl, donate_argnums=(7, 8),
-            static_argnums=(9,))
-        # zero-sync decode (ISSUE 20): the state-carrying chunk variant
+        # zero-sync decode (ISSUE 20): the state-carrying decode chunk
         # — tokens/seqlens/live/budgets ride the device chunk-to-chunk
         # (donated, like the pools), tables/poison are NOT donated so
         # the same device copies serve every chunk until a composition
@@ -381,7 +376,6 @@ class PagedDecoder(CachedDecoder):
         self._paged_chunk_state_jit = jax.jit(
             self._paged_chunk_state_impl,
             donate_argnums=(1, 2, 4, 5, 7, 8), static_argnums=(9, 10))
-        self._chunk_state_aot = {}
         self.h2d_uploads = 0          # decode-state host->device writes
         self.chunk_dispatches = 0     # decode chunk launches
         self.lookahead_dispatches = 0  # launched while one was in flight
@@ -398,21 +392,13 @@ class PagedDecoder(CachedDecoder):
         # so ONE executable serves every block pair
         self._cow_copy_jit = jax.jit(
             self._cow_copy_impl, donate_argnums=(0, 1))
-        # prefill executables are cached per bucket length in serve()
+        # prefill programs, one a bucket length (`_prefill_exec`), and
+        # the warm (pool-mapped) prefill's (`_warmfill_exec`)
         self._prefill_cache = {}
-        # warm (pool-mapped) prefill: per-bucket jit cache + AOT cache,
-        # mirroring the cold-prefill pair below
         self._warm_cache = {}
-        self._warm_aot = {}
-        # telemetry path: per-signature AOT executables (the jit call
-        # cache is separate from the AOT cache — same split TrainStep
-        # makes). AOT compiles give an exact compile/execute split AND
-        # the HBM ledger (memory_profile.record_executable) per
-        # executable; keyed by prefill bucket / chunk length + pool
-        # shape so a re-shaped pool re-profiles
-        self._prefill_aot = {}
-        self._chunk_aot = {}
-        self._spec_aot = {}
+        # telemetry's analysis records (observability/programs.py), by
+        # kind and bucket / chunk length and eos / draft length
+        self._analysed = {}
         _LIVE_DECODERS.add(self)
 
     def _prepare_weights(self, model, max_len, weight_quant):
@@ -608,11 +594,11 @@ class PagedDecoder(CachedDecoder):
             return self._attend(q, kw, vw, seqlens, dtype)
 
     def _paged_step_impl(self, params, tokens, seqlens, tables,
-                        kpool, vpool, active=None):
+                        kpool, vpool, active):
         """One decode step for every slot. tokens [S] int32; seqlens [S]
         int32 = tokens already in the pages (the new token is written at
         position seqlens); tables [S, MB] int32 block ids; pools
-        [L, NB, bs, Hkv, D] donated; active [S] bool (optional) marks
+        [L, NB, bs, Hkv, D] donated; active [S] bool marks
         slots that really advance — inactive slots route their K/V
         writes to the trash block so an exhausted-budget slot can't
         clobber valid pool KV. Returns (logits [S, V], pools).
@@ -632,11 +618,10 @@ class PagedDecoder(CachedDecoder):
         # flat pool index of the write target per slot
         blk = jnp.take_along_axis(tables, (seqlens // bs)[:, None],
                                   axis=1)[:, 0]             # [S]
-        if active is not None:
-            # budget gate (ADVICE r5): a slot past its budget must not
-            # keep writing through the clamped gather — send it to the
-            # trash block (block 0; lane seqlens % bs stays in range)
-            blk = jnp.where(active, blk, 0)
+        # budget gate (ADVICE r5): a slot past its budget must not
+        # keep writing through the clamped gather — send it to the
+        # trash block (block 0; lane seqlens % bs stays in range)
+        blk = jnp.where(active, blk, 0)
         widx = blk * bs + seqlens % bs                      # [S]
 
         kflat, vflat, NB, layer_ids = self._flat_pools(kpool, vpool)
@@ -681,48 +666,24 @@ class PagedDecoder(CachedDecoder):
         x = _rms(x, params["norm"], self.eps)
         return self._head_logits(params, x), kpool, vpool
 
-    def _paged_chunk_impl(self, params, tok0, seqlens0, tables, live,
-                          budgets, poison, kpool, vpool, n):
-        """n fused greedy steps with argmax feedback. live [S] bool masks
-        slots that advance (retired slots keep writing into trash via
-        their zeroed tables, but their lengths stay put so the host state
-        is exact); budgets [S] int32 is each slot's REMAINING token
-        budget — at step i only slots with i < budget stay active, so a
-        chunk sized by the largest budget can't run a smaller-budget
-        slot past its allocation (writes route to the trash block and
-        its length freezes). poison [S] bool is the chaos harness's
-        logits-poison lane (NaN injected AFTER the real logits — KV
-        stays clean, exactly like a poisoned head matmul); `bad` [S]
-        reports any active step whose logits went non-finite, injected
-        OR organic — the quarantine machinery keys off it.
-        Returns ([S, n] tokens, bad [S], pools)."""
-        def body(carry, i):
-            tok, lens, bad, kc, vc = carry
-            act = live & (i < budgets)
-            logits, kc, vc = self._paged_step_impl(
-                params, tok, lens, tables, kc, vc, active=act)
-            logits = jnp.where(poison[:, None],
-                               jnp.asarray(jnp.nan, logits.dtype),
-                               logits)
-            bad = bad | (act & jnp.any(~jnp.isfinite(logits), axis=-1))
-            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            nxt = jnp.where(act, nxt, tok)
-            lens = jnp.where(act, lens + 1, lens)
-            return (nxt, lens, bad, kc, vc), nxt
-
-        bad0 = jnp.zeros(tok0.shape, bool)
-        (tok, lens, bad, kpool, vpool), toks = jax.lax.scan(
-            body, (tok0, seqlens0, bad0, kpool, vpool),
-            jnp.arange(n, dtype=jnp.int32))
-        return jnp.swapaxes(toks, 0, 1), bad, kpool, vpool
-
     def _paged_chunk_state_impl(self, params, tok0, seqlens0, tables,
                                 live, budgets, poison, kpool, vpool, n,
                                 eos_id):
-        """State-carrying decode chunk (ISSUE 20 tentpole a): same scan
-        as `_paged_chunk_impl`, but the batch state advances ON DEVICE
-        so the next chunk's inputs are this chunk's outputs — the
-        steady-state loop never uploads tokens/seqlens/live/budgets.
+        """The decode chunk: n fused greedy steps with argmax feedback,
+        the batch state advancing ON DEVICE so the next chunk's inputs
+        are this chunk's outputs — the steady-state loop never uploads
+        tokens/seqlens/live/budgets (ISSUE 20 tentpole a).
+
+        live [S] bool masks slots that advance (retired slots keep
+        writing into trash via their zeroed tables, their lengths stay
+        put); budgets [S] int32 is each slot's REMAINING budget — at
+        step i only slots with i < budget stay active, so a chunk sized
+        by the largest budget can't run a smaller-budget slot past its
+        allocation. poison [S] bool is the chaos harness's lane (NaN
+        injected AFTER the real logits — KV stays clean); `bad` [S]
+        reports any active step whose logits went non-finite, injected
+        OR organic — the quarantine machinery keys off it.
+
         ``eos_id`` is static (-1 = no eos): the device retires a slot's
         liveness itself when its chunk emits eos or exhausts budget,
         mirroring exactly the host-side advance()/retire() arithmetic
@@ -1044,173 +1005,21 @@ class PagedDecoder(CachedDecoder):
                                   jax.tree_util.tree_map(bad, vp))
         return self._persistent_pools
 
-    # -- telemetry-path AOT executables ------------------------------------
-    @staticmethod
-    def _pool_sig(pool):
-        """Hashable shape/dtype signature of a pool pytree (a bare array
-        or the quantized (codes, scales) pair) for AOT cache keys."""
-        return tuple((tuple(x.shape), str(x.dtype))
-                     for x in jax.tree_util.tree_leaves(pool))
+    # -- per-bucket prefill programs ---------------------------------------
+    def _prefill_exec(self, bucket):
+        """The jitted prefill of this bucket length (made on its first
+        use: one program a bucket, whoever asks)."""
+        if bucket not in self._prefill_cache:
+            self._prefill_cache[bucket] = jax.jit(
+                self._prefill_paged, donate_argnums=self._prefill_donate)
+        return self._prefill_cache[bucket]
 
-    def _prefill_exec(self, bucket, args, telemetry):
-        """(callable, built) for this prefill bucket: the plain jit
-        cache off-telemetry; per-signature AOT executables when
-        telemetry is on (exact compile/execute split — the jit call
-        cache is separate from the AOT cache, TrainStep's split — plus
-        the per-executable HBM ledger recorded at compile time)."""
-        if not telemetry:
-            built = bucket not in self._prefill_cache
-            if built:
-                self._prefill_cache[bucket] = jax.jit(
-                    self._prefill_paged,
-                    donate_argnums=self._prefill_donate)
-            return self._prefill_cache[bucket], built
-        key = (bucket, self._pool_sig(args[4]))
-        compiled = self._prefill_aot.get(key)
-        built = compiled is None
-        if built:
-            from ..distributed.resilience import compile_cache as _cc
-            with _obs.span("serve:compile", what=f"prefill_b{bucket}"):
-                compiled, _ = _cc.get_or_compile(
-                    jax.jit(self._prefill_paged,
-                            donate_argnums=self._prefill_donate
-                            ).lower(*args),
-                    tag=f"serve_prefill_b{bucket}")
-            self._prefill_aot[key] = compiled
-            from ..observability import memory_profile as _mp
-            try:
-                _mp.record_executable("serve", f"prefill_b{bucket}",
-                                      compiled)
-            except Exception:
-                pass
-            from ..observability import roofline as _rl
-            try:
-                _rl.record_executable("serve", f"prefill_b{bucket}",
-                                      compiled)
-            except Exception:
-                pass
-        return compiled, built
-
-    def _warmfill_exec(self, bucket, args, telemetry):
-        """(callable, built) for the warm (pool-mapped) prefill at this
-        suffix bucket — the cold `_prefill_exec` pair's twin."""
-        if not telemetry:
-            built = bucket not in self._warm_cache
-            if built:
-                self._warm_cache[bucket] = jax.jit(
-                    self._prefill_warm_impl, donate_argnums=(5, 6))
-            return self._warm_cache[bucket], built
-        key = (bucket, self._pool_sig(args[5]))
-        compiled = self._warm_aot.get(key)
-        built = compiled is None
-        if built:
-            from ..distributed.resilience import compile_cache as _cc
-            with _obs.span("serve:compile", what=f"warmfill_b{bucket}"):
-                compiled, _ = _cc.get_or_compile(
-                    jax.jit(self._prefill_warm_impl,
-                            donate_argnums=(5, 6)).lower(*args),
-                    tag=f"serve_warmfill_b{bucket}")
-            self._warm_aot[key] = compiled
-            from ..observability import memory_profile as _mp
-            try:
-                _mp.record_executable("serve", f"warmfill_b{bucket}",
-                                      compiled)
-            except Exception:
-                pass
-            from ..observability import roofline as _rl
-            try:
-                _rl.record_executable("serve", f"warmfill_b{bucket}",
-                                      compiled)
-            except Exception:
-                pass
-        return compiled, built
-
-    def _chunk_exec(self, n, args):
-        """Telemetry-path decode-chunk executable for static length
-        ``n`` (and this pool/table geometry), AOT-compiled once and
-        ledger-profiled like the prefill buckets."""
-        key = (int(n), self._pool_sig(args[7]), args[3].shape)
-        compiled = self._chunk_aot.get(key)
-        built = compiled is None
-        if built:
-            from ..distributed.resilience import compile_cache as _cc
-            with _obs.span("serve:compile", what=f"chunk_n{int(n)}"):
-                compiled, _ = _cc.get_or_compile(
-                    self._paged_chunk_jit.lower(*args, int(n)),
-                    tag=f"serve_chunk_n{int(n)}")
-            self._chunk_aot[key] = compiled
-            from ..observability import memory_profile as _mp
-            try:
-                _mp.record_executable("serve", f"chunk_n{int(n)}",
-                                      compiled)
-            except Exception:
-                pass
-            from ..observability import roofline as _rl
-            try:
-                _rl.record_executable("serve", f"chunk_n{int(n)}",
-                                      compiled)
-            except Exception:
-                pass
-        return compiled, built
-
-    def _chunk_state_exec(self, n, eos_id, args):
-        """Telemetry-path STATE-CARRYING decode-chunk executable
-        (ISSUE 20): static length ``n`` + static ``eos_id`` (and this
-        pool/table geometry), AOT-compiled once and ledger-profiled
-        exactly like `_chunk_exec`."""
-        key = (int(n), int(eos_id), self._pool_sig(args[7]),
-               args[3].shape)
-        compiled = self._chunk_state_aot.get(key)
-        built = compiled is None
-        if built:
-            from ..distributed.resilience import compile_cache as _cc
-            with _obs.span("serve:compile", what=f"chunkst_n{int(n)}"):
-                compiled, _ = _cc.get_or_compile(
-                    self._paged_chunk_state_jit.lower(
-                        *args, int(n), int(eos_id)),
-                    tag=f"serve_chunkst_n{int(n)}e{int(eos_id)}")
-            self._chunk_state_aot[key] = compiled
-            from ..observability import memory_profile as _mp
-            try:
-                _mp.record_executable("serve", f"chunkst_n{int(n)}",
-                                      compiled)
-            except Exception:
-                pass
-            from ..observability import roofline as _rl
-            try:
-                _rl.record_executable("serve", f"chunkst_n{int(n)}",
-                                      compiled)
-            except Exception:
-                pass
-        return compiled, built
-
-    def _spec_exec(self, k1, args):
-        """Telemetry-path speculative-verify executable for draft shape
-        [S, k1] (and this pool/table geometry), AOT-compiled once and
-        ledger-profiled like the decode chunks."""
-        key = (int(k1), self._pool_sig(args[7]), args[3].shape)
-        compiled = self._spec_aot.get(key)
-        built = compiled is None
-        if built:
-            from ..distributed.resilience import compile_cache as _cc
-            with _obs.span("serve:compile", what=f"spec_k{int(k1) - 1}"):
-                compiled, _ = _cc.get_or_compile(
-                    self._spec_verify_jit.lower(*args),
-                    tag=f"serve_spec_k{int(k1) - 1}")
-            self._spec_aot[key] = compiled
-            from ..observability import memory_profile as _mp
-            try:
-                _mp.record_executable("serve", f"spec_k{int(k1) - 1}",
-                                      compiled)
-            except Exception:
-                pass
-            from ..observability import roofline as _rl
-            try:
-                _rl.record_executable("serve", f"spec_k{int(k1) - 1}",
-                                      compiled)
-            except Exception:
-                pass
-        return compiled, built
+    def _warmfill_exec(self, bucket):
+        """The jitted warm (pool-mapped) prefill of this suffix bucket."""
+        if bucket not in self._warm_cache:
+            self._warm_cache[bucket] = jax.jit(
+                self._prefill_warm_impl, donate_argnums=(5, 6))
+        return self._warm_cache[bucket]
 
     def _record_traffic(self, seqlens, steps, live, budgets,
                         launches=None):
@@ -1369,8 +1178,8 @@ class PagedDecoder(CachedDecoder):
         chunk N+1 off the device-resident state before consuming chunk
         N's tokens, overlapping all host bookkeeping with device
         compute; False drains every chunk at dispatch (exact per-chunk
-        walls — telemetry exact-wall mode and chaos drills needing
-        per-chunk determinism); True additionally REFUSES spec_decode
+        walls, for chaos drills needing per-chunk determinism); True
+        additionally REFUSES spec_decode
         (the verify pass is host-interactive by construction) instead
         of silently falling back. Greedy parity with the serial loop
         holds by construction — the fed-back tokens are the ones the
@@ -1379,11 +1188,12 @@ class PagedDecoder(CachedDecoder):
         HBM: bounded by the block pool — `allocator.peak_in_use` blocks,
         not max_slots * max_len (the fixed engine's bill).
 
-        Telemetry-on runs classify every serve-loop iteration into the
-        goodput ledger (source="serve"): prefill-executable builds are
-        `compile`, prefill/chunk device time is `execute` (synced for an
-        honest wall), the admission/bookkeeping host loop is `dispatch`
-        — emitted per iteration to the JSONL sink like TrainStep's.
+        Telemetry-on runs call the same programs and time them: every
+        iteration is classified into the goodput ledger (source="serve"):
+        backend compiles heard during it are `compile`, the loop's waits
+        for prefill/chunk results `execute`, the admission/bookkeeping
+        host loop `dispatch` — emitted per iteration to the JSONL sink
+        like TrainStep's (analysis records: observability/programs.py).
 
         Every run, telemetry on or off, threads every request through
         the per-request lifecycle ledger (`self.request_ledger`,
@@ -1407,11 +1217,3 @@ class PagedDecoder(CachedDecoder):
             replay_backoff_s=replay_backoff_s,
             max_chunk_retries=max_chunk_retries, feed=feed,
             feed_active=feed_active, pipeline=pipeline)
-
-    @property
-    def paged_chunk_cache_size(self):
-        return self._paged_chunk_jit._cache_size()
-
-    @property
-    def spec_verify_cache_size(self):
-        return self._spec_verify_jit._cache_size()

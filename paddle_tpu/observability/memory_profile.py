@@ -25,8 +25,8 @@ emit one ``memory_profile`` JSONL record each, and are snapshotted into
 flight-recorder dumps + HeadroomGuard violation extras (the pre-OOM
 black box carries the ledger of every live executable).
 
-Producers: jit/train_step.py (per-signature AOT executables),
-models/paged_decode.py (telemetry-path prefill/chunk executables),
+Producers: observability/programs.py (telemetry's analysis copy of
+each TrainStep signature and each serve program, on its first call),
 tools/memory_report.py (the registry-lane fingerprint + CI gate).
 """
 from __future__ import annotations
@@ -160,15 +160,12 @@ def verify_ledger(ledger, tol=0.02, floor_bytes=256):
     return errs
 
 
-def record_executable(source, executable, compiled, top_k=8,
-                      extra=None):
+def record_executable(source, executable, compiled, top_k=8):
     """Profile ``compiled`` and record the ledger under
     ``source:executable``: store for forensics, per-executable gauges,
     one JSONL record. Called once per compile (the compile already cost
     seconds; the profile costs milliseconds). Returns the ledger."""
     ledger = executable_ledger(compiled, top_k=top_k)
-    if extra:
-        ledger = dict(ledger, **extra)
     key = f"{source}:{executable}"
     with _LOCK:
         _LEDGERS.pop(key, None)

@@ -26,8 +26,8 @@ Recorded records land in a bounded in-process store, surface as gauges
 modeled_step_seconds,mfu_gap_seconds}{source,executable}``, and emit
 one ``roofline`` JSONL record each.
 
-Producers: jit/train_step.py (per-signature AOT executables),
-models/paged_decode.py (telemetry-path prefill/chunk/spec executables),
+Producers: observability/programs.py (telemetry's analysis copy of
+each TrainStep signature and each serve program, on its first call),
 tools/roofline_report.py (the CI gate + mutation teeth).
 """
 from __future__ import annotations
@@ -81,6 +81,16 @@ def _hlo_text_of(compiled):
         return None
 
 
+def cost_analysis_flops(compiled):
+    """The executable's own FLOP count, or None where it reports none."""
+    try:
+        ca = compiled.cost_analysis()
+        ca = ca[0] if isinstance(ca, (list, tuple)) else ca
+        return float(ca.get("flops", 0.0))
+    except Exception:
+        return None
+
+
 def executable_roofline(compiled, top_k=8, hlo_text=None):
     """Roofline record for one AOT-compiled executable, or None when
     the scheduled HLO is unavailable. Never raises on analysis failure
@@ -96,13 +106,7 @@ def executable_roofline(compiled, top_k=8, hlo_text=None):
     rec["schema"] = SCHEMA
     # modeled-vs-measured flops cross-check: the text-parsed dot/conv
     # arithmetic against the executable's own cost_analysis
-    ca_flops = None
-    try:
-        ca = compiled.cost_analysis()
-        ca = ca[0] if isinstance(ca, (list, tuple)) else ca
-        ca_flops = float(ca.get("flops", 0.0))
-    except Exception:
-        pass
+    ca_flops = cost_analysis_flops(compiled)
     rec["cost_analysis_flops"] = ca_flops
     rec["flops_drift_frac"] = (
         abs(rec["flops_total"] - ca_flops) / max(ca_flops, 1.0)
@@ -184,8 +188,7 @@ def drift_vs_cost_model(rec, tol=0.02):
     return errs
 
 
-def record_executable(source, executable, compiled, top_k=8,
-                      extra=None):
+def record_executable(source, executable, compiled, top_k=8):
     """Price ``compiled`` and record the roofline under
     ``source:executable``: bounded store, per-executable gauges, one
     JSONL record. Called once per compile (the compile already cost
@@ -194,8 +197,6 @@ def record_executable(source, executable, compiled, top_k=8,
     rec = executable_roofline(compiled, top_k=top_k)
     if rec is None:
         return None
-    if extra:
-        rec = dict(rec, **extra)
     key = f"{source}:{executable}"
     with _LOCK:
         _RECORDS.pop(key, None)

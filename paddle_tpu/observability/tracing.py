@@ -6,8 +6,8 @@ PR 1's registry answers *how much*; this answers *where the time went*.
 The instrumented regions are the serve loop's phases (`serve:iteration`,
 `:feed`, `:admit`, `:prefill`, `:wait_first_token`, `:chunk`,
 `:wait_chunk`, `:commit`, ...), the train step (`train_step:call`, with
-`:compile` / `:execute` under telemetry), eager collectives
-(`collective:<op>`), backend compiles (`xla:compile`, from a
+`:execute` and `:analyse` under telemetry; `serve:analyse`), eager
+collectives (`collective:<op>`), backend compiles (`xla:compile`, from a
 `jax.monitoring` listener) and the per-request tracks the
 `RequestLedger` writes (`req:queue`, `req:prefill`, `req:decode`).
 
@@ -61,7 +61,7 @@ __all__ = [
     "span", "record_span", "recording", "tracing_enabled", "enable_tracing",
     "disable_tracing", "drain", "clear", "tail", "chrome_events",
     "export_chrome", "write_rank_part", "merge_rank_parts", "trace_rank",
-    "set_track_name", "new_span_id",
+    "set_track_name", "new_span_id", "compile_seconds",
 ]
 
 define_flag("enable_tracing", False,
@@ -286,17 +286,29 @@ def span(name, **meta):
 
 
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_COMPILED_S = [0.0]
+
+
+def compile_seconds():
+    """Seconds this process has spent in backend compiles so far,
+    counted whether or not anything records. Read before and after a
+    call: the difference is the step ledgers' `compile` bucket."""
+    return _COMPILED_S[0]
 
 
 def _on_duration(event, seconds, fun_name="", **_):
-    """`jax.monitoring` listener: one `xla:compile` span per backend
-    compile (or persistent-cache retrieval) while the tracer records —
-    on the plain jit path a prefill bucket that compiles mid-serve is
-    otherwise invisible from inside the program. The event arrives when
-    the compile ends: start = end - seconds in the ring; in a profiler
-    session's trace a zero-length marker at the end carries the
-    seconds."""
-    if event != _COMPILE_EVENT or not recording():
+    """`jax.monitoring` listener: every backend compile (or
+    persistent-cache retrieval) counts into `compile_seconds()` and is
+    one `xla:compile` span while the tracer records — a prefill bucket
+    that compiles mid-serve is otherwise invisible from inside the
+    program. The event arrives when the compile ends: start = end -
+    seconds in the ring; in a profiler session's trace a zero-length
+    marker at the end carries the seconds."""
+    if event != _COMPILE_EVENT:
+        return
+    with _LOCK:
+        _COMPILED_S[0] += float(seconds)
+    if not recording():
         return
     t1 = time.perf_counter_ns()
     meta = {"fun_name": str(fun_name), "seconds": float(seconds)}

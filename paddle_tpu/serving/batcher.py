@@ -44,6 +44,12 @@ serve ledger's `host_gap` bucket measures the device-idle window
 between consecutive decode executions — the quantity the pipeline
 exists to eliminate.
 
+Telemetry observes this loop and never steers it: every program is
+called by one expression whatever `telemetry` says. Around the calls it
+adds counter mirrors, the step ledger's clock reads (`execute` = the
+loop's waits, `compile` = what the compile listener heard) and, before a
+program's first call, an analysis copy (`observability/programs.py`).
+
 PT_PIPE_TEETH (CI mutation hooks, tools/serving_drill.py
 --verify-teeth): "force_sync" re-uploads the full state every chunk
 (the h2d/host_gap gates must trip); "mutate_feedback" corrupts one
@@ -55,11 +61,11 @@ import os
 import time
 
 import numpy as np
-import jax
 import jax.numpy as jnp
 
 from .. import observability as _obs
 from ..framework.flags import flag as _flag
+from ..observability.programs import profile_program
 from ..resilience import faults as _faults
 from .cache import plan_prefix
 from .scheduler import AdmissionQueue, ReplayTracker
@@ -79,7 +85,6 @@ def serve_loop(eng, requests, *, max_new_tokens=32, eos_token_id=None,
     the full API contract; ``eng`` is the PagedDecoder."""
     from ..models.paged_decode import _Slot
     from ..models.spec_decode import resolve_spec
-    eng._prefill_cache = getattr(eng, "_prefill_cache", {})
     spec_cfg, draft = resolve_spec(spec_decode, eng)
     if pipeline is True and spec_cfg is not None:
         # explicit refusal, not a silent fallback: the verify pass is
@@ -121,7 +126,8 @@ def serve_loop(eng, requests, *, max_new_tokens=32, eos_token_id=None,
     replays = ReplayTracker(max_restarts, replay_backoff_s)
     defer_counts = {}        # rid -> guard deferrals while queued
     chunk_failures = 0       # consecutive decode-pass faults
-    phase = {"compile": 0.0, "execute": 0.0, "host_gap": 0.0}
+    phase = {"execute": 0.0, "host_gap": 0.0}
+    compiled = _obs.tracing.compile_seconds
     t_start = time.perf_counter()
     queue = AdmissionQueue(t_start)
     quads = queue.load(requests, max_new_tokens)
@@ -206,6 +212,18 @@ def serve_loop(eng, requests, *, max_new_tokens=32, eos_token_id=None,
         spec_mirror[name] = (np.array(host, copy=True), arr)
         note_uploads(1)
         return arr
+
+    def analysed(key, program, args):
+        """Telemetry's part before a program's call: its analysis record
+        on the first one (`key`: the label's stem, its number, whatever
+        else tells two programs apart). Returns the compile listener's
+        reading: a first call compiles inside a window `execute` bills
+        (it opens before the call: the request waited for that too), so
+        what the listener hears from here to the call's end comes out of
+        `execute` again."""
+        profile_program(eng._analysed, key, "serve",
+                        lambda: f"{key[0]}{key[1]}", program, args)
+        return compiled()
 
     def blocks_needed(length):
         return -(-length // bs)
@@ -482,12 +500,10 @@ def serve_loop(eng, requests, *, max_new_tokens=32, eos_token_id=None,
         if uploads:
             note_uploads(uploads)
         st = dev["state"]
-        args = (eng._params,) + st + pools
+        args = (eng._params,) + st + pools + (n, eos_dev)
         if telemetry:
-            t0b = time.perf_counter()
-            fn, built = eng._chunk_state_exec(n, eos_dev, args)
-            if built:
-                phase["compile"] += time.perf_counter() - t0b
+            analysed(("chunkst_n", int(n), eos_dev),
+                     eng._paged_chunk_state_jit, args)
         t_disp = time.perf_counter()
         # device-idle attribution: host time between the previous
         # chunk's results landing and THIS dispatch, net of prefill
@@ -500,8 +516,7 @@ def serve_loop(eng, requests, *, max_new_tokens=32, eos_token_id=None,
         with _obs.span("serve:chunk", steps=int(n),
                        lookahead=int(after_n is not None),
                        uploads=uploads):
-            out = fn(*args) if telemetry else \
-                eng._paged_chunk_state_jit(*args, n, eos_dev)
+            out = eng._paged_chunk_state_jit(*args)
         # the batch state, the pools in their order, then whatever
         # counters the engine's program sends home with the tokens
         toks, bad, tok_o, len_o, live_o, budg_o = out[:6]
@@ -710,15 +725,14 @@ def serve_loop(eng, requests, *, max_new_tokens=32, eos_token_id=None,
             args_p = (eng._params, jnp.asarray(ids), jnp.int32(s0),
                       jnp.asarray(tables[i])) + pools \
                 + eng._prefill_extra(i)
-            t0b = time.perf_counter() if telemetry else 0.0
-            fn, built = eng._prefill_exec(bucket, args_p, telemetry)
-            if telemetry and built:
-                # the AOT build pays trace+compile OUTSIDE the call —
-                # billed exactly (the warm call below is pure execute)
-                phase["compile"] += time.perf_counter() - t0b
+            fn = eng._prefill_exec(bucket)
+            c0 = analysed(("prefill_b", bucket), fn, args_p) \
+                if telemetry else 0.0
             t0p = time.perf_counter()
             with _obs.span("serve:prefill", bucket=bucket):
                 enc, *out = fn(*args_p)
+            if telemetry:
+                phase["execute"] -= compiled() - c0
             pools = tuple(out)
             eng.prefill_device_calls += 1
             eng.prefill_tokens_computed += s0
@@ -757,10 +771,9 @@ def serve_loop(eng, requests, *, max_new_tokens=32, eos_token_id=None,
                 args_w = (eng._params, jnp.asarray(ids),
                           jnp.int32(cached + off), jnp.int32(npiece),
                           jnp.asarray(tables[i])) + pools
-                t0b = time.perf_counter() if telemetry else 0.0
-                fn, built = eng._warmfill_exec(bucket, args_w, telemetry)
-                if telemetry and built:
-                    phase["compile"] += time.perf_counter() - t0b
+                fn = eng._warmfill_exec(bucket)
+                c0 = analysed(("warmfill_b", bucket), fn, args_w) \
+                    if telemetry else 0.0
                 if off == 0:
                     t0p = time.perf_counter()
                     if cow_src is not None:
@@ -777,6 +790,8 @@ def serve_loop(eng, requests, *, max_new_tokens=32, eos_token_id=None,
                 with _obs.span("serve:warm_prefill", bucket=bucket,
                                cached=cached + off):
                     enc, *out = fn(*args_w)
+                if telemetry:
+                    phase["execute"] -= compiled() - c0
                 pools = tuple(out)
                 eng.prefill_device_calls += 1
             # only the LAST window's fused first-token matters (the
@@ -854,8 +869,8 @@ def serve_loop(eng, requests, *, max_new_tokens=32, eos_token_id=None,
             with _obs.span("serve:iteration", live=int(live.sum()),
                            queued=len(queue)):
                 it0 = time.perf_counter() if telemetry else 0.0
-                phase["compile"] = phase["execute"] = 0.0
-                phase["host_gap"] = 0.0
+                compiled0 = compiled() if telemetry else 0.0
+                phase["execute"] = phase["host_gap"] = 0.0
                 drain_feed()
                 now = time.perf_counter()
                 # drain on peer death (ISSUE 14): once the watchdog
@@ -1080,11 +1095,8 @@ def serve_loop(eng, requests, *, max_new_tokens=32, eos_token_id=None,
                               spec_dev_arr("budgets", budgets),
                               spec_dev_arr("poison", poison)) + pools
                     note_uploads(2)
-                    if telemetry:
-                        t0b = time.perf_counter()
-                        fn, built = eng._spec_exec(K + 1, args_s)
-                        if built:
-                            phase["compile"] += time.perf_counter() - t0b
+                    c0 = analysed(("spec_k", int(K)), eng._spec_verify_jit,
+                                  args_s) if telemetry else 0.0
                     t0c = time.perf_counter()
                     if telemetry:
                         if last_ready[0] is not None:
@@ -1092,15 +1104,12 @@ def serve_loop(eng, requests, *, max_new_tokens=32, eos_token_id=None,
                                 0.0, t0c - last_ready[0] - dev_busy[0])
                         dev_busy[0] = 0.0
                     with _obs.span("serve:spec_verify", k=int(K)):
-                        if telemetry:
-                            g, bad, *out = fn(*args_s)
-                            jax.block_until_ready(g)
-                        else:
-                            g, bad, *out = eng._spec_verify_jit(*args_s)
+                        g, bad, *out = eng._spec_verify_jit(*args_s)
                         pools = tuple(out)
+                    if telemetry:
+                        phase["execute"] -= compiled() - c0
                     with _obs.span("serve:wait_chunk", steps=int(K + 1)):
-                        # the pass's results reach the host here (already
-                        # there under telemetry, which synced above)
+                        # the pass's results reach the host here
                         g = np.asarray(g)
                         bad = np.asarray(bad)
                     t1c = time.perf_counter()
@@ -1185,8 +1194,9 @@ def serve_loop(eng, requests, *, max_new_tokens=32, eos_token_id=None,
                         consume(rec, n_eff)
                 if telemetry:
                     eng._serve_ledger.step(
-                        it0, time.perf_counter(), compile_s=phase["compile"],
-                        execute_s=phase["execute"],
+                        it0, time.perf_counter(),
+                        compile_s=compiled() - compiled0,
+                        execute_s=max(phase["execute"], 0.0),
                         host_gap_s=phase["host_gap"],
                         extra={"live_slots": int(live.sum()),
                                "chunk_steps": (int(spec_cfg.k + 1)

@@ -100,7 +100,7 @@ class PrefillWorker:
             ids[:ns] = suffix
             args_w = (eng._params, jnp.asarray(ids), jnp.int32(cached),
                       jnp.int32(ns), jnp.asarray(row), kpool, vpool)
-            fn, _ = eng._warmfill_exec(bucket, args_w, False)
+            fn = eng._warmfill_exec(bucket)
             if cow_src is not None:
                 kpool, vpool = eng._cow_copy_jit(
                     kpool, vpool, jnp.int32(cow_src),

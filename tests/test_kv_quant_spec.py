@@ -317,7 +317,7 @@ class TestSpeculativeDecode:
             assert st["emitted"] == sum(len(v) for v in spec.values()) \
                 - len(reqs)
             # one verify executable per draft length
-            assert dec_s.spec_verify_cache_size == 1
+            assert dec_s._spec_verify_jit._cache_size() == 1
 
     def test_spec_identity_with_eos_and_quant(self):
         """Spec + eos masking + int8 pool compose: identical output to

@@ -213,9 +213,9 @@ class TestContinuousBatching:
         budgets = jnp.asarray(np.array([3, 8], np.int32))
         n = 8
         poison = jnp.asarray(np.zeros(2, bool))
-        _, _, kpool, vpool = dec._paged_chunk_jit(
+        *_, kpool, vpool = dec._paged_chunk_state_jit(
             dec._params, toks, jnp.asarray(lens0), jnp.asarray(tables),
-            live, budgets, poison, kpool, vpool, n)
+            live, budgets, poison, kpool, vpool, n, -1)
         # step i writes position lens0+i for slots with i < budget:
         # slot 0 (budget 3) writes lanes 10..12 of its first block and
         # FREEZES — lanes 13..15 stay zero; slot 1 (budget 8) fills
@@ -230,14 +230,30 @@ class TestContinuousBatching:
 
     def test_compiled_set_stays_bounded(self):
         """Serving again (same chunk/maxima, different prompts/lengths)
-        must not add executables — block tables and seqlens are DATA."""
+        must not add executables — block tables and seqlens are DATA.
+        The CPU twin of the cells' `programs.compiles_in_window.serve`
+        = 0, read off the programs the loop runs."""
         model = _tiny()
         model.eval()
         dec = PagedDecoder(model, max_len=64, block_size=16, max_slots=2,
                            num_blocks=9)
+
+        def programs():
+            return (dec._paged_chunk_state_jit._cache_size(),
+                    {b: fn._cache_size()
+                     for b, fn in dec._prefill_cache.items()},
+                    {b: fn._cache_size()
+                     for b, fn in dec._warm_cache.items()})
+
         dec.serve([("a", [1, 2, 3]), ("b", [4, 5, 6, 7, 8])],
                   max_new_tokens=9, chunk=4)
-        n = dec.paged_chunk_cache_size
+        first = programs()
+        # a budget of 9 is the prefill's token and two chunks of 4: one
+        # chunk length; both prompts fall in the 16 bucket
+        assert first == (1, {16: 1}, {})
         dec.serve([("c", [9, 8, 7, 6]), ("d", [5])],
                   max_new_tokens=9, chunk=4)
-        assert dec.paged_chunk_cache_size == n
+        assert programs() == first
+        # and it can fail: a prompt past the bucket adds a program
+        dec.serve([("e", list(range(1, 20)))], max_new_tokens=9, chunk=4)
+        assert programs() != first

@@ -57,7 +57,7 @@ def _programs(dec):
              flag(S), kp, vp)
     return {
         "state_chunk": (dec._paged_chunk_state_jit, state + (2, -1)),
-        "plain_chunk": (dec._paged_chunk_jit, state + (2,)),
+        "state_chunk_eos": (dec._paged_chunk_state_jit, state + (2, 0)),
         "spec_verify": (dec._spec_verify_jit,
                         (dec._params, i32(S, 3)) + state[2:]),
         "warm_prefill": (jax.jit(dec._prefill_warm_impl,
@@ -88,7 +88,7 @@ def pool_moves(text, pool_shape):
     return hits
 
 
-@pytest.mark.parametrize("program", ["state_chunk", "plain_chunk",
+@pytest.mark.parametrize("program", ["state_chunk", "state_chunk_eos",
                                      "spec_verify", "warm_prefill",
                                      "cold_prefill"])
 def test_no_program_moves_a_pool(program):
@@ -171,10 +171,10 @@ def test_layer_rows_land_in_their_layer(variant):
                               jnp.asarray(tables[s]), kp, vp)
         first.append(dec.decode_first_token(enc)[0])
     lens0 = np.array([len(p) for p in prompts] + [7], np.int32)
-    toks, bad, kp, vp = dec._paged_chunk_jit(
+    toks, bad, *_, kp, vp = dec._paged_chunk_state_jit(
         dec._params, jnp.asarray(first + [3], jnp.int32),
         jnp.asarray(lens0), jnp.asarray(tables), jnp.asarray(live),
-        jnp.asarray(budgets), jnp.zeros(SLOTS, bool), kp, vp, steps)
+        jnp.asarray(budgets), jnp.zeros(SLOTS, bool), kp, vp, steps, -1)
     toks = np.asarray(toks)
     assert not np.asarray(bad).any()
 

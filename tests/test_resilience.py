@@ -132,43 +132,101 @@ class TestCompileCache:
         assert info["cache"] == "off"
         assert cc.stats() == {k: 0 for k in cc.stats()}
 
+    @staticmethod
+    def _train(steps=3):
+        pt.seed(3)
+        m = pt.nn.Sequential(pt.nn.Linear(6, 8), pt.nn.Tanh(),
+                             pt.nn.Linear(8, 1))
+        opt = pt.optimizer.AdamW(learning_rate=1e-2,
+                                 parameters=m.parameters())
+        step = pt.jit.TrainStep(
+            m, lambda o, t: pt.nn.functional.mse_loss(o, t), opt)
+        rng = np.random.default_rng(0)
+        out = []
+        for _ in range(steps):
+            x = pt.to_tensor(
+                rng.standard_normal((4, 6)).astype("float32"))
+            y = pt.to_tensor(np.zeros((4, 1), "float32"))
+            out.append(float(step((x,), (y,))))
+        return out, step
+
+    @staticmethod
+    def _jax_cache_events(fn):
+        """fn()'s result and what JAX's persistent cache said meanwhile."""
+        events = []
+
+        def listen(event, **_):
+            if event.startswith("/jax/compilation_cache/cache_"):
+                events.append(event.rsplit("/", 1)[1])
+
+        jax.monitoring.register_event_listener(listen)
+        try:
+            return fn(), events
+        finally:
+            jax.monitoring.unregister_event_listener(listen)
+
     def test_trainstep_warm_restart_parity(self, cache_dir):
         """The restart contract end to end: a second TrainStep over the
-        same program serves BOTH its executables from disk and walks
-        the identical loss trajectory."""
+        same program finds BOTH its signatures on disk, the copies
+        telemetry reads (the counters) and the executables that run
+        (JAX's cache under the same flag), and walks the identical loss
+        trajectory."""
         import paddle_tpu.observability as obs
-
-        def build():
-            pt.seed(3)
-            m = pt.nn.Sequential(pt.nn.Linear(6, 8), pt.nn.Tanh(),
-                                 pt.nn.Linear(8, 1))
-            opt = pt.optimizer.AdamW(learning_rate=1e-2,
-                                     parameters=m.parameters())
-            return pt.jit.TrainStep(
-                m, lambda o, t: pt.nn.functional.mse_loss(o, t), opt)
-
-        def run(step):
-            rng = np.random.default_rng(0)
-            out = []
-            for _ in range(3):
-                x = pt.to_tensor(
-                    rng.standard_normal((4, 6)).astype("float32"))
-                y = pt.to_tensor(np.zeros((4, 1), "float32"))
-                out.append(float(step((x,), (y,))))
-            return out
 
         obs.enable()
         try:
-            l1 = run(build())
+            l1, _ = self._train()
             st1 = cc.stats()
-            l2 = run(build())
+            (l2, _), events = self._jax_cache_events(self._train)
             st2 = cc.stats()
         finally:
             obs.disable()
         assert st1["misses"] == 2 and st1["hits"] == 0, st1
         assert st2["hits"] == 2 and st2["misses"] == 2, st2
+        assert events.count("cache_hits") >= 2, events
+        assert "cache_misses" not in events
         np.testing.assert_allclose(l1, l2, rtol=1e-6)
-        assert build().compile_cache_last is None
+        assert self._train(0)[1].compile_cache_last is None
+
+    @staticmethod
+    def _serve():
+        from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
+        from paddle_tpu.models.paged_decode import PagedDecoder
+        pt.seed(5)
+        model = LlamaForCausalLM(LlamaConfig(
+            vocab_size=97, hidden_size=32, intermediate_size=64,
+            num_hidden_layers=1, num_attention_heads=2,
+            num_key_value_heads=2, max_position_embeddings=64,
+            use_flash_attention=False))
+        model.eval()
+        dec = PagedDecoder(model, max_len=32, block_size=16, max_slots=2,
+                           num_blocks=9)
+        return dec.serve([(0, [3, 1, 4, 1, 5], 4), (1, [9, 2, 6], 4)],
+                         chunk=2), dec
+
+    @pytest.mark.parametrize("entry", ["_train", "_serve"])
+    def test_flag_alone_serves_the_programs_that_run(self, cache_dir,
+                                                     entry):
+        """With no telemetry the one flag still spares a restart its
+        compiles: the jitted calls (two train-step signatures; a prefill
+        bucket and a chunk) are retrieved from `<dir>/xla`, and emptying
+        the flag gives JAX's cache settings back."""
+        prior = dict(cc._JAX_PRIOR)
+        assert jax.config.jax_compilation_cache_dir == (
+            os.environ.get("JAX_COMPILATION_CACHE_DIR")
+            or os.path.join(cache_dir, "xla"))
+        assert jax.config.jax_persistent_cache_min_compile_time_secs == 0
+        run = getattr(self, entry)
+        first, _ = run()
+        (again, engine), events = self._jax_cache_events(run)
+        assert events.count("cache_hits") >= 2, events
+        assert "cache_misses" not in events
+        assert first == again and engine._analysed == {}
+        assert cc.stats() == {k: 0 for k in cc.stats()}
+        assert not glob.glob(os.path.join(cache_dir, "*.ptcc"))
+        set_flags({"compile_cache_dir": ""})
+        assert prior and not cc._JAX_PRIOR
+        assert {k: getattr(jax.config, k) for k in prior} == prior
 
 
 # -- checkpoint commit protocol ----------------------------------------------

@@ -323,11 +323,8 @@ class TestServeRoofline:
         obs.disable()
         recs = rl.records()
         assert any(k.startswith("serve:prefill_b") for k in recs), recs
-        # the pipelined loop prices the state-carrying chunk
-        # executable (chunkst_n*); the spec/serial-compat path keeps
-        # the plain chunk_n* spelling
-        assert any(k.startswith(("serve:chunk_n", "serve:chunkst_n"))
-                   for k in recs), recs
+        # the one decode chunk program (chunkst_n*)
+        assert any(k.startswith("serve:chunkst_n") for k in recs), recs
         scopes = set()
         for rec in recs.values():
             assert rl.verify_record(rec) == []

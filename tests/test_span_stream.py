@@ -215,12 +215,10 @@ def test_serve_spans_on_the_plain_path(quiet):
     for name in SERVE_SPANS + ("req:queue", "req:prefill", "req:decode"):
         assert by.get(name), f"no {name} span"
 
-    # observation did not change the observed: same tokens, the plain
-    # jit programs, no AOT twin built
+    # observation did not change the observed: same tokens, and no
+    # analysis record without telemetry
     assert out == reference
-    for cache in ("_prefill_aot", "_warm_aot", "_chunk_aot",
-                  "_chunk_state_aot", "_spec_aot"):
-        assert getattr(dec, cache) == {}, cache
+    assert dec._analysed == {}
 
     # the tree: phases under their iteration, waits under their admit
     ids = {s["id"]: s for s in spans}
@@ -292,7 +290,7 @@ def test_spec_verify_commits_through_the_same_spans(armed):
     tokens = sum(s["meta"]["tokens"]
                  for s in by["serve:commit"] + by["serve:admit"])
     assert tokens == sum(len(t) for t in out.values())
-    assert dec._spec_aot == {}
+    assert dec._analysed == {}          # spans are not telemetry
     assert all(r.tpot_s() is None or r.tpot_s() > 0
                for r in dec.request_ledger.completed_records())
 
@@ -309,7 +307,8 @@ def test_train_step_emits_one_call_span_a_step(quiet):
     calls = _by_name(tracing.tail())["train_step:call"]
     assert [s["meta"]["step"] for s in calls] == [1, 2, 3]
     assert all(s["parent"] is None for s in calls)
-    assert step._compiled_by_sig == {}       # the plain jit path ran
+    # spans are not telemetry: nothing analysed
+    assert step._analysed == {} and step.analysed_executables() == {}
 
 
 def test_train_step_phases_nest_under_the_call_with_telemetry(armed):
@@ -322,8 +321,15 @@ def test_train_step_phases_nest_under_the_call_with_telemetry(armed):
         obs.disable()
     by = _by_name(tracing.tail())
     call, = by["train_step:call"]
-    assert by["train_step:compile"][0]["parent"] == call["id"]
+    # telemetry's analysis, the timed call and every backend compile of
+    # the step's program lie under the one call span
+    analyse, = by["train_step:analyse"]
+    assert analyse["parent"] == call["id"]
     assert by["train_step:execute"][0]["parent"] == call["id"]
+    own = [s for s in by["xla:compile"]
+           if "_traced" in s["meta"]["fun_name"]]
+    assert own and all(s["parent"] in (call["id"], analyse["id"])
+                       for s in own)
 
 
 # -- (e) compiles --------------------------------------------------------------

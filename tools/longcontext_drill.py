@@ -422,7 +422,7 @@ def lane_train_main(refs_arg):
                           (global_batch, TRAIN_SEQ))
 
     obs.reset()
-    obs.enable()             # telemetry path caches the AOT executable
+    obs.enable()             # telemetry keeps the analysed executable
     model, step = _train_build(plan, cp=True)
     if teeth == "break_parity":
         # CI mutation: perturb ONE weight so the parity gate must trip
@@ -440,9 +440,8 @@ def lane_train_main(refs_arg):
     nh = TRAIN_DIMS["num_attention_heads"]
     hd = TRAIN_DIMS["hidden_size"] // nh
     try:
-        compiled = list(step._compiled_by_sig.values())
-        assert compiled, ("telemetry compile path did not cache an "
-                          "executable")
+        compiled = list(step.analysed_executables().values())
+        assert compiled, "telemetry analysed no executable"
         text = compiled[-1].runtime_executable() \
             .hlo_modules()[0].to_string()
         hlo_lint.assert_sharding(

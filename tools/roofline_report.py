@@ -4,8 +4,9 @@ lane, contract- and drift-gated (the CI face of
 observability/roofline.py — ISSUE 16).
 
 Builds the tiny llama train lane (llama_tiny, 2 decoder layers, 3
-telemetry-enabled steps — per-signature AOT executables record their
-rooflines at compile time), then gates every recorded executable:
+telemetry-enabled steps — each signature's analysis executable records
+its roofline on the signature's first call), then gates every recorded
+executable:
 
 - **telescoping** (roofline.verify_record): bound-class seconds sum to
   the modeled step wall within --tol (default 2%), class fractions sum
@@ -52,8 +53,8 @@ SCHEMA = "paddle_tpu.roofline_report/1"
 
 def build_train_records(steps=3):
     """Run the tiny llama train lane with telemetry on; returns the
-    roofline records its AOT compiles stored ({source:executable ->
-    record})."""
+    roofline records its analysis compiles stored ({source:executable
+    -> record})."""
     import numpy as np
     import paddle_tpu as pt
     import paddle_tpu.observability as obs
