@@ -206,9 +206,12 @@ def tune_ragged_blocks(num_heads, num_kv_heads, head_dim,
                        candidates=(16, 32, 64, 128, 256)):
     """Pick the KV pool block_size for the ragged paged-attention kernel
     on the local device (one compile + timed run per candidate, the
-    flash pattern). The block size trades grid overhead (small blocks =
-    many grid steps) against ragged waste (big blocks = more dead tokens
-    fetched past each sequence's length); the winner is cached under
+    flash pattern). The kernel copies a sequence's live blocks in
+    groups and attends several small blocks in one product, so the block
+    size no longer buys grid steps: it trades the copies issued a token
+    (small blocks = more, shorter DMAs) against ragged waste (big blocks
+    = more dead tokens fetched past each sequence's length, and masked
+    in the last product); the winner is cached under
     ("ragged_blocks", geometry) and consulted by
     PagedDecoder(block_size="auto")."""
     import numpy as np
@@ -243,7 +246,7 @@ def tune_ragged_blocks(num_heads, num_kv_heads, head_dim,
 def lookup_kv_quant_blocks(num_heads, num_kv_heads, head_dim, dtype):
     """Cached pool block_size winner for the QUANTIZED (int8-KV) ragged
     kernel at this attention geometry, or None. Separate cache key from
-    the unquantized kernel — in-VMEM dequant shifts the grid-overhead /
+    the unquantized kernel — blocks of half the bytes shift the copies /
     ragged-waste trade, so winners don't transfer. Raw-store read, same
     no-stat-perturbation contract as lookup_ragged_blocks."""
     return AutoTuneCache.instance()._store.get(

@@ -12,25 +12,40 @@ HBM before attending — every slot READS the full window twice (pool
 gather read, then attention read of the gathered copy) and writes it
 once, regardless of its actual length. Here the pool blocks stream
 HBM -> VMEM exactly once, and whole blocks past `seq_lens[s]` are never
-fetched at all (the ragged early-exit), so a slot at position p costs
+copied at all (the ragged early-exit), so a slot at position p costs
 `(p // bs + 1) * bs` tokens of read traffic instead of `2 * W` reads
 plus a `W` write.
 
 Mechanics:
 
-- grid = (S, blocks_per_seq); scalar-prefetched block tables + seq_lens
-  drive the K/V BlockSpec index maps, so the pipeline fetches pool
-  block `tables[s, j]` for grid step (s, j) — the gather IS the fetch
-  (pltpu.PrefetchScalarGridSpec, the T3-style fusion of gather and
-  attention into one pipeline).
-- blocks past the sequence's last block CLAMP their index map to the
-  last live block: Mosaic skips the re-fetch when consecutive grid
-  steps map to the same block, and `pl.when` skips the compute — the
-  early-exit costs no HBM and (nearly) no cycles.
-- online softmax (running m / l / acc in VMEM scratch across the j
-  axis, exactly like flash_attention.py's streaming kernels) keeps the
-  whole reduction in one pass; grouped (GQA) heads attend against the
-  unrepeated K/V block via a per-group MXU dot.
+- grid = (S,), one step a slot. The pools stay in HBM
+  (`memory_space=ANY`), seen as `[blocks, bs * Hkv, D]`: tokens and KV
+  heads merged into one axis of rows, which under the chip's tiled
+  layouts is the same bytes (XLA makes the reshape a bitcast). The body
+  copies the slot's LIVE blocks itself, through the scalar-prefetched
+  block table, into two VMEM buffers of `group` blocks for K and two
+  for V: while one group is attended the next is in flight, this slot's
+  or the next slot's first, so the DMA engine never waits for a grid
+  step. A block past `seq_lens[s] // bs` gets no copy, and the loops
+  over blocks end with the live ones: the early exit costs nothing, and
+  a table entry behind the live ones is never read.
+- one product `q [nh, D] x K^T [D, chunk * bs * Hkv]` gives the scores
+  of every query head against every (token, KV head) row of `chunk`
+  blocks; a query head keeps the columns of its own KV head (the rest
+  are masked before the softmax and are 0 in `p`), so `p x V` lands in
+  the accumulator's layout with no head ever sliced out of a block. The
+  masked share is arithmetic the MXU has to spare; the kernel's time is
+  that of its bytes. K, V and q go to the MXU in the dtype they are
+  stored in, float32 accumulated (`flash_attention._dot`); `p` is
+  rounded to that dtype before the second product.
+- `_blocks_per_step` sizes `group` and `chunk` from the block the kernel
+  is handed (its bytes against the buffers, its rows against a product's
+  columns): 4 and 1 at 32 KV heads, 32 and 16 at 2.
+- online softmax (running m / l / acc in VMEM scratch across the slot's
+  products) keeps the whole reduction in one pass. The plain, partials
+  (sharded) and int8 entry points share the one step body: int8 codes
+  are exact in q's dtype and their row scales multiply score and
+  probability columns; the partials finish with (o, lse).
 
 On non-TPU backends the kernel runs in interpret mode so tier-1 CI
 exercises the exact kernel code (flash_attention.py's pattern).
@@ -47,6 +62,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ._x64 import i32_trace
+from .flash_attention import _NN, _NT, _dot
 
 __all__ = ["ragged_paged_attention", "ragged_paged_attention_sharded",
            "ragged_paged_attention_quant",
@@ -56,7 +72,7 @@ __all__ = ["ragged_paged_attention", "ragged_paged_attention_sharded",
 
 import numpy as np
 
-# the kernel body and index maps are re-traced at pallas lowering time,
+# the kernel body is re-traced at pallas lowering time,
 # OUTSIDE the i32_trace context — every scalar constant must carry an
 # explicit 32-bit dtype or global x64 mode promotes it to f64/i64, which
 # Mosaic (and the interpret-mode verifier) reject
@@ -67,109 +83,247 @@ def _interpret():
     return jax.default_backend() != "tpu"
 
 
-def _kernel(tabs_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
-            m_sc, l_sc, acc_sc, *, bs, nkv, nrep, scale):
-    """One (slot, kv-block) grid step.
+# both pools' double buffers together; what the scores, the mask and the
+# pipeline's own q / output / scale blocks need fits beside them under
+# the limit _launch asks for (the chip's default scoped limit is 16 MiB
+# of its 128)
+_BUFFER_BYTES = 8 * 2**20
+# score columns one product covers: a 64-token block of 32 KV heads
+# alone, 16 blocks of 2 KV heads together
+_PRODUCT_COLS = 2048
 
-    q_ref [nh, hd]; k_ref/v_ref [bs, nkv, hd] = pool block tables[s, j];
-    o_ref [nh, hd]; scratch m/l [nh, 1] f32, acc [nh, hd] f32 carried
-    across the j axis. lens[s] is the position of the token just
-    written, so the live window is positions 0..lens[s] inclusive.
+
+def _blocks_per_step(block_bytes, block_rows, blocks_per_seq):
+    """(blocks one DMA group carries, blocks one product covers), from
+    what the kernel can see: a group is as many blocks as the buffers
+    hold (K and V, two buffers each), whole products and at most a
+    sequence. Blocks of 2048 rows (32 KV heads, 0.5 MB of K and as much
+    of V) come 4 a group and 1 a product; blocks of 128 rows (2 KV
+    heads, 32 KB) 32 and 16."""
+    chunk = max(1, min(blocks_per_seq, _PRODUCT_COLS // block_rows))
+    chunks = max(1, min(-(-blocks_per_seq // chunk),
+                        _BUFFER_BYTES // (4 * block_bytes * chunk)))
+    return chunk * chunks, chunk
+
+
+def _step_kernel(tabs_ref, lens_ref, q_ref, k_hbm, v_hbm, *rest, bs, nkv,
+                 nrep, scale, group, chunk, quant, partials):
+    """One slot: its live pool blocks stream HBM -> VMEM in groups of
+    `group`, double-buffered, the next group (this slot's, or the next
+    slot's first) in flight while this one is attended, `chunk` blocks
+    a product.
+
+    q_ref [nh, hd]; k_hbm / v_hbm the whole pools [blocks, bs * nkv,
+    hd], left in HBM: row t * nkv + g of a block is token t, KV head g;
+    with `quant` two more inputs ks_ref / vs_ref [1, columns] f32, the
+    slot's per-row scales laid out along the score columns of its
+    blocks; o_ref [nh, hd] (with `partials` float32, and lse_ref
+    [nh, 1] beside it). lens[s] is the position of the token just
+    written: the live window is positions 0..lens[s], -1 an empty
+    shard.
     """
+    if quant:
+        ks_ref, vs_ref, *rest = rest
+    if partials:
+        o_ref, lse_ref, *rest = rest
+    else:
+        o_ref, *rest = rest
+    kbuf, vbuf, sem, turn, m_sc, l_sc, acc_sc = rest
     s = pl.program_id(0)
-    j = pl.program_id(1)
-    nb = pl.num_programs(1)
+    nslots = pl.num_programs(0)
+    nh = nkv * nrep
+    rows = bs * nkv                     # of one block
+    cols = chunk * rows                 # of one product
+    one, zero = np.int32(1), np.int32(0)
+    bs_i, grp, chk = np.int32(bs), np.int32(group), np.int32(chunk)
+    # int8 codes are exact in q's dtype; a pool stored in another float
+    # type than q keeps the float32 contract
+    cdtype = q_ref.dtype if quant or k_hbm.dtype == q_ref.dtype \
+        else jnp.float32
+
+    def blocks_of(slot):
+        return (lens_ref[slot] + bs_i) // bs_i          # 0 when empty
+
+    def copies(slot, j, buf, i):
+        """The K and V copies of member i of `slot`'s group j."""
+        blk = tabs_ref[slot, j * grp + i]
+        at = pl.ds(pl.multiple_of(i * np.int32(rows), rows), rows)
+        return [pltpu.make_async_copy(pool.at[blk], dst.at[buf, at],
+                                      sem.at[buf, lane])
+                for pool, dst, lane in ((k_hbm, kbuf, 0), (v_hbm, vbuf, 1))]
+
+    def start(slot, j, buf):
+        def member(i, _):
+            for dma in copies(slot, j, buf, i):
+                dma.start()
+            return _
+        lax.fori_loop(zero, jnp.minimum(grp, blocks_of(slot) - j * grp),
+                      member, zero)
+
+    @pl.when(s == 0)
+    def _first():
+        turn[0] = zero
+        if chunk > 1:
+            # a product covers `chunk` blocks, the last of a slot maybe
+            # fewer live ones: what it reads behind them is zero or an
+            # earlier live block, never what the chip left in VMEM
+            # (p is 0 there, and 0 x NaN is NaN)
+            vbuf[:] = jnp.zeros_like(vbuf)
+
+    nblk = blocks_of(s)
+    ngroups = (nblk + grp - one) // grp
+    first = turn[0]                      # groups attended before this slot
+    prev_blocks = blocks_of(jnp.maximum(s - one, zero))
+
+    # a slot's first group is started by the slot before it, behind its
+    # own last group; the first slot, and one behind an empty shard,
+    # starts its own
+    @pl.when(jnp.logical_or(s == 0, prev_blocks == 0))
+    def _own():
+        start(s, zero, first % 2)
+
+    m_sc[:] = jnp.full_like(m_sc, NEG_INF)
+    l_sc[:] = jnp.zeros_like(l_sc)
+    acc_sc[:] = jnp.zeros_like(acc_sc)
+
     pos = lens_ref[s]
+    q = q_ref[:].astype(cdtype)                          # [nh, hd]
+    # score column c is (token c // nkv, kv head c % nkv): a query head
+    # keeps the columns of its own kv head
+    col = lax.broadcasted_iota(jnp.int32, (nh, cols), 1)
+    head = lax.broadcasted_iota(jnp.int32, (nh, cols), 0) // np.int32(nrep)
+    own = lax.rem(col, np.int32(nkv)) == head
 
-    @pl.when(j == 0)
-    def _init():
-        m_sc[:] = jnp.full_like(m_sc, NEG_INF)
-        l_sc[:] = jnp.zeros_like(l_sc)
-        acc_sc[:] = jnp.zeros_like(acc_sc)
-
-    # ragged early-exit: block j holds positions [j*bs, (j+1)*bs) — past
-    # the last live block nothing is fetched (index map clamps) and
-    # nothing is computed
-    @pl.when(j * bs <= pos)
-    def _step():
-        q = q_ref[:].astype(jnp.float32) * scale        # [nh, hd]
-        col = j * bs + lax.broadcasted_iota(jnp.int32, (1, bs), 1)
-        live = col <= pos                               # [1, bs]
-        # grouped scores against the UNREPEATED block: one [nrep, hd] x
-        # [hd, bs] MXU dot per kv group
-        st_groups = []
-        for g in range(nkv):
-            qg = q[g * nrep:(g + 1) * nrep, :]          # [nrep, hd]
-            kg = k_ref[:, g, :].astype(jnp.float32)     # [bs, hd]
-            st_groups.append(lax.dot_general(
-                qg, kg, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32))    # [nrep, bs]
-        st = jnp.concatenate(st_groups, axis=0) if nkv > 1 \
-            else st_groups[0]                           # [nh, bs]
-        st = jnp.where(live, st, NEG_INF)
+    def attend(buf, c, b0):
+        """Product c of buffer `buf`: the slot's blocks b0 .. b0 + chunk."""
+        at = pl.ds(pl.multiple_of(c * np.int32(cols), cols), cols)
+        st = _dot(q, kbuf[buf, at, :].astype(cdtype), _NT) * scale
+        live = jnp.logical_and(
+            own, col < (pos - b0 * bs_i + one) * np.int32(nkv))
+        if quant:
+            sc_at = pl.ds(pl.multiple_of(b0 * np.int32(rows), rows), cols)
+            st = st * ks_ref[:, sc_at]
+        st = jnp.where(live, st, NEG_INF)                # [nh, cols]
         m = m_sc[:]
         m_new = jnp.maximum(m, st.max(axis=-1, keepdims=True))
         p = jnp.exp(st - m_new)
         alpha = jnp.exp(m - m_new)
         l_sc[:] = l_sc[:] * alpha + p.sum(axis=-1, keepdims=True)
-        o_groups = []
-        for g in range(nkv):
-            pg = p[g * nrep:(g + 1) * nrep, :]          # [nrep, bs]
-            vg = v_ref[:, g, :].astype(jnp.float32)     # [bs, hd]
-            o_groups.append(lax.dot_general(
-                pg, vg, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32))    # [nrep, hd]
-        o = jnp.concatenate(o_groups, axis=0) if nkv > 1 \
-            else o_groups[0]                            # [nh, hd]
+        if quant:
+            # a dead column's p is 0, its scale may be anything
+            p = jnp.where(live, p * vs_ref[:, sc_at], np.float32(0))
+        o = _dot(p.astype(cdtype), vbuf[buf, at, :].astype(cdtype), _NN)
         acc_sc[:] = acc_sc[:] * alpha + o
         m_sc[:] = m_new
 
-    @pl.when(j == nb - 1)
-    def _finish():
+    def one_group(j, _):
+        buf = (first + j) % 2
+        mine = j + one < ngroups
+        nxt_slot = jnp.where(mine, s, s + one)
+
+        @pl.when(jnp.logical_or(mine, nxt_slot < nslots))
+        def _prefetch():
+            start(nxt_slot, jnp.where(mine, j + one, zero), one - buf)
+
+        here = jnp.minimum(grp, nblk - j * grp)          # live blocks
+
+        # a block past the slot's last is never copied and starts no
+        # product: the loops end with the live ones
+        def one_product(c, _):
+            def arrived(i, _):
+                for dma in copies(s, j, buf, c * chk + i):
+                    dma.wait()
+                return _
+            lax.fori_loop(zero, jnp.minimum(chk, here - c * chk),
+                          arrived, zero)
+            attend(buf, c, j * grp + c * chk)
+            return _
+        lax.fori_loop(zero, (here + chk - one) // chk, one_product, zero)
+        return _
+
+    lax.fori_loop(zero, ngroups, one_group, zero)
+    turn[0] = first + ngroups
+
+    if partials:
+        l_safe = jnp.maximum(l_sc[:], np.float32(1e-30))  # [nh, 1]
+        o_ref[:] = acc_sc[:] / l_safe
+        lse_ref[:] = m_sc[:] + jnp.log(l_safe)
+    else:
         o_ref[:] = (acc_sc[:] / l_sc[:]).astype(o_ref.dtype)
 
 
 @i32_trace
-def _ragged_call(q, kpool, vpool, tables, seq_lens, scale):
+def _launch(q, kpool, vpool, tables, seq_lens, scale, scales=None,
+            partials=False):
+    """The one pallas launch behind the three entry points. `scales` =
+    (kscale, vscale) [num_blocks, bs] f32 marks int8 pools; `partials`
+    returns (o [S, nh, hd] f32 normalized within the launch, lse
+    [S, nh, 1] f32) for the sharded merge, where seq_lens may be -1."""
     S, nh, hd = q.shape
-    nb_pool, bs, nkv, _ = kpool.shape
+    nblocks, bs, nkv, _ = kpool.shape
     mb = tables.shape[1]
-    nrep = nh // nkv
+    rows = bs * nkv
     tables = tables.astype(jnp.int32)
     seq_lens = seq_lens.astype(jnp.int32)
+    block_bytes = rows * hd * kpool.dtype.itemsize
+    group, chunk = _blocks_per_step(block_bytes, rows, mb)
+    # tokens and KV heads merged into one axis of rows: the same bytes
+    # under the chip's tiled layouts (XLA makes it a bitcast, not a
+    # copy), and no head is ever sliced out of a block
+    kpool = kpool.reshape(nblocks, rows, hd)
+    vpool = vpool.reshape(nblocks, rows, hd)
 
-    # numpy scalar: index maps must not capture traced constants
-    bs_i = np.int32(bs)
+    def slot(*shape):
+        return pl.BlockSpec((None,) + shape,
+                            lambda s, tabs, lens: (s,) + (0,) * len(shape))
 
-    def kv_map(s, j, tabs, lens):
-        # clamp past-the-end j to the last live block: same index as the
-        # previous grid step => the pipeline skips the HBM fetch
-        return (tabs[s, jnp.minimum(j, lens[s] // bs_i)], 0, 0, 0)
-
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    in_specs, operands = [slot(nh, hd), hbm, hbm], [q, kpool, vpool]
+    if scales is not None:
+        # the slot's scale rows, gathered through its table by XLA and
+        # laid along the score columns (each token's nkv times over),
+        # to a whole number of products
+        pad = -mb % chunk
+        for sc in scales:
+            sc = jnp.take(sc.astype(jnp.float32), tables, axis=0)
+            sc = jnp.pad(jnp.repeat(sc, nkv, axis=-1),
+                         ((0, 0), (0, pad), (0, 0)), constant_values=1.0)
+            in_specs.append(slot(1, (mb + pad) * rows))
+            operands.append(sc.reshape(S, 1, -1))
+    out_specs, out_shape = slot(nh, hd), jax.ShapeDtypeStruct(
+        (S, nh, hd), jnp.float32 if partials else q.dtype)
+    if partials:
+        out_specs = [out_specs, slot(nh, 1)]
+        out_shape = [out_shape,
+                     jax.ShapeDtypeStruct((S, nh, 1), jnp.float32)]
+    buffers = [pltpu.VMEM((2, group * rows, hd), pool.dtype)
+               for pool in (kpool, vpool)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(S, mb),
-        in_specs=[
-            pl.BlockSpec((None, nh, hd), lambda s, j, tabs, lens: (s, 0, 0)),
-            pl.BlockSpec((None, bs, nkv, hd), kv_map),
-            pl.BlockSpec((None, bs, nkv, hd), kv_map),
-        ],
-        out_specs=pl.BlockSpec((None, nh, hd),
-                               lambda s, j, tabs, lens: (s, 0, 0)),
-        scratch_shapes=[
+        grid=(S,),
+        in_specs=in_specs,
+        out_specs=out_specs,
+        scratch_shapes=buffers + [
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.SMEM((1,), jnp.int32),
             pltpu.VMEM((nh, 1), jnp.float32),
             pltpu.VMEM((nh, 1), jnp.float32),
             pltpu.VMEM((nh, hd), jnp.float32),
         ],
     )
-    kernel = functools.partial(_kernel, bs=bs, nkv=nkv, nrep=nrep,
-                               scale=np.float32(scale))
+    kernel = functools.partial(
+        _step_kernel, bs=bs, nkv=nkv, nrep=nh // nkv,
+        scale=np.float32(scale), group=group, chunk=chunk,
+        quant=scales is not None, partials=partials)
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((S, nh, hd), q.dtype),
+        out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=4 * group * block_bytes + 32 * 2**20),
         interpret=_interpret(),
-    )(tables, seq_lens, q, kpool, vpool)
+    )(tables, seq_lens, *operands)
 
 
 def ragged_paged_attention(q, kpool, vpool, tables, seq_lens, scale=None):
@@ -182,11 +336,11 @@ def ragged_paged_attention(q, kpool, vpool, tables, seq_lens, scale=None):
     `arange(W) <= pos` mask). Returns [S, nh, hd] in q.dtype.
 
     Rows whose table entries past `seq_lens[s] // block_size` are
-    unallocated (zeros) are safe: the index map never reads them.
+    unallocated (zeros) are safe: no copy is issued for them.
     """
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
-    return _ragged_call(q, kpool, vpool, tables, seq_lens, float(scale))
+    return _launch(q, kpool, vpool, tables, seq_lens, float(scale))
 
 
 # -- context-length-sharded decode attention (ISSUE 19 tentpole a) ------------
@@ -201,117 +355,10 @@ def ragged_paged_attention(q, kpool, vpool, tables, seq_lens, scale=None):
 # code path serves blockwise execution on one chip (bounding VMEM-
 # resident table span and per-launch KV traffic) and ring-style
 # placement of shards over the mp axis (each chip runs its shard, the
-# merge is a tiny [S, nh] reduction on the combining chip).
-
-def _pkernel(tabs_ref, lens_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
-             m_sc, l_sc, acc_sc, *, bs, nkv, nrep, scale):
-    """Partials grid step: the _kernel online-softmax body, finishing
-    with (o = acc / max(l, tiny) in f32, lse = m + log(max(l, tiny)))
-    instead of a cast final output. A shard with no live tokens
-    (lens[s] < 0) computes nothing and lands at o = 0, lse ~ -inf, so
-    its merge weight exp(lse - M) underflows to exactly 0."""
-    s = pl.program_id(0)
-    j = pl.program_id(1)
-    nb = pl.num_programs(1)
-    pos = lens_ref[s]
-
-    @pl.when(j == 0)
-    def _init():
-        m_sc[:] = jnp.full_like(m_sc, NEG_INF)
-        l_sc[:] = jnp.zeros_like(l_sc)
-        acc_sc[:] = jnp.zeros_like(acc_sc)
-
-    @pl.when(j * bs <= pos)
-    def _step():
-        q = q_ref[:].astype(jnp.float32) * scale        # [nh, hd]
-        col = j * bs + lax.broadcasted_iota(jnp.int32, (1, bs), 1)
-        live = col <= pos                               # [1, bs]
-        st_groups = []
-        for g in range(nkv):
-            qg = q[g * nrep:(g + 1) * nrep, :]          # [nrep, hd]
-            kg = k_ref[:, g, :].astype(jnp.float32)     # [bs, hd]
-            st_groups.append(lax.dot_general(
-                qg, kg, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32))    # [nrep, bs]
-        st = jnp.concatenate(st_groups, axis=0) if nkv > 1 \
-            else st_groups[0]                           # [nh, bs]
-        st = jnp.where(live, st, NEG_INF)
-        m = m_sc[:]
-        m_new = jnp.maximum(m, st.max(axis=-1, keepdims=True))
-        p = jnp.exp(st - m_new)
-        alpha = jnp.exp(m - m_new)
-        l_sc[:] = l_sc[:] * alpha + p.sum(axis=-1, keepdims=True)
-        o_groups = []
-        for g in range(nkv):
-            pg = p[g * nrep:(g + 1) * nrep, :]          # [nrep, bs]
-            vg = v_ref[:, g, :].astype(jnp.float32)     # [bs, hd]
-            o_groups.append(lax.dot_general(
-                pg, vg, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32))    # [nrep, hd]
-        o = jnp.concatenate(o_groups, axis=0) if nkv > 1 \
-            else o_groups[0]                            # [nh, hd]
-        acc_sc[:] = acc_sc[:] * alpha + o
-        m_sc[:] = m_new
-
-    @pl.when(j == nb - 1)
-    def _finish():
-        l_safe = jnp.maximum(l_sc[:], np.float32(1e-30))  # [nh, 1]
-        o_ref[:] = acc_sc[:] / l_safe
-        lse_ref[:] = m_sc[:] + jnp.log(l_safe)
-
-
-@i32_trace
-def _ragged_partials_call(q, kpool, vpool, tables, seq_lens, scale):
-    """One shard's pallas launch: like _ragged_call but returns
-    (o [S, nh, hd] f32 normalized-within-shard, lse [S, nh, 1] f32).
-    seq_lens here are SHARD-LOCAL positions (may be -1: empty shard;
-    the index map clamps so nothing out-of-range is ever fetched)."""
-    S, nh, hd = q.shape
-    nb_pool, bs, nkv, _ = kpool.shape
-    mb = tables.shape[1]
-    nrep = nh // nkv
-    tables = tables.astype(jnp.int32)
-    seq_lens = seq_lens.astype(jnp.int32)
-    bs_i = np.int32(bs)
-    zero_i = np.int32(0)
-
-    def kv_map(s, j, tabs, lens):
-        # clamp empty (-1) AND past-the-end positions into the
-        # sub-table: repeated indices skip the HBM re-fetch, and the
-        # pl.when gate skips the compute either way
-        return (tabs[s, jnp.minimum(
-            j, jnp.maximum(lens[s], zero_i) // bs_i)], 0, 0, 0)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(S, mb),
-        in_specs=[
-            pl.BlockSpec((None, nh, hd), lambda s, j, tabs, lens: (s, 0, 0)),
-            pl.BlockSpec((None, bs, nkv, hd), kv_map),
-            pl.BlockSpec((None, bs, nkv, hd), kv_map),
-        ],
-        out_specs=[
-            pl.BlockSpec((None, nh, hd),
-                         lambda s, j, tabs, lens: (s, 0, 0)),
-            pl.BlockSpec((None, nh, 1),
-                         lambda s, j, tabs, lens: (s, 0, 0)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((nh, 1), jnp.float32),
-            pltpu.VMEM((nh, 1), jnp.float32),
-            pltpu.VMEM((nh, hd), jnp.float32),
-        ],
-    )
-    kernel = functools.partial(_pkernel, bs=bs, nkv=nkv, nrep=nrep,
-                               scale=np.float32(scale))
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((S, nh, hd), jnp.float32),
-                   jax.ShapeDtypeStruct((S, nh, 1), jnp.float32)],
-        interpret=_interpret(),
-    )(tables, seq_lens, q, kpool, vpool)
-
+# merge is a tiny [S, nh] reduction on the combining chip). A shard with
+# no live tokens (shard-local seq_lens -1) copies and computes nothing
+# and lands at o = 0, lse ~ -inf, so its merge weight exp(lse - M)
+# underflows to exactly 0.
 
 def ragged_paged_attention_sharded(q, kpool, vpool, tables, seq_lens,
                                    num_shards, scale=None):
@@ -354,8 +401,8 @@ def ragged_paged_attention_sharded(q, kpool, vpool, tables, seq_lens,
         lens_k = jnp.clip(lens + np.int32(1) - np.int32(lo * bs),
                           np.int32(0),
                           np.int32((hi - lo) * bs)) - np.int32(1)
-        o_k, lse_k = _ragged_partials_call(q, kpool, vpool, sub, lens_k,
-                                           float(scale))
+        o_k, lse_k = _launch(q, kpool, vpool, sub, lens_k, float(scale),
+                             partials=True)
         outs.append(o_k)
         lses.append(lse_k[..., 0])        # [S, nh]
     lse = jnp.stack(lses, axis=0)         # [K, S, nh] f32
@@ -406,122 +453,21 @@ def kv_row_error_bound(x):
     return amax / 254.0
 
 
-def _qkernel(tabs_ref, lens_ref, q_ref, k_ref, ks_ref, v_ref, vs_ref,
-             o_ref, m_sc, l_sc, acc_sc, *, bs, nkv, nrep, scale):
-    """Quantized-pool grid step: identical online-softmax body to
-    _kernel, but k_ref/v_ref are int8 codes and ks_ref/vs_ref [bs] the
-    per-row f32 scales — dequantized here, in VMEM, after the fetch."""
-    s = pl.program_id(0)
-    j = pl.program_id(1)
-    nb = pl.num_programs(1)
-    pos = lens_ref[s]
-
-    @pl.when(j == 0)
-    def _init():
-        m_sc[:] = jnp.full_like(m_sc, NEG_INF)
-        l_sc[:] = jnp.zeros_like(l_sc)
-        acc_sc[:] = jnp.zeros_like(acc_sc)
-
-    @pl.when(j * bs <= pos)
-    def _step():
-        q = q_ref[:].astype(jnp.float32) * scale        # [nh, hd]
-        col = j * bs + lax.broadcasted_iota(jnp.int32, (1, bs), 1)
-        live = col <= pos                               # [1, bs]
-        ks = ks_ref[:].astype(jnp.float32)[:, None]     # [bs, 1]
-        vs = vs_ref[:].astype(jnp.float32)[:, None]
-        st_groups = []
-        for g in range(nkv):
-            qg = q[g * nrep:(g + 1) * nrep, :]          # [nrep, hd]
-            kg = k_ref[:, g, :].astype(jnp.float32) * ks  # dequant [bs, hd]
-            st_groups.append(lax.dot_general(
-                qg, kg, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32))    # [nrep, bs]
-        st = jnp.concatenate(st_groups, axis=0) if nkv > 1 \
-            else st_groups[0]                           # [nh, bs]
-        st = jnp.where(live, st, NEG_INF)
-        m = m_sc[:]
-        m_new = jnp.maximum(m, st.max(axis=-1, keepdims=True))
-        p = jnp.exp(st - m_new)
-        alpha = jnp.exp(m - m_new)
-        l_sc[:] = l_sc[:] * alpha + p.sum(axis=-1, keepdims=True)
-        o_groups = []
-        for g in range(nkv):
-            pg = p[g * nrep:(g + 1) * nrep, :]          # [nrep, bs]
-            vg = v_ref[:, g, :].astype(jnp.float32) * vs  # dequant [bs, hd]
-            o_groups.append(lax.dot_general(
-                pg, vg, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32))    # [nrep, hd]
-        o = jnp.concatenate(o_groups, axis=0) if nkv > 1 \
-            else o_groups[0]                            # [nh, hd]
-        acc_sc[:] = acc_sc[:] * alpha + o
-        m_sc[:] = m_new
-
-    @pl.when(j == nb - 1)
-    def _finish():
-        o_ref[:] = (acc_sc[:] / l_sc[:]).astype(o_ref.dtype)
-
-
-@i32_trace
-def _ragged_quant_call(q, kpool, kscale, vpool, vscale, tables, seq_lens,
-                       scale):
-    S, nh, hd = q.shape
-    nb_pool, bs, nkv, _ = kpool.shape
-    mb = tables.shape[1]
-    nrep = nh // nkv
-    tables = tables.astype(jnp.int32)
-    seq_lens = seq_lens.astype(jnp.int32)
-    bs_i = np.int32(bs)
-
-    def kv_map(s, j, tabs, lens):
-        # same past-the-end clamp as the unquantized kernel: repeated
-        # indices skip the re-fetch
-        return (tabs[s, jnp.minimum(j, lens[s] // bs_i)], 0, 0, 0)
-
-    def sc_map(s, j, tabs, lens):
-        return (tabs[s, jnp.minimum(j, lens[s] // bs_i)], 0)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(S, mb),
-        in_specs=[
-            pl.BlockSpec((None, nh, hd), lambda s, j, tabs, lens: (s, 0, 0)),
-            pl.BlockSpec((None, bs, nkv, hd), kv_map),
-            pl.BlockSpec((None, bs), sc_map),
-            pl.BlockSpec((None, bs, nkv, hd), kv_map),
-            pl.BlockSpec((None, bs), sc_map),
-        ],
-        out_specs=pl.BlockSpec((None, nh, hd),
-                               lambda s, j, tabs, lens: (s, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((nh, 1), jnp.float32),
-            pltpu.VMEM((nh, 1), jnp.float32),
-            pltpu.VMEM((nh, hd), jnp.float32),
-        ],
-    )
-    kernel = functools.partial(_qkernel, bs=bs, nkv=nkv, nrep=nrep,
-                               scale=np.float32(scale))
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((S, nh, hd), q.dtype),
-        interpret=_interpret(),
-    )(tables, seq_lens, q, kpool, kscale, vpool, vscale)
-
-
 def ragged_paged_attention_quant(q, kpool, kscale, vpool, vscale, tables,
                                  seq_lens, scale=None):
     """ragged_paged_attention over an int8 pool: kpool/vpool
     [num_blocks, block_size, nkv, hd] int8 codes, kscale/vscale
     [num_blocks, block_size] f32 per-row scales (kv_quantize_rows
-    layout). Dequantization happens inside the kernel after the
-    HBM -> VMEM fetch, so the wire moves codes + scales, never the
-    widened values. Same clamp/early-exit contract as the unquantized
-    kernel: blocks (and their scale rows) past seq_lens are never
-    fetched."""
+    layout). The codes reach the MXU as they are (exact in q's dtype)
+    and the row scales multiply the scores and the probabilities, so
+    the wire moves codes + scales, never the widened values. Same
+    early-exit contract as the unquantized kernel: no code block past
+    seq_lens is copied; the scale rows a table names are gathered
+    beforehand, and those of its dead entries never meet a product."""
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
-    return _ragged_quant_call(q, kpool, kscale, vpool, vscale, tables,
-                              seq_lens, float(scale))
+    return _launch(q, kpool, vpool, tables, seq_lens, float(scale),
+                   scales=(kscale, vscale))
 
 
 # op-registry faces (lazily registered at module import, the flash /
@@ -591,7 +537,13 @@ def record_ragged_step(seq_lens, blocks_per_seq, block_size, nkv, hd,
     read only the trash block. `launches` overrides the kernel-launch
     count when it differs from `steps`: a batched spec-decode verify is
     ONE launch per layer covering k+1 positions' worth of traffic —
-    bytes bill at steps=k+1, calls at launches=1."""
+    bytes bill at steps=k+1, calls at launches=1.
+
+    How much of the grid works: a launch makes one grid step a slot, and
+    a step waits once for each DMA group of its live blocks (the group
+    size `_blocks_per_step` gives this block). `grid_steps` counts the
+    former, `grid_steps_live` those that attended a block (a step a
+    slot leaves none dead), `dma_groups` the latter."""
     from ... import observability as obs
     if not obs.enabled():
         return
@@ -601,14 +553,20 @@ def record_ragged_step(seq_lens, blocks_per_seq, block_size, nkv, hd,
     alive = np.ones(lens.shape, bool) if live is None \
         else np.asarray(live, bool)
     attended = skipped = ragged_bytes = bf16eq_bytes = 0
+    grid_steps = grid_live = dma_groups = 0
     per_block = 2 * block_size * (nkv * hd * itemsize + scale_bytes)
     bf16_block = 2 * block_size * nkv * hd * 2
+    group, _ = _blocks_per_step(block_size * nkv * hd * itemsize,
+                                block_size * nkv, blocks_per_seq)
     for i in range(steps):
         adv = i if budgets is None else np.minimum(i, np.asarray(budgets))
         pos = lens + adv * alive
         needed = np.where(alive, pos // block_size + 1, 1)
         attended += int(needed.sum())
         skipped += int((blocks_per_seq - needed).sum())
+        grid_steps += len(needed)
+        grid_live += int((needed > 0).sum())
+        dma_groups += int((-(-needed // group)).sum())
         ragged_bytes += int(needed.sum()) * per_block
         bf16eq_bytes += int(needed.sum()) * bf16_block
     dense_bytes = steps * dense_gather_hbm_bytes(
@@ -623,6 +581,15 @@ def record_ragged_step(seq_lens, blocks_per_seq, block_size, nkv, hd,
     reg.counter("paddle_tpu_ragged_attn_blocks_skipped_total",
                 "KV pool blocks skipped by the ragged early-exit").inc(
                     layers * skipped)
+    reg.counter("paddle_tpu_ragged_attn_grid_steps_total",
+                "grid steps of the ragged kernel's launches").inc(
+                    layers * grid_steps)
+    reg.counter("paddle_tpu_ragged_attn_grid_steps_live_total",
+                "ragged kernel grid steps that attended a block").inc(
+                    layers * grid_live)
+    reg.counter("paddle_tpu_ragged_attn_dma_groups_total",
+                "groups of pool blocks the ragged kernel waited for").inc(
+                    layers * dma_groups)
     reg.counter("paddle_tpu_ragged_attn_hbm_bytes_total",
                 "attention KV bytes read by the ragged kernel").inc(
                     layers * ragged_bytes)

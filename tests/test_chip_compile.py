@@ -7,6 +7,8 @@ worker that is given this file, and nowhere at import time. A compile that
 passes is not a chip run; it says the chip's compiler accepts the kernel
 (tiling, fast memory) and that the kernel is in the program.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -95,18 +97,58 @@ def test_flash_attention_fwd_bwd(one_chip, for_the_chip, bh, seq, dtype):
     assert text.count("tpu_custom_call") >= 3       # fwd, dq, dk/dv
 
 
-@pytest.mark.parametrize("nkv", [32, 8, 2])
-def test_ragged_paged_attention(one_chip, for_the_chip, nkv):
-    """16 slots x 32 heads x 128 against a paged pool: MHA, GQA, and the
-    hybrid's 2 KV heads of 16 query heads each."""
+@pytest.mark.parametrize("slots,nkv,blocks", [
+    (16, 32, 257), (16, 8, 257), (16, 2, 257),     # MHA, GQA, 2 KV heads of 16
+    (32, 32, 8 * 513),      # deepseek_llm_7b_serve_backlog: 8 layers' pool
+    (128, 2, 4097),         # nemotron3_super_serve_backlog
+])
+def test_ragged_paged_attention(one_chip, for_the_chip, slots, nkv, blocks):
+    """32 query heads x 128 against a paged pool of 64-token blocks, 32
+    blocks a sequence: the pools stay in HBM and the body copies the live
+    blocks itself, so Mosaic has to take the DMAs, the merged (token, KV
+    head) rows and the buffers' share of VMEM."""
     from paddle_tpu.kernels.pallas.ragged_paged_attention import (
         ragged_paged_attention)
-    slots, blocks, block_size, blocks_per_seq = 16, 257, 64, 32
+    block_size, blocks_per_seq = 64, 32
     pool = ((blocks, block_size, nkv, 128), BF16)
     text = _compiled_text(
         ragged_paged_attention, one_chip, ((slots, 32, 128), BF16), pool,
         pool, ((slots, blocks_per_seq), jnp.int32), ((slots,), jnp.int32))
     assert "tpu_custom_call" in text
+    # the pools' (token, KV head) axes merged: the same bytes, no copy
+    merged = rf"bf16\[{blocks},{block_size * nkv},128\]\S* "
+    assert len(re.findall(merged + r"bitcast\(", text)) == 2
+    assert not re.search(merged + r"copy\(", text)
+
+
+@pytest.mark.parametrize("nkv", [32, 8])
+def test_ragged_paged_attention_quant(one_chip, for_the_chip, nkv):
+    """int8 pools with float32 row scales: the codes go to the MXU in q's
+    dtype, the scales ride the score columns as a `[1, columns]` block a
+    slot (Mosaic refuses a `[bs]` row of the `[blocks, bs]` scale pool)."""
+    from paddle_tpu.kernels.pallas.ragged_paged_attention import (
+        ragged_paged_attention_quant)
+    slots, blocks = 32, 513
+    pool = ((blocks, 64, nkv, 128), jnp.int8)
+    scales = ((blocks, 64), jnp.float32)
+    text = _compiled_text(
+        ragged_paged_attention_quant, one_chip, ((slots, 32, 128), BF16),
+        pool, scales, pool, scales, ((slots, 32), jnp.int32),
+        ((slots,), jnp.int32))
+    assert "tpu_custom_call" in text
+
+
+def test_ragged_paged_attention_sharded(one_chip, for_the_chip):
+    """Two shards of 16 blocks: two partials launches and the merge."""
+    from paddle_tpu.kernels.pallas.ragged_paged_attention import (
+        ragged_paged_attention_sharded)
+    slots, blocks = 32, 513
+    pool = ((blocks, 64, 32, 128), BF16)
+    text = _compiled_text(
+        lambda *a: ragged_paged_attention_sharded(*a, 2), one_chip,
+        ((slots, 32, 128), BF16), pool, pool, ((slots, 32), jnp.int32),
+        ((slots,), jnp.int32))
+    assert text.count("tpu_custom_call") >= 2
 
 
 def test_rope(one_chip, for_the_chip):
