@@ -14,6 +14,7 @@ Usage:
 """
 from __future__ import annotations
 
+import collections
 import json
 import logging
 import time
@@ -21,6 +22,7 @@ import warnings
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..framework.tensor import Tensor
 from ..framework import autograd, random as random_mod
@@ -115,6 +117,20 @@ class TrainStep:
         self._buffers = {k: b for k, b in model.named_buffers()
                          if isinstance(b, Tensor)}
         self._pname_of_id = {id(p): k for k, p in self._params.items()}
+        # device-side counters of a step: a model names the buffers its
+        # forward writes them to and what each entry counts
+        # (`step_counters = {buffer: (field, ...)}`). The step returns
+        # them once more beside the buffers it threads (those are donated
+        # to the next step); the host reads a step's counters at a later
+        # call, once they are ready, never by waiting
+        # (`_settled_counters`), into `last_counters` and onto
+        # `train_step:call`
+        self._counter_fields = {
+            k: tuple(v) for k, v in
+            (getattr(model, "step_counters", None) or {}).items()
+            if k in self._buffers}
+        self._counters_pending = collections.deque()
+        self.last_counters = None
         # compressed/bucketed gradient sync (fleet/grad_buckets.py):
         # either an explicit scheduler, or built here from the config a
         # fleet wrapper carried, against THIS step's param-name space.
@@ -321,7 +337,8 @@ class TrainStep:
                         v, self._param_out_shardings[k])
                         if k in self._param_out_shardings else v)
                     for k, v in new_params.items()}
-            return loss, new_params, new_buffers, new_accums, outs
+            counters = {k: new_buffers[k] for k in self._counter_fields}
+            return loss, new_params, new_buffers, new_accums, outs, counters
         finally:
             random_mod.pop_traced_key()
             for k, p in self._params.items():
@@ -532,9 +549,23 @@ class TrainStep:
     def __call__(self, inputs, labels=()):
         """One fused step: loss = loss_fn(model(*inputs), *labels).
         `inputs`/`labels` may be a single Tensor or a tuple/list of them."""
-        with _obs.span("train_step:call",
-                       step=int(self.opt._step_count)):
+        with _obs.span("train_step:call", step=int(self.opt._step_count),
+                       **self._settled_counters()):
             return self._call(inputs, labels)
+
+    def _settled_counters(self):
+        """The newest counters a finished step left (`last_counters`:
+        the fields of `model.step_counters` and `counters_step`, the step
+        that counted them), read from the device only once they are
+        there: a step still in flight keeps its own for a later call."""
+        pending = self._counters_pending
+        while pending and all(a.is_ready() for a in pending[0][1].values()):
+            step, arrays = pending.popleft()
+            self.last_counters = {"counters_step": step}
+            for name, fields in self._counter_fields.items():
+                self.last_counters.update(
+                    zip(fields, np.asarray(arrays[name]).tolist()))
+        return self.last_counters or {}
 
     def _call(self, inputs, labels):
         if isinstance(inputs, Tensor):
@@ -568,8 +599,11 @@ class TrainStep:
                 self._jitted, args)
             compiled1 = _obs.tracing.compile_seconds()
             t0_ns = time.perf_counter_ns()
-        loss, new_params, new_buffers, new_accums, outs = \
+        loss, new_params, new_buffers, new_accums, outs, counters = \
             self._jitted(*args)
+        if counters:
+            self._counters_pending.append(
+                (int(self.opt._step_count), counters))
         if telemetry:
             # the sync that makes `execute` the step's device time
             jax.block_until_ready(loss)

@@ -473,7 +473,7 @@ def grouped_matmul(x, w, b=None, *, group_offsets, group_counts,
     return fn(x, w, b, group_offsets, group_counts)
 
 
-# -- forward-only, rows sorted by group with no padding (serving) ------------
+# -- rows sorted by group with no padding (serving, and training since PR 35) ---
 
 def _sorted_tile(n, cap):
     """Largest multiple of 128 that divides n and is <= cap; n itself
@@ -496,51 +496,111 @@ def _sorted_reference(x, w, group_sizes):
     return jnp.where(inside[:, None], out, 0.0)
 
 
-def grouped_matmul_sorted(x, w, group_sizes, *, impl="auto"):
+def _megablox(name):
+    """JAX's grouped kernel `name` ("gmm", "tgmm") as a plain function.
+    The package's attribute of that name is its custom_vjp or jit
+    wrapper; the module `gmm` holds the kernels themselves, and the
+    plain function under JAX's own jit wrapper lets the kernel's
+    instruction carry the caller's `jax.named_scope`."""
+    import importlib
+    fn = getattr(importlib.import_module(
+        "jax.experimental.pallas.ops.tpu.megablox.gmm"), name)
+    return getattr(fn, "__wrapped__", fn)
+
+
+def _megablox_call(name, lhs, rhs, group_sizes, out_dtype, tiling, **kw):
+    """Traced with x64 off: the kernel's tile counts and index maps must
+    stay 32-bit (`_x64.i32_trace`). And at the default matmul precision:
+    the package sets "highest" for the whole process, which Mosaic
+    refuses for a kernel's bf16 operands ("Bad lhs type")."""
+    with jax.default_matmul_precision("default"):
+        return i32_trace(_megablox(name))(
+            lhs, rhs, group_sizes.astype(jnp.int32),
+            preferred_element_type=out_dtype, tiling=tiling,
+            interpret=_interpret(), **kw)
+
+
+def _pad_rows(x, tm):
+    pad = (-x.shape[0]) % tm
+    return jnp.pad(x, ((0, pad), (0, 0))) if pad else x
+
+
+def _sorted_kernel_fwd(x, w, group_sizes, tm, out_dtype):
+    m, k = x.shape
+    n = w.shape[2]
+    out = _megablox_call(
+        "gmm", _pad_rows(x, tm), w, group_sizes, out_dtype,
+        (tm, _sorted_tile(k, 1024), _sorted_tile(n, 1024)))
+    return out if out.shape[0] == m else out[:m]
+
+
+def _sorted_kernel_bwd(tm, res, dy):
+    """dx = dy @ w[group]^T through the same grouped kernel with the
+    weights read transposed; dw[e] = x[rows of e]^T @ dy[rows of e]
+    through the kernel that accumulates over a group's row tiles
+    (`tgmm`: an empty group's block is written as zeros). Rows past the
+    groups' sum are visited by neither: their dx is selected to zero."""
+    x, w, group_sizes = res
+    m, k = x.shape
+    n = w.shape[2]
+    dy = _pad_rows(dy.astype(x.dtype), tm)
+    dx = _megablox_call(
+        "gmm", dy, w, group_sizes, x.dtype,
+        (tm, _sorted_tile(n, 1024), _sorted_tile(k, 1024)),
+        transpose_rhs=True)[:m]
+    inside = jnp.arange(m, dtype=jnp.int32) < jnp.sum(
+        group_sizes, dtype=jnp.int32)
+    dx = jnp.where(inside[:, None], dx, jnp.zeros((), dx.dtype))
+    dw = _megablox_call(
+        "tgmm", _pad_rows(x, tm).swapaxes(0, 1), dy, group_sizes, w.dtype,
+        (tm, _sorted_tile(k, 1024), _sorted_tile(n, 1024)))
+    return dx, dw, None
+
+
+@functools.lru_cache(maxsize=None)
+def _sorted_kernel(tm, out_dtype):
+    fn = jax.custom_vjp(functools.partial(
+        _sorted_kernel_fwd, tm=tm, out_dtype=out_dtype))
+    fn.defvjp(lambda x, w, sizes: (
+        _sorted_kernel_fwd(x, w, sizes, tm, out_dtype), (x, w, sizes)),
+        functools.partial(_sorted_kernel_bwd, tm))
+    return fn
+
+
+def grouped_matmul_sorted(x, w, group_sizes, *, impl="auto", row_tile=128,
+                          out_dtype=jnp.float32):
     """Per-group matmul over rows SORTED by group with no padding
     between groups: rows `sum(sizes[:e]) .. sum(sizes[:e+1])` of x [M, K]
     meet w[e] of w [E, K, N]; `group_sizes` [E] int32 may sum to less
     than M, and rows past that sum are not computed (their output is
-    unspecified: select them away, never scale them). Returns [M, N]
-    float32. Forward only: the serving path of an expert layer, where a
-    step brings a handful of rows to each of many experts and the
+    unspecified: select them away, never scale them). Returns [M, N] in
+    `out_dtype` (float32 accumulated whatever it is). The layout of an
+    expert layer that brings each expert exactly its rows, where the
     tile-aligned layout of `grouped_matmul` (a grid over every expert's
     worst-case tiles) would spend the step skipping.
 
-    impl "auto": on a TPU the grouped kernel that ships with JAX
-    (`jax.experimental.pallas.ops.tpu.megablox.gmm`: a grid over the
+    Differentiable in x and w: dx through the same kernel against the
+    transposed weights, dw through the kernel that accumulates over a
+    group's rows. Rows past the groups' sum get zero cotangent into dx
+    and take no part in dw; an expert without a row gets a zero dw. The
+    caller selects the unwritten rows of the OUTPUT away before anything
+    multiplies them: a backward pass would otherwise multiply their zero
+    cotangent by whatever the memory held (0 x NaN).
+
+    impl "auto": on a TPU the grouped kernels that ship with JAX
+    (`jax.experimental.pallas.ops.tpu.megablox`: `gmm`, a grid over the
     row tiles that groups touch, the group's weights found through
     scalar-prefetched ids, an expert's weights streamed once per
-    visit), elsewhere a gathered-weight XLA product. "kernel" forces
-    the kernel (interpreted off the TPU), "reference" the XLA product.
+    visit; `tgmm` for dw), elsewhere a gathered-weight XLA product.
+    "kernel" forces the kernels (interpreted off the TPU), "reference"
+    the XLA product. `row_tile`: rows of a kernel tile; 128 suits a
+    serving step's handful of rows an expert, a training step's
+    thousands want more rows against each weight tile it streams.
     """
     if impl == "reference" or (impl == "auto" and _interpret()):
-        return _sorted_reference(x, w, group_sizes)
-    import importlib
-    # the package's `gmm` attribute is its custom_vjp wrapper; the module
-    # of the same name holds the forward kernel itself
-    _gmm = importlib.import_module(
-        "jax.experimental.pallas.ops.tpu.megablox.gmm")
-    m, k = x.shape
-    n = w.shape[2]
-    tm = 128
-    pad = (-m) % tm
-    if pad:
-        x = jnp.pad(x, ((0, pad), (0, 0)))
-    # traced with x64 off: the kernel's tile counts and index maps must
-    # stay 32-bit (`_x64.i32_trace`). And at the default matmul
-    # precision: the package sets "highest" for the whole process, which
-    # Mosaic refuses for a kernel's bf16 operands ("Bad lhs type")
-    # (the plain function under JAX's own jit wrapper, so that the
-    # kernel's instruction carries the caller's `jax.named_scope`)
-    gmm = getattr(_gmm.gmm, "__wrapped__", _gmm.gmm)
-    with jax.default_matmul_precision("default"):
-        out = i32_trace(gmm)(
-            x, w, group_sizes.astype(jnp.int32),
-            preferred_element_type=jnp.float32,
-            tiling=(tm, _sorted_tile(k, 1024), _sorted_tile(n, 1024)),
-            interpret=_interpret())
-    return out[:m] if pad else out
+        return _sorted_reference(x, w, group_sizes).astype(out_dtype)
+    return _sorted_kernel(int(row_tile), jnp.dtype(out_dtype))(
+        x, w, group_sizes)
 
 
 # -- host-side telemetry -----------------------------------------------------

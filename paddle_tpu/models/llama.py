@@ -414,9 +414,11 @@ class LlamaModel(_PipelineStateDictMixin, Layer):
                 self.decoder_stack = LlamaStackedDecoder(config)
         elif getattr(config, "num_experts", 0):
             raise ValueError(
-                "num_experts > 0 requires pipeline_parallel=True (the "
-                "MoE family ships as the stacked pipelined decoder; "
-                "use incubate MoELayer for the non-pipelined path)")
+                "num_experts > 0 requires pipeline_parallel=True (this "
+                "family's experts ship as the stacked pipelined decoder; "
+                "the sorted, dropless expert layer that trains without a "
+                "pipeline is models/lfm2.py's `sparse_moe` over "
+                "`grouped_matmul_sorted`)")
         else:
             from ..nn.layer.container import LayerList
             self.layers = LayerList(

@@ -378,8 +378,37 @@ def moe_route(cfg, p, u):
                            cfg.num_experts_per_tok)
     picked = jnp.take_along_axis(s, idx, axis=1)
     if cfg.norm_topk_prob:
-        picked = picked / (jnp.sum(picked, -1, keepdims=True) + 1e-20)
+        picked = picked / (jnp.sum(picked, -1, keepdims=True)
+                           + getattr(cfg, "norm_topk_eps", 1e-20))
     return idx.astype(jnp.int32), picked * cfg.routed_scaling_factor
+
+
+def sort_pairs(cfg, idx, active=None):
+    """The token-expert pairs idx [T, k] sorted by held expert: (order
+    [T*k] int32, the pair at each sorted place; sizes [count] int32, the
+    pairs each held expert got; rows [T] bool). Pairs of experts held
+    elsewhere, and of rows that are not `active`, go last."""
+    first, count = cfg.experts_held
+    t = idx.shape[0]
+    local = idx - first
+    here = (local >= 0) & (local < count)
+    rows = jnp.ones((t,), bool) if active is None else active
+    here = here & rows[:, None]
+    key = jnp.where(here, local, count).reshape(-1)          # [T*k]
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    sizes = jnp.zeros((count + 1,), jnp.int32).at[key].add(1)[:count]
+    return order, sizes, rows
+
+
+def pair_counts(n_here, sizes, rows, k):
+    """int32 [4] of one expert layer's call: pairs computed here
+    (`n_here`, the sum of `sizes`), pairs routed anywhere, held experts
+    that got a row, the most rows one expert got; `merge_counts` adds
+    them up over calls."""
+    return jnp.stack([n_here,
+                      jnp.sum(rows, dtype=jnp.int32) * jnp.int32(k),
+                      jnp.sum(sizes > 0, dtype=jnp.int32),
+                      jnp.max(sizes)])
 
 
 def moe_experts(cfg, p, v, idx, weights, active=None):
@@ -393,15 +422,8 @@ def moe_experts(cfg, p, v, idx, weights, active=None):
     that got a row, the most rows one expert got; `merge_counts` adds
     them up)."""
     from ..kernels.pallas.grouped_matmul import grouped_matmul_sorted
-    first, count = cfg.experts_held
     t, k = idx.shape
-    local = idx - first
-    here = (local >= 0) & (local < count)
-    rows = jnp.ones((t,), bool) if active is None else active
-    here = here & rows[:, None]
-    key = jnp.where(here, local, count).reshape(-1)          # [T*k]
-    order = jnp.argsort(key, stable=True).astype(jnp.int32)
-    sizes = jnp.zeros((count + 1,), jnp.int32).at[key].add(1)[:count]
+    order, sizes, rows = sort_pairs(cfg, idx, active)
     with jax.named_scope("moe.experts"):
         xs = jnp.take(v, order // k, axis=0)
         h = grouped_matmul_sorted(xs, p["w1"], sizes)
@@ -414,11 +436,7 @@ def moe_experts(cfg, p, v, idx, weights, active=None):
     back = jnp.zeros((t * k,), jnp.int32).at[order].set(
         jnp.arange(t * k, dtype=jnp.int32))
     r = jnp.sum(jnp.take(wy, back, axis=0).reshape(t, k, -1), axis=1)
-    counts = jnp.stack([n_here,
-                        jnp.sum(rows, dtype=jnp.int32) * jnp.int32(k),
-                        jnp.sum(sizes > 0, dtype=jnp.int32),
-                        jnp.max(sizes)])
-    return r, counts
+    return r, pair_counts(n_here, sizes, rows, k)
 
 
 NO_COUNTS = np.zeros(4, np.int32)

@@ -254,3 +254,41 @@ def test_hybrid_prefill_bucket(hybrid, for_the_chip):
         *pools, described((), jnp.int32)).compile()
     text = _kernels_and_spare(compiled, dec)
     assert "%moe.experts" in text
+
+
+# -- the sparse-expert train step (PR 35): head size 64, the grouped backward ----
+
+def test_flash_attention_head64_fwd_bwd(one_chip, for_the_chip):
+    """32 query heads of 64 over one 8192-token sequence (K and V already
+    repeated to the query heads): half a lane tile a head, forward, dQ
+    and dK/dV, resident kernels (8192 x 64 is under the resident limit)."""
+    from paddle_tpu.kernels.pallas.flash_attention import _flash_bhsd
+
+    def fwd_bwd(q, k, v):
+        return jax.grad(lambda q, k, v: _flash_bhsd(
+            q, k, v, True, 64 ** -0.5).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2))(q, k, v)
+
+    qkv = ((32, 8192, 64), BF16)
+    text = _compiled_text(fwd_bwd, one_chip, qkv, qkv, qkv)
+    assert text.count("tpu_custom_call") >= 3       # fwd, dq, dk/dv
+
+
+@pytest.mark.parametrize("rows", [8192, 12288])
+@pytest.mark.parametrize("k,n", [(2048, 1792), (1792, 2048)])
+def test_grouped_matmul_sorted_fwd_bwd(one_chip, for_the_chip, rows, k, n):
+    """One chip's 8 of 32 experts of width 1792 under hidden 2048, the
+    sorted buffer of a step of 8192 tokens x top-4 (a quarter lands
+    here: 8192 rows; the static bound: 12288), 512 rows a tile: the
+    forward, dx (`gmm` with the weights read transposed) and dw (`tgmm`)
+    for gate / up and for down."""
+    from paddle_tpu.kernels.pallas.grouped_matmul import grouped_matmul_sorted
+
+    def fwd_bwd(x, w, sizes):
+        return jax.value_and_grad(lambda x, w: grouped_matmul_sorted(
+            x, w, sizes, row_tile=512, out_dtype=BF16).astype(
+                jnp.float32).sum(), argnums=(0, 1))(x, w)
+
+    text = _compiled_text(fwd_bwd, one_chip, ((rows, k), BF16),
+                          ((8, k, n), BF16), ((8,), jnp.int32))
+    assert text.count("tpu_custom_call") >= 3       # forward, dx, dw
