@@ -204,21 +204,49 @@ def step_sizes(p, dt):
             -jnp.exp(p["A_log"].astype(F32)))
 
 
-def conv_sequence(xbc, w, b, true_len):
-    """Causal depthwise conv over one sequence xbc [T, C] (zeros before
-    the start) and silu. Also the conv state a decode step continues
-    from: the last K-1 rows before `true_len` (zeros where the sequence
-    is shorter), so a bucket padded behind hands over the state of
-    `true_len`, not of its padding."""
+def one_segment(t):
+    """(starts, lens) of a token axis that holds one whole sequence."""
+    return jnp.zeros((1,), jnp.int32), jnp.full((1,), t, jnp.int32)
+
+
+def segment_rows(starts, lens, t):
+    """A token axis of `t` rows holds a pack of sequences laid one after
+    another: segment k is rows starts[k] .. starts[k] + lens[k]; starts
+    ascend from 0, an unused segment starts at `t` with length 0, and
+    the rows between a segment's end and the next start are padding.
+    Returns (the segment of each row [t], that segment's start [t],
+    whether the row is real [t]). One whole sequence is `one_segment`."""
+    pos = jnp.arange(t, dtype=jnp.int32)
+    seg = jnp.maximum(
+        jnp.sum(pos[:, None] >= starts[None, :], axis=1, dtype=jnp.int32)
+        - 1, 0)
+    first = jnp.take(starts, seg)
+    return seg, first, (pos >= first) & (pos < first + jnp.take(lens, seg))
+
+
+def conv_sequence(xbc, w, b, starts, lens):
+    """Causal depthwise conv over the segments of xbc [T, C] (zeros
+    before a segment's start: no tap crosses it) and silu. Also the conv
+    state a decode step continues from, for each segment: the last K-1
+    rows before its end (zeros where it is shorter) [segments, K-1, C],
+    so rows padded behind hand over the state of the segment's length,
+    not of its padding."""
     k = w.shape[1]
     t = xbc.shape[0]
+    first = segment_rows(starts, lens, t)[1]
+    pos = jnp.arange(t, dtype=jnp.int32)
     padded = jnp.concatenate(
         [jnp.zeros((k - 1, xbc.shape[1]), xbc.dtype), xbc])
     wf = w.astype(F32)
     out = b.astype(F32)[None, :]
     for j in range(k):
-        out = out + padded[j:j + t].astype(F32) * wf[:, j][None, :]
-    state = jax.lax.dynamic_slice_in_dim(padded, true_len, k - 1, axis=0)
+        inside = (pos - (k - 1 - j) >= first)[:, None]
+        out = out + jnp.where(inside, padded[j:j + t], 0).astype(F32) \
+            * wf[:, j][None, :]
+    at = (starts + lens)[:, None] - (k - 1) \
+        + jnp.arange(k - 1, dtype=jnp.int32)[None, :]       # [segments, K-1]
+    state = jnp.where((at >= starts[:, None])[..., None],
+                      jnp.take(xbc, jnp.clip(at, 0, t - 1), axis=0), 0)
     return jax.nn.silu(out).astype(xbc.dtype), state
 
 
@@ -249,15 +277,27 @@ def ssm_step(state, x, b, c, dt, a, d):
     return y.reshape(s, heads, hd), new.reshape(s, heads, hd, n)
 
 
-def ssd_chunked(x, b, c, dt, a, d, chunk, state0=None):
-    """The same sum as `ssm_step` over one whole sequence, in chunks
-    (the SSD form): inside a chunk a masked, decay-weighted product of
-    `C B^T` with x; between chunks the state carried by a short scan.
-    x [T, heads, hd]; b, c [T, G, N]; dt [T, heads] float32 after
-    softplus, 0 at padded positions (a position with dt = 0 neither
-    decays nor feeds the state); a, d [heads]. T is padded up to a
-    multiple of `chunk` here. Returns (y [T, heads, hd] float32, the
-    state after the last position [heads, hd, N] float32)."""
+def last_chunk(starts, lens, chunk, t):
+    """The chunk of `ssd_chunked` that holds each segment's last
+    position [segments]: the scan's state after it is the segment's (the
+    chunks padded behind it would carry it on unchanged)."""
+    q = min(int(chunk), t)
+    return jnp.clip((starts + jnp.maximum(lens, 1) - 1) // q, 0,
+                    -(-t // q) - 1)
+
+
+def ssd_chunked(x, b, c, dt, a, d, chunk, starts):
+    """The same sum as `ssm_step` over the segments of a token axis
+    (`segment_rows`), in chunks (the SSD form): inside a chunk a masked,
+    decay-weighted product of `C B^T` with x; between chunks the state
+    carried by a short scan, which starts from zero at a segment's first
+    chunk: every start is a multiple of `chunk`, so no chunk holds rows
+    of two segments. x [T, heads, hd]; b, c [T, G, N]; dt [T, heads]
+    float32 after softplus, 0 at padded positions (a position with
+    dt = 0 neither decays nor feeds the state); a, d [heads]. T is
+    padded up to a multiple of `chunk` here. Returns (y [T, heads, hd]
+    float32, the state after each chunk [chunks, heads, hd, N] float32:
+    a segment's own is the one after its `last_chunk`)."""
     t, heads, hd = x.shape
     g, n = b.shape[1], b.shape[2]
     r = heads // g
@@ -284,19 +324,22 @@ def ssd_chunked(x, b, c, dt, a, d, chunk, state0=None):
     to_end = jnp.exp(cum[:, -1:] - cum) * dtc               # [nc,q,g,r]
     own = jnp.einsum("csgr,csgrp,csgn->cgrpn", to_end, xf, bf)
     total = jnp.exp(cum[:, -1])                             # [nc,g,r]
-    if state0 is None:
-        state0 = jnp.zeros((heads, hd, n), F32)
+    opens = jnp.any((jnp.arange(nc, dtype=jnp.int32) * q)[:, None]
+                    == starts[None, :], axis=1)             # [nc]
 
     def carry(state, xs):
-        own_c, total_c = xs
-        return state * total_c[..., None, None] + own_c, state
-    last, before = jax.lax.scan(carry, state0.reshape(g, r, hd, n),
-                                (own, total))
+        own_c, total_c, opens_c = xs
+        state = jnp.where(opens_c, 0.0, state)
+        after = state * total_c[..., None, None] + own_c
+        return after, (state, after)
+    _, (before, after) = jax.lax.scan(
+        carry, jnp.zeros((g, r, hd, n), F32), (own, total, opens))
     # what the state before each chunk adds: exp(cum_t) C_t S_before
     y = y + jnp.einsum("ctgn,cgrpn->ctgrp", cf, before) \
         * jnp.exp(cum)[..., None]
     y = y + d.astype(F32).reshape(1, 1, g, r, 1) * xf
-    return (y.reshape(nc * q, heads, hd)[:t], last.reshape(heads, hd, n))
+    return (y.reshape(nc * q, heads, hd)[:t],
+            after.reshape(nc, heads, hd, n))
 
 
 def gated_group_norm(y, z, w, groups, eps):
@@ -310,18 +353,21 @@ def gated_group_norm(y, z, w, groups, eps):
     return gated.reshape(t, width) * w.astype(F32)
 
 
-def mamba_sequence(cfg, p, u, true_len):
-    """The Mamba-2 mixer over one sequence u [T, H] of which `true_len`
-    positions are real. Returns (out [T, H], SSM state at `true_len`,
-    conv state at `true_len`)."""
+def mamba_sequence(cfg, p, u, starts, lens):
+    """The Mamba-2 mixer over the segments of u [T, H] (`segment_rows`).
+    Returns (out [T, H], the SSM state after each chunk [chunks, heads,
+    hd, N] (a segment's: `last_chunk`), the conv state at each segment's
+    end [segments, K-1, C])."""
     z, xbc, dt = mamba_project(cfg, p, u)
-    xbc, conv_state = conv_sequence(xbc, p["conv_w"], p["conv_b"], true_len)
+    xbc, conv_state = conv_sequence(xbc, p["conv_w"], p["conv_b"], starts,
+                                    lens)
     x, b, c = mamba_split(cfg, xbc)
     dt, a = step_sizes(p, dt)
-    valid = jnp.arange(u.shape[0], dtype=jnp.int32) < true_len
+    valid = segment_rows(starts, lens, u.shape[0])[2]
     dt = jnp.where(valid[:, None], dt, 0.0)
     with jax.named_scope("prefill.ssm_scan"):
-        y, state = ssd_chunked(x, b, c, dt, a, p["D"], cfg.chunk_size)
+        y, state = ssd_chunked(x, b, c, dt, a, p["D"], cfg.chunk_size,
+                               starts)
     y = gated_group_norm(y.reshape(u.shape[0], -1), z, p["gnorm"],
                          cfg.n_groups, cfg.layer_norm_epsilon)
     return y.astype(u.dtype) @ p["out_proj"].astype(u.dtype), state, \
@@ -347,9 +393,10 @@ def mamba_decode(cfg, p, u, state, conv_state, active=None):
     return y.astype(u.dtype) @ p["out_proj"].astype(u.dtype), new, conv_new
 
 
-def attention_sequence(cfg, p, u):
-    """Causal GQA over one sequence u [T, H] with no positional term.
-    Returns (out [T, H], k [T, nkv, hd], v [T, nkv, hd])."""
+def attention_sequence(cfg, p, u, starts, lens):
+    """Causal GQA inside each segment of u [T, H] (`segment_rows`), with
+    no positional term. Returns (out [T, H], k [T, nkv, hd], v [T, nkv,
+    hd])."""
     t = u.shape[0]
     nh, nkv, hd = (cfg.num_attention_heads, cfg.num_key_value_heads,
                    cfg.head_dim)
@@ -357,9 +404,11 @@ def attention_sequence(cfg, p, u):
     k = (u @ p["wk"].astype(u.dtype)).reshape(t, nkv, hd)
     v = (u @ p["wv"].astype(u.dtype)).reshape(t, nkv, hd)
     pos = jnp.arange(t, dtype=jnp.int32)
+    seg = segment_rows(starts, lens, t)[0]
+    sees = (pos[None, :] <= pos[:, None]) & (seg[None, :] == seg[:, None])
     att = jnp.einsum("qgnd,kgd->gnqk", q.astype(F32), k.astype(F32)) \
         / math.sqrt(hd)
-    att = jnp.where((pos[None, :] <= pos[:, None])[None, None], att, -1e30)
+    att = jnp.where(sees[None, None], att, -1e30)
     o = jnp.einsum("gnqk,kgd->qgnd", jax.nn.softmax(att, axis=-1),
                    v.astype(F32)).astype(u.dtype)
     return o.reshape(t, nh * hd) @ p["wo"].astype(u.dtype), k, v
@@ -464,14 +513,14 @@ def forward_sequence(cfg, params, ids):
     """Full causal forward over one sequence ids [T]: logits [T, V]
     float32."""
     x = jnp.take(params["embed"], ids, axis=0)
-    t = ids.shape[0]
+    whole = one_segment(ids.shape[0])
     for i, kind in enumerate(cfg.hybrid_override_pattern):
         p = params["layers"][i]
         u = _rms(x, p["norm"], cfg.layer_norm_epsilon)
         if kind == "M":
-            x = x + mamba_sequence(cfg, p, u, t)[0]
+            x = x + mamba_sequence(cfg, p, u, *whole)[0]
         elif kind == "*":
-            x = x + attention_sequence(cfg, p, u)[0]
+            x = x + attention_sequence(cfg, p, u, *whole)[0]
         else:
             x = x + latent_moe(cfg, p, u)[0]
     x = _rms(x, params["norm"], cfg.layer_norm_epsilon)
@@ -637,7 +686,7 @@ class HybridPagedDecoder(PagedDecoder):
         self.weight_stream_bytes = {"quant": int(body), "bf16eq": int(body)}
 
     # -- the cache ----------------------------------------------------------------
-    _prefill_donate = (4, 5, 6, 7)
+    _prefill_donate = (5, 6, 7, 8)
 
     def new_pools(self):
         cfg = self.cfg
@@ -658,9 +707,6 @@ class HybridPagedDecoder(PagedDecoder):
         return self.state_layers * (
             cfg.mamba_inner * cfg.ssm_state_size * 4
             + (cfg.conv_kernel - 1) * cfg.conv_dim * itemsize)
-
-    def _prefill_extra(self, slot):
-        return (jnp.int32(slot),)
 
     def _refuse(self, what, why):
         raise NotImplementedError(
@@ -779,37 +825,119 @@ class HybridPagedDecoder(PagedDecoder):
         host's side of the token read."""
         return dict(zip(self.COUNTERS, (int(v) for v in np.asarray(aux[0]))))
 
-    def _prefill_paged(self, params, ids, true_len, table, kpool, vpool,
-                       ssm, conv, slot):
-        """Prefill one bucket-padded prompt: K and V of the attention
-        blocks into the slot's pages, the state of every Mamba block at
-        `true_len` (padded positions contribute nothing) into the
-        slot's rows of `ssm` and `conv`. Returns int32 [5] (the encoded
-        first token, then the prompt's MoE counts as `moe_experts` gives
-        them, merged over the expert blocks) and the pools."""
+    # -- the packed prefill ------------------------------------------------------------
+    def prefill_bucket(self, n):
+        """As `PagedDecoder`'s, for `n` rows brought up to whole chunks:
+        a pack's segments start on chunk boundaries."""
+        q = self.cfg.chunk_size
+        return super().prefill_bucket(min(-(-n // q) * q, self.max_len))
+
+    def prefill_buckets(self):
+        """Every row count a prefill program can be asked for."""
+        return sorted({self.prefill_bucket(n) for n in range(
+            1, self.max_len + 1, self.cfg.chunk_size)})
+
+    def prefill_packs(self, lengths):
+        """How the prompts a scan staged (their lengths, in the order of
+        admission) go into prefill programs: [(bucket, [(index, start
+        row)])]. Each prompt starts on a chunk boundary behind the one
+        before it in its pack; a pack holds `max_len` rows at most and
+        runs in the smallest bucket that holds it. A padded row costs
+        what a real one costs, so the packs are filled first-fit with
+        the longest prompt first, not in the order of admission. A
+        prompt admitted alone is a pack of one from row 0."""
+        q = self.cfg.chunk_size
+        packs = []                      # [rows taken, members]
+        for j in sorted(range(len(lengths)), key=lambda j: -lengths[j]):
+            pack = next((p for p in packs
+                         if p[0] + lengths[j] <= self.max_len), None)
+            if pack is None:
+                pack = [0, []]
+                packs.append(pack)
+            pack[1].append((j, pack[0]))
+            pack[0] = -(-(pack[0] + lengths[j]) // q) * q
+        return [(self.prefill_bucket(rows), members)
+                for rows, members in packs]
+
+    def _prefill_inputs(self, bucket, members, tables, pad):
+        """What `_prefill_paged` takes before and after the pools for a
+        pack `members` [(slot, prompt ids, start row)]. The program has
+        a segment a chunk of the bucket; the unused ones, behind those
+        in use, start at the bucket's end with length 0."""
+        k = -(-bucket // self.cfg.chunk_size)
+        ids = np.full(bucket, pad, np.int32)
+        starts = np.full(k, bucket, np.int32)
+        lens = np.zeros(k, np.int32)
+        rows = np.zeros((k, self.blocks_per_seq), np.int32)
+        slots = np.zeros(k, np.int32)
+        for j, (slot, prompt, start) in enumerate(members):
+            ids[start:start + len(prompt)] = prompt
+            starts[j], lens[j], slots[j] = start, len(prompt), slot
+            rows[j] = tables[slot]
+        return (jnp.asarray(ids), jnp.asarray(starts), jnp.asarray(lens),
+                jnp.asarray(rows)), (jnp.asarray(slots),)
+
+    def warm_prefill(self, pools, pad):
+        """Run every bucket's program that has not run yet on an empty
+        pack, which writes the trash block and nothing else: a scan's
+        prompts come out in whatever buckets their lengths add up to, so
+        none may wait for its first pack to be compiled. Returns the
+        pools."""
+        for bucket in self.prefill_buckets():
+            if bucket not in self._prefill_cache:
+                head, tail = self._prefill_inputs(bucket, [], (), pad)
+                _, *pools = self._prefill_exec(bucket)(
+                    self._params, *head, *pools, *tail)
+        return tuple(pools)
+
+    def _prefill_paged(self, params, ids, starts, lens, tables, kpool,
+                       vpool, ssm, conv, slots):
+        """Prefill a pack of prompts in one pass over the weights: ids
+        [rows] holds them one after another, segment k in rows
+        starts[k] .. starts[k] + lens[k] from a chunk boundary
+        (`segment_rows`), with its block table tables[k] and its slot
+        slots[k]. Whatever reads weights by the row (projections, the
+        router, the experts, the head) runs once over all rows; the
+        convolution, the scan and attention keep to a row's segment. K
+        and V of the attention blocks go into each segment's pages, the
+        state of every Mamba block at a segment's end (padded rows
+        contribute nothing) into its slot's rows of `ssm` and `conv`.
+        Returns int32 [segments + 4] (each segment's encoded first
+        token, then the pack's MoE counts as `moe_experts` gives them,
+        merged over the expert blocks) and the pools."""
         cfg, bs = self.cfg, self.block_size
-        S0 = ids.shape[0]
+        rows = ids.shape[0]
         x = jnp.take(params["embed"], ids, axis=0)
-        pos = jnp.arange(S0, dtype=jnp.int32)
-        valid = pos < true_len
-        blk = jnp.where(valid, jnp.take(table, pos // bs), 0)
-        widx = blk * bs + pos % bs
+        seg, first, valid = segment_rows(starts, lens, rows)
+        at = jnp.arange(rows, dtype=jnp.int32) - first
+        blk = jnp.where(valid, jnp.take(
+            tables.reshape(-1), seg * tables.shape[1] + at // bs), 0)
+        widx = blk * bs + at % bs
         kflat, vflat, NB, _ = self._flat_pools(kpool, vpool)
+        # the segments in use come first; only their states are written
+        used = jnp.sum(lens > 0, dtype=jnp.int32)
+        ends = last_chunk(starts, lens, cfg.chunk_size, rows)
         m = a = 0
         counts = jnp.asarray(NO_COUNTS)
         for i, kind in enumerate(cfg.hybrid_override_pattern):
             p = params["layers"][i]
             u = _rms(x, p["norm"], self.eps)
             if kind == "M":
-                out, state, cstate = mamba_sequence(cfg, p, u, true_len)
-                at = (jnp.int32(m), slot) + (jnp.int32(0),) * 3
-                ssm = jax.lax.dynamic_update_slice(
-                    ssm, state[None, None], at)
-                conv = jax.lax.dynamic_update_slice(
-                    conv, cstate[None, None].astype(conv.dtype), at[:4])
+                out, after, cstate = mamba_sequence(cfg, p, u, starts, lens)
+
+                def put(k, pools):
+                    # segment k's states into its slot's rows, in place
+                    pick = jax.lax.dynamic_index_in_dim
+                    at = (jnp.int32(m), slots[k]) + (jnp.int32(0),) * 3
+                    return (jax.lax.dynamic_update_slice(
+                                pools[0], pick(after, ends[k])[None], at),
+                            jax.lax.dynamic_update_slice(
+                                pools[1], pick(cstate, k)[None].astype(
+                                    conv.dtype), at[:4]))
+                ssm, conv = jax.lax.fori_loop(0, used, put, (ssm, conv))
                 m += 1
             elif kind == "*":
-                out, k, v = attention_sequence(cfg, p, u)
+                out, k, v = attention_sequence(cfg, p, u, starts, lens)
                 kflat, vflat = self._pool_write(
                     kflat, vflat, k, v, a * (NB * bs) + widx)
                 a += 1
@@ -819,23 +947,25 @@ class HybridPagedDecoder(PagedDecoder):
             x = x + out
         kpool = self._stacked_pools(kflat, kpool)
         vpool = self._stacked_pools(vflat, vpool)
-        last = jnp.take(x, jnp.maximum(true_len - 1, 0), axis=0)
-        last = _rms(last[None], params["norm"], self.eps)
-        logits = self._head_logits(params, last)[0]
-        enc = jnp.concatenate([self._encode_first_token(logits)[None],
-                               counts])
+        last = jnp.take(x, jnp.clip(starts + lens - 1, 0, rows - 1), axis=0)
+        logits = self._head_logits(
+            params, _rms(last, params["norm"], self.eps))
+        enc = jnp.concatenate(
+            [jax.vmap(self._encode_first_token)(logits), counts])
         return enc, kpool, vpool, ssm, conv
 
-    def decode_first_token(self, enc):
-        """The first token as `PagedDecoder` encodes it, and behind it on
-        the same wire the prompt's MoE counts, kept for
-        `admit_metadata`."""
+    def decode_first_token(self, enc, seg=0):
+        """Segment `seg`'s first token as `PagedDecoder` encodes it. The
+        pack's MoE counts ride behind the tokens on the same wire and
+        are kept for `admit_metadata`: on the pack's first admission,
+        the others carry none, so that the sums over admissions are the
+        programs' own."""
         v = np.asarray(enc)
-        self._admit_counts = [int(c) for c in v[1:]]
-        return super().decode_first_token(v[0])
+        self._admit_counts = [int(c) if seg == 0 else 0 for c in v[-4:]]
+        return super().decode_first_token(v[seg])
 
     def admit_metadata(self):
         """The slot's recurrent state that the prefill overwrote, and
-        the prompt's MoE counts under the chunk counters' names."""
+        the pack's MoE counts under the chunk counters' names."""
         return {"state_bytes": self.slot_state_bytes,
                 **dict(zip(self.COUNTERS[:4], self._admit_counts))}

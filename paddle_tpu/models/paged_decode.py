@@ -409,9 +409,24 @@ class PagedDecoder(CachedDecoder):
                                weight_quant=weight_quant)
         self.kv_layers = self.cfg.num_hidden_layers
 
-    def _prefill_extra(self, slot):
-        """Arguments `_prefill_paged` takes after the pools."""
-        return ()
+    def prefill_bucket(self, n):
+        """Rows of the prefill program that takes a prompt of `n`
+        tokens: the block size doubled until it holds them, `max_len`
+        at most."""
+        bucket = self.block_size
+        while bucket < n:
+            bucket *= 2
+        return min(bucket, self.max_len)
+
+    def _prefill_inputs(self, bucket, members, tables, pad):
+        """What `_prefill_paged` takes before and after the pools for
+        `members` [(slot, prompt ids, start row)]: one prompt from row 0
+        here, padded behind to the bucket."""
+        (slot, prompt, _), = members
+        ids = np.full(bucket, pad, np.int32)
+        ids[:len(prompt)] = prompt
+        return (jnp.asarray(ids), jnp.int32(len(prompt)),
+                jnp.asarray(tables[slot])), ()
 
     def chunk_counters(self, aux):
         """`serve:commit` metadata from what the chunk program returned
@@ -784,9 +799,10 @@ class PagedDecoder(CachedDecoder):
         return jnp.where(ok, tok, -tok - 1)
 
     @staticmethod
-    def decode_first_token(enc):
+    def decode_first_token(enc, seg=0):
         """Host side of `_encode_first_token`: (first_token,
-        logits_nonfinite) from the one-int32 prefill result."""
+        logits_nonfinite) from the one-int32 prefill result (`seg`:
+        which prompt of a packed prefill; this engine's holds one)."""
         v = int(np.asarray(enc))
         return (-v - 1, True) if v < 0 else (v, False)
 
@@ -1144,7 +1160,9 @@ class PagedDecoder(CachedDecoder):
         its prefills, then reads their first tokens in the same order;
         the tokens served are the same, `serve:prefill` then lies
         outside `serve:admit`, which spans the wait for the first token
-        and the slot joining the batch.
+        and the slot joining the batch. An engine whose prefill program
+        takes a pack of prompts (`prefill_packs`; the hybrid engine)
+        then sends the scan's prompts several a program.
 
         Prefix cache (ISSUE 18; engines built with prefix_cache=True):
         admission matches the prompt against the radix tree over the

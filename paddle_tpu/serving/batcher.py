@@ -175,6 +175,11 @@ def serve_loop(eng, requests, *, max_new_tokens=32, eos_token_id=None,
     stage_admissions = bool(getattr(eng, "pipelined_admission", False))
     staged = []
     joined = [0.0]           # when the last first token was read
+    # an engine whose prefill program takes a pack of prompts says how a
+    # scan's staged prompts go into programs (`prefill_packs`); the scan
+    # then reserves only, and `join_staged` dispatches the packs
+    plan_packs = getattr(eng, "prefill_packs", None) \
+        if stage_admissions else None
 
     def note_uploads(k):
         eng.h2d_uploads += k
@@ -642,37 +647,50 @@ def serve_loop(eng, requests, *, max_new_tokens=32, eos_token_id=None,
         """One admission as the loop pays for it: from the pop off the
         queue to the slot joining the batch, the wait for the prefill's
         first token included. With `eng.pipelined_admission` a prompt's
-        prefill is only dispatched here; `join_staged` reads the first
-        tokens once the scan has dispatched them all, so the device runs
-        the scan's prefills back to back and a host that is slow or
-        held up between two of them leaves it no gap."""
+        prefill is only dispatched here (only reserved, where the engine
+        packs prompts); `join_staged` reads the first tokens once the
+        scan has dispatched them all, so the device runs the scan's
+        prefills back to back and a host that is slow or held up between
+        two of them leaves it no gap."""
         if stage_admissions and not isinstance(prompt, KVBlockPayload):
-            staged.append(prefill_prompt(i, req_id, prompt, max_new,
-                                         t_admit))
+            rec = reserve_prompt(i, req_id, prompt, max_new, t_admit)
+            if plan_packs is None:
+                dispatch_prefill(rec)
+            staged.append(rec)
             return
         with _obs.span("serve:admit", rid=req_id, slot=i,
                        prompt_tokens=_plen(prompt)) as sp:
             if isinstance(prompt, KVBlockPayload):
                 admit_payload(i, req_id, prompt, max_new, t_admit, sp)
             else:
-                join_prompt(prefill_prompt(i, req_id, prompt, max_new,
-                                           t_admit), sp)
+                rec = reserve_prompt(i, req_id, prompt, max_new, t_admit)
+                dispatch_prefill(rec)
+                join_prompt(rec, sp)
 
     def join_staged():
         """The second half of a pipelined scan's admissions, in the
-        order of their dispatch: `serve:admit` spans the loop's wait
-        for each first token and the slot joining the batch."""
+        order of admission: `serve:admit` spans the loop's wait for each
+        first token and the slot joining the batch. Where the engine
+        packs prompts, the scan has only reserved: its packs are
+        dispatched here, back to back, before the first read (one read a
+        pack: the others of its admissions find their token on the
+        host)."""
+        if plan_packs is not None:
+            for bucket, members in plan_packs([r["s0"] for r in staged]):
+                dispatch_cold(bucket,
+                              [(staged[j], start) for j, start in members])
         for rec in staged:
-            i, slot = rec[0], rec[1]
-            with _obs.span("serve:admit", rid=slot.req_id, slot=i,
+            slot = rec["slot"]
+            with _obs.span("serve:admit", rid=slot.req_id, slot=rec["i"],
                            prompt_tokens=len(slot.prompt)) as sp:
                 join_prompt(rec, sp)
         staged.clear()
 
-    def prefill_prompt(i, req_id, prompt, max_new, t_admit):
-        """Allocate the slot and dispatch the prompt's prefill. Returns
-        what `join_prompt` needs to read its first token."""
-        nonlocal pools
+    def reserve_prompt(i, req_id, prompt, max_new, t_admit):
+        """Everything an admission does before its first device call:
+        the slot, its blocks and table row, the ledger. Returns the
+        record that `dispatch_prefill` fills in and `join_prompt`
+        reads."""
         mark_state_dirty()
         prompt = list(map(int, prompt))
         # chunked-prefill replay: a previously evicted incarnation
@@ -713,36 +731,51 @@ def serve_loop(eng, requests, *, max_new_tokens=32, eos_token_id=None,
         # device call (pools untouched, donation not yet consumed),
         # the window where recovery is clean unwind + replay
         _faults.inject("prefill_chunk")
+        return {"i": i, "slot": slot, "s0": s0, "ids": ids_full,
+                "cached": cached, "kb": kb, "cow_src": cow_src,
+                "fresh": fresh, "seg": 0}
+
+    def dispatch_cold(bucket, members):
+        """One bucketed in-prompt prefill program over `members`
+        [(record, start row)]: one prompt from row 0, or a pack on an
+        engine whose program takes one."""
+        nonlocal pools
+        head, tail = eng._prefill_inputs(
+            bucket, [(r["i"], r["ids"], start) for r, start in members],
+            tables, pad_token_id)
+        args_p = (eng._params,) + head + pools + tail
+        fn = eng._prefill_exec(bucket)
+        c0 = analysed(("prefill_b", bucket), fn, args_p) \
+            if telemetry else 0.0
+        rows = sum(r["s0"] for r, _ in members)
+        t0p = time.perf_counter()
+        with _obs.span("serve:prefill", bucket=bucket,
+                       prompts=len(members), rows=rows):
+            enc, *out = fn(*args_p)
+        if telemetry:
+            phase["execute"] -= compiled() - c0
+        pools = tuple(out)
+        eng.prefill_device_calls += 1
+        eng.prefill_tokens_computed += rows
+        for seg, (r, _) in enumerate(members):
+            r.update(enc=enc, seg=seg, t0p=t0p, bucket=bucket)
+
+    def dispatch_prefill(rec):
+        """Dispatch one reserved prompt's prefill by itself."""
+        nonlocal pools
+        i, s0, cached = rec["i"], rec["s0"], rec["cached"]
         if cache is None:
             # historical cold path: bucketed in-prompt prefill —
             # cache-off engines keep their executables byte-identical
-            bucket = bs
-            while bucket < s0:
-                bucket *= 2
-            bucket = min(bucket, eng.max_len)
-            ids = np.full(bucket, pad_token_id, np.int32)
-            ids[:s0] = ids_full
-            args_p = (eng._params, jnp.asarray(ids), jnp.int32(s0),
-                      jnp.asarray(tables[i])) + pools \
-                + eng._prefill_extra(i)
-            fn = eng._prefill_exec(bucket)
-            c0 = analysed(("prefill_b", bucket), fn, args_p) \
-                if telemetry else 0.0
-            t0p = time.perf_counter()
-            with _obs.span("serve:prefill", bucket=bucket):
-                enc, *out = fn(*args_p)
-            if telemetry:
-                phase["execute"] -= compiled() - c0
-            pools = tuple(out)
-            eng.prefill_device_calls += 1
-            eng.prefill_tokens_computed += s0
+            dispatch_cold(eng.prefill_bucket(s0), [(rec, 0)])
         else:
             # warm path: every cache-on prefill — hit or miss — runs
             # the pool-mapped suffix executable (cold is just
             # start=0), so cold and warm streams share numerics and
             # the greedy parity gate holds by construction
-            suffix = ids_full[cached:]
+            suffix = rec["ids"][cached:]
             ns = len(suffix)
+            cow_src, fresh = rec["cow_src"], rec["fresh"]
             # chunked prefill (r21 long-context): when the engine was
             # built with prefill_chunk, a long suffix runs through
             # FIXED chunk-sized warmfill executables over successive
@@ -762,10 +795,7 @@ def serve_loop(eng, requests, *, max_new_tokens=32, eos_token_id=None,
             enc = None
             for off, piece in pieces:
                 npiece = len(piece)
-                bucket = bs
-                while bucket < npiece:
-                    bucket *= 2
-                bucket = min(bucket, eng.max_len)
+                bucket = eng.prefill_bucket(npiece)
                 ids = np.full(bucket, pad_token_id, np.int32)
                 ids[:npiece] = piece
                 args_w = (eng._params, jnp.asarray(ids),
@@ -797,20 +827,24 @@ def serve_loop(eng, requests, *, max_new_tokens=32, eos_token_id=None,
             # only the LAST window's fused first-token matters (the
             # earlier windows exist for their KV writes)
             eng.prefill_tokens_computed += ns
-            cache.record_admission(cached, kb, cow=cow_src is not None)
-        return i, slot, s0, enc, t0p, bucket, cached
+            cache.record_admission(cached, rec["kb"],
+                                   cow=cow_src is not None)
+            rec.update(enc=enc, t0p=t0p, bucket=bucket)
 
     def join_prompt(rec, sp):
         """Read the first token of a dispatched prefill and let the
         slot join the batch."""
-        i, slot, s0, enc, t0p, bucket, cached = rec
+        i, slot, s0, cached = rec["i"], rec["slot"], rec["s0"], rec["cached"]
+        t0p, bucket = rec["t0p"], rec["bucket"]
         req_id = slot.req_id
         # ONE int32 on the wire (ISSUE 20 tentpole c): the argmax AND
         # the finiteness probe are fused on device — a 128k-vocab f32
         # row used to cross per admission. Reading it is where the loop
-        # waits for the prefill
+        # waits for the prefill (a pack's tokens come in one array, read
+        # once)
         with _obs.span("serve:wait_first_token", rid=req_id):
-            first, nonfinite = eng.decode_first_token(enc)
+            first, nonfinite = eng.decode_first_token(rec["enc"],
+                                                      rec["seg"])
         bad_prefill = quarantine_on and nonfinite
         t1p = time.perf_counter()
         if telemetry:
@@ -865,6 +899,11 @@ def serve_loop(eng, requests, *, max_new_tokens=32, eos_token_id=None,
     feeding = (lambda: False) if feed_active is None else feed_active
 
     try:
+        if plan_packs is not None:
+            # a pack's bucket follows from what a scan happens to stage:
+            # every bucket's program exists before the first admission
+            with _obs.span("serve:warm_packs"):
+                pools = eng.warm_prefill(pools, pad_token_id)
         while queue or live.any() or feeding():
             with _obs.span("serve:iteration", live=int(live.sum()),
                            queued=len(queue)):

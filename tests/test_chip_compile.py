@@ -244,16 +244,36 @@ def test_hybrid_chunk_program(hybrid, for_the_chip):
         < 15.75 * 2**30
 
 
+def _hybrid_prefill(dec, pools, described, bucket):
+    """The packed prefill program of `bucket` rows, compiled."""
+    k = -(-bucket // dec.cfg.chunk_size)
+    i32 = jnp.int32
+    return dec._prefill_exec(bucket).lower(
+        dec._params, described((bucket,), i32), described((k,), i32),
+        described((k,), i32), described((k, dec.blocks_per_seq), i32),
+        *pools, described((k,), i32)).compile()
+
+
 def test_hybrid_prefill_bucket(hybrid, for_the_chip):
-    """The 128-token prefill bucket: it writes one slot's state in place."""
+    """The 128-row prefill bucket (a pack of one): it writes one slot's
+    state in place."""
     dec, pools, described = hybrid
-    compiled = jax.jit(
-        dec._prefill_paged, donate_argnums=dec._prefill_donate).lower(
-        dec._params, described((128,), jnp.int32),
-        described((), jnp.int32), described((dec.blocks_per_seq,), jnp.int32),
-        *pools, described((), jnp.int32)).compile()
-    text = _kernels_and_spare(compiled, dec)
+    text = _kernels_and_spare(_hybrid_prefill(dec, pools, described, 128),
+                              dec)
     assert "%moe.experts" in text
+
+
+def test_hybrid_prefill_pack_of_sixteen(hybrid, for_the_chip):
+    """The 2048-row bucket, up to 16 prompts a pack: with the arguments
+    it fits the chip, and the 16 segments' states go into the pools
+    without a second copy of a pool."""
+    dec, pools, described = hybrid
+    compiled = _hybrid_prefill(dec, pools, described, 2048)
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes \
+        < 15.75 * 2**30
+    ssm = pools[2]
+    assert memory.temp_size_in_bytes < ssm.size * 4
 
 
 # -- the sparse-expert train step (PR 35): head size 64, the grouped backward ----

@@ -339,7 +339,9 @@ def test_nemotron_h_serve_programs_are_unchanged():
     """`moe_route` and the sort are shared with `nemotron_h`; its serve
     programs have to lower to the text they lowered to before this family
     came (sha256 of the CPU lowering at the parent commit; a PR that
-    means to change those programs replaces the two digests)."""
+    means to change those programs replaces the two digests: PR 36 made
+    the prefill the packed program and replaced its digest, the chunk's
+    is PR 35's)."""
     from paddle_tpu.models.nemotron_h import (NemotronHForCausalLM,
                                               nemotron_h_tiny)
     from paddle_tpu.models.paged_decode import PagedDecoder
@@ -352,15 +354,14 @@ def test_nemotron_h_serve_programs_are_unchanged():
         dec._params, jnp.zeros((s,), i32), jnp.zeros((s,), i32),
         jnp.zeros((s, mb), i32), jnp.zeros((s,), flag),
         jnp.zeros((s,), i32), jnp.zeros((s,), flag), *pools, 2, -1).as_text()
-    prefill = jax.jit(dec._prefill_paged,
-                      donate_argnums=dec._prefill_donate).lower(
-        dec._params, jnp.zeros((16,), i32), jnp.zeros((), i32),
-        jnp.zeros((mb,), i32), *pools, jnp.zeros((), i32)).as_text()
+    head, tail = dec._prefill_inputs(16, [], (), 0)
+    prefill = dec._prefill_exec(16).lower(
+        dec._params, *head, *pools, *tail).as_text()
     digest = {name: hashlib.sha256(text.encode()).hexdigest()
               for name, text in (("chunk", chunk), ("prefill", prefill))}
     assert digest == {
         "chunk": "af2e2181d25aed7d9edbaf0ae64963710e0c4cef9e71fce72659560255b4127d",
-        "prefill": "79d54082127dd259d95bde15e60ecd5c9c9d18b06caff425a536d01ab50af476"}
+        "prefill": "eaac8f468060fb8e54f85efccfcb015484e131c92691d6257d863892e09fed58"}
 
 
 def test_rows_the_kernel_never_wrote_do_not_reach_a_gradient(

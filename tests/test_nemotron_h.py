@@ -71,10 +71,10 @@ def test_mixer_matches_the_reference(model, weights, kind, layer):
     rp = ref.layer_params(weights, layer)
     u = _hidden(21)
     if kind == "M":
-        got = nh.mamba_sequence(cfg, p, u, 21)[0]
+        got = nh.mamba_sequence(cfg, p, u, *nh.one_segment(21))[0]
         want = ref.mamba_mixer(CFG, rp, u, "f32")[0]
     elif kind == "*":
-        got = nh.attention_sequence(cfg, p, u)[0]
+        got = nh.attention_sequence(cfg, p, u, *nh.one_segment(21))[0]
         want = ref.attention_mixer(CFG, rp, u, "f32")
     else:
         got = nh.latent_moe(cfg, p, u)[0]
@@ -106,10 +106,11 @@ def _ssm_inputs(n, seed=3):
 @pytest.mark.parametrize("length", [3, 8, 13, 29])
 def test_chunked_scan_is_the_sequential_recurrence(length):
     x, b, c, dt, a, d = _ssm_inputs(length)
-    y, last = nh.ssd_chunked(x, b, c, dt, a, d, chunk=8)
+    y, after = nh.ssd_chunked(x, b, c, dt, a, d, 8,
+                              nh.one_segment(length)[0])
     y_ref, last_ref = ref.ssm_sequential(x, b, c, dt, a, d)
     _close(y, y_ref)
-    _close(last, last_ref)
+    _close(after[-1], last_ref)
 
 
 def test_decode_step_is_one_step_of_the_recurrence():
@@ -133,11 +134,15 @@ def test_padded_bucket_leaves_the_state_of_true_len(model, true_len, bucket):
     unpadded prompt, and the real positions' outputs do not move."""
     cfg, p = model.config, model.param_tree()["layers"][0]
     u = _hidden(bucket, seed=true_len)
-    out, state, conv = nh.mamba_sequence(cfg, p, u, true_len)
-    out1, state1, conv1 = nh.mamba_sequence(cfg, p, u[:true_len], true_len)
+    one = nh.one_segment(true_len)
+    out, after, conv = nh.mamba_sequence(cfg, p, u, *one)
+    out1, after1, conv1 = nh.mamba_sequence(cfg, p, u[:true_len], *one)
+    state = after[nh.last_chunk(*one, cfg.chunk_size, bucket)[0]]
+    state1 = after1[-1]
     _close(state, state1)
     _close(conv, conv1)
     _close(out[:true_len], out1)
+    conv = conv[0]
     # and the conv's rows are the last three inputs before true_len
     xbc = nh.mamba_project(cfg, p, u)[1]
     want = np.zeros((3, xbc.shape[1]), np.float32)
@@ -174,17 +179,14 @@ def test_prefill_then_decode_gives_the_reference_logits(model, weights):
     pools = dec.new_pools()
     table = np.zeros(dec.blocks_per_seq, np.int32)
     table[:3] = [5, 2, 7]
-    padded = np.zeros(16, np.int32)
-    padded[:11] = prompt
     slot = 1
-    enc, *pools = dec._prefill_paged(
-        dec._params, jnp.asarray(padded), jnp.int32(11), jnp.asarray(table),
-        *pools, jnp.int32(slot))
+    tables = np.zeros((2, dec.blocks_per_seq), np.int32)
+    tables[slot] = table
+    head, tail = dec._prefill_inputs(16, [(slot, prompt, 0)], tables, 0)
+    enc, *pools = dec._prefill_paged(dec._params, *head, *pools, *tail)
     want = np.asarray(ref.logits_at(CFG, weights, jnp.asarray(ids),
                                     jnp.arange(10, 20)))
     assert dec.decode_first_token(enc) == (int(want[0].argmax()), False)
-    tables = np.zeros((2, dec.blocks_per_seq), np.int32)
-    tables[slot] = table
     active = jnp.asarray([False, True])
     for step, token in enumerate(rest):
         tokens = jnp.asarray([0, token], jnp.int32)
@@ -237,10 +239,11 @@ def test_reused_slot_gives_what_the_request_gives_alone(served, model, rid):
 
 @pytest.mark.parametrize("eos", [None, 7])
 def test_pipelined_admission_serves_the_same_tokens(served, model, eos):
-    """`pipelined_admission=True`: a scan dispatches all its prefills
-    (three free slots: the first scan takes three prompts) before it
-    reads a first token, and every request gets the tokens it gets
-    with one prefill in flight at a time."""
+    """`pipelined_admission=True`: a scan dispatches its prompts' one
+    packed prefill (three free slots: the first scan takes three
+    prompts, 5 + 12 + 8 tokens from rows 0, 8 and 24 of a 32-row
+    program) before it reads a first token, and every request gets the
+    tokens it gets with one prompt in flight at a time."""
     from paddle_tpu.observability import tracing
     reqs = _serve_requests()
     kw = dict(max_new_tokens=25, chunk=4, eos_token_id=eos)
@@ -257,7 +260,9 @@ def test_pipelined_admission_serves_the_same_tokens(served, model, eos):
     assert got == want
     order = [s["name"] for s in sorted(spans, key=lambda s: s["t0_ns"])
              if s["name"] in ("serve:prefill", "serve:wait_first_token")]
-    assert order[:6] == ["serve:prefill"] * 3 + ["serve:wait_first_token"] * 3
+    assert order[:4] == ["serve:prefill"] + ["serve:wait_first_token"] * 3
+    first = next(s["meta"] for s in spans if s["name"] == "serve:prefill")
+    assert first == {"bucket": 32, "prompts": 3, "rows": 5 + 12 + 8}
     ids = {s["id"]: s["name"] for s in spans}
     admits = [s for s in spans if s["name"] == "serve:admit"]
     assert len(admits) == len(reqs)

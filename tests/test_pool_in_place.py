@@ -225,14 +225,16 @@ def _hybrid_programs():
     pools = dec.new_pools()
     i32 = lambda *shape: jnp.zeros(shape, jnp.int32)
     flag = lambda *shape: jnp.zeros(shape, bool)
+    # a pack of two prompts, from rows 0 and 8 of the bucket
+    head, tail = dec._prefill_inputs(
+        BUCKET, [(1, [3, 4, 5], 0), (2, [6, 7], 8)],
+        np.zeros((S, MB), np.int32), 0)
     return dec, pools, {
         "state_chunk": (dec._paged_chunk_state_jit,
                         (dec._params, i32(S), i32(S), i32(S, MB), flag(S),
                          i32(S), flag(S)) + pools + (2, -1)),
-        "cold_prefill": (jax.jit(dec._prefill_paged,
-                                 donate_argnums=dec._prefill_donate),
-                         (dec._params, i32(BUCKET), i32(), i32(MB)) + pools
-                         + (i32(),)),
+        "cold_prefill": (dec._prefill_exec(BUCKET),
+                         (dec._params,) + head + pools + tail),
     }
 
 
