@@ -106,8 +106,8 @@ def _blocks_per_step(block_bytes, block_rows, blocks_per_seq):
     return chunk * chunks, chunk
 
 
-def _step_kernel(tabs_ref, lens_ref, q_ref, k_hbm, v_hbm, *rest, bs, nkv,
-                 nrep, scale, group, chunk, quant, partials):
+def _step_kernel(tabs_ref, lens_ref, *rest, bs, nkv, nrep, scale, group,
+                 chunk, quant, partials, bounded=False, sunk=False):
     """One slot: its live pool blocks stream HBM -> VMEM in groups of
     `group`, double-buffered, the next group (this slot's, or the next
     slot's first) in flight while this one is attended, `chunk` blocks
@@ -120,10 +120,21 @@ def _step_kernel(tabs_ref, lens_ref, q_ref, k_hbm, v_hbm, *rest, bs, nkv,
     blocks; o_ref [nh, hd] (with `partials` float32, and lse_ref
     [nh, 1] beside it). lens[s] is the position of the token just
     written: the live window is positions 0..lens[s], -1 an empty
-    shard.
+    shard. K rows and V rows may differ in width (o_ref is as wide as
+    V's). `bounded`: a third prefetched scalar a slot, lows[s], the
+    first position of the table's first block that is attended (a
+    window's lower edge; what lies before it in that block is masked).
+    `sunk`: one more input sink_ref [nh, 1] f32, a bias a head that
+    joins the softmax's denominator and carries no value: the running
+    (max, sum) start at (sink, 1) instead of (-inf, 0).
     """
+    if bounded:
+        lows_ref, *rest = rest
+    q_ref, k_hbm, v_hbm, *rest = rest
     if quant:
         ks_ref, vs_ref, *rest = rest
+    if sunk:
+        sink_ref, *rest = rest
     if partials:
         o_ref, lse_ref, *rest = rest
     else:
@@ -182,8 +193,12 @@ def _step_kernel(tabs_ref, lens_ref, q_ref, k_hbm, v_hbm, *rest, bs, nkv,
     def _own():
         start(s, zero, first % 2)
 
-    m_sc[:] = jnp.full_like(m_sc, NEG_INF)
-    l_sc[:] = jnp.zeros_like(l_sc)
+    if sunk:
+        m_sc[:] = sink_ref[:]
+        l_sc[:] = jnp.ones_like(l_sc)
+    else:
+        m_sc[:] = jnp.full_like(m_sc, NEG_INF)
+        l_sc[:] = jnp.zeros_like(l_sc)
     acc_sc[:] = jnp.zeros_like(acc_sc)
 
     pos = lens_ref[s]
@@ -200,6 +215,9 @@ def _step_kernel(tabs_ref, lens_ref, q_ref, k_hbm, v_hbm, *rest, bs, nkv,
         st = _dot(q, kbuf[buf, at, :].astype(cdtype), _NT) * scale
         live = jnp.logical_and(
             own, col < (pos - b0 * bs_i + one) * np.int32(nkv))
+        if bounded:
+            live = jnp.logical_and(
+                live, col >= (lows_ref[s] - b0 * bs_i) * np.int32(nkv))
         if quant:
             sc_at = pl.ds(pl.multiple_of(b0 * np.int32(rows), rows), cols)
             st = st * ks_ref[:, sc_at]
@@ -254,28 +272,44 @@ def _step_kernel(tabs_ref, lens_ref, q_ref, k_hbm, v_hbm, *rest, bs, nkv,
 
 @i32_trace
 def _launch(q, kpool, vpool, tables, seq_lens, scale, scales=None,
-            partials=False):
-    """The one pallas launch behind the three entry points. `scales` =
+            partials=False, lows=None, sinks=None, kv_heads=None):
+    """The one pallas launch behind the entry points. `scales` =
     (kscale, vscale) [num_blocks, bs] f32 marks int8 pools; `partials`
     returns (o [S, nh, hd] f32 normalized within the launch, lse
-    [S, nh, 1] f32) for the sharded merge, where seq_lens may be -1."""
+    [S, nh, 1] f32) for the sharded merge, where seq_lens may be -1.
+    V's rows may be narrower or wider than K's (o is as wide as V's);
+    `lows` [S] int32 bounds each slot's window from below and `sinks`
+    [nh] f32 joins each head's denominator (`_step_kernel`)."""
     S, nh, hd = q.shape
-    nblocks, bs, nkv, _ = kpool.shape
+    if kpool.ndim == 3:
+        # the pools already as the kernel sees them, [blocks, bs * nkv,
+        # width]
+        nblocks, rows, _ = kpool.shape
+        nkv, bs = int(kv_heads), rows // int(kv_heads)
+    else:
+        nblocks, bs, nkv, _ = kpool.shape
+    hdv = vpool.shape[-1]
     mb = tables.shape[1]
     rows = bs * nkv
     tables = tables.astype(jnp.int32)
     seq_lens = seq_lens.astype(jnp.int32)
     block_bytes = rows * hd * kpool.dtype.itemsize
-    group, chunk = _blocks_per_step(block_bytes, rows, mb)
+    vblock_bytes = rows * hdv * vpool.dtype.itemsize
+    # K's and V's blocks together, as two of K's when they are alike
+    group, chunk = _blocks_per_step((block_bytes + vblock_bytes) // 2, rows,
+                                    mb)
     # tokens and KV heads merged into one axis of rows: the same bytes
     # under the chip's tiled layouts (XLA makes it a bitcast, not a
     # copy), and no head is ever sliced out of a block
     kpool = kpool.reshape(nblocks, rows, hd)
-    vpool = vpool.reshape(nblocks, rows, hd)
+    vpool = vpool.reshape(nblocks, rows, hdv)
+    prefetch = [tables, seq_lens]
+    if lows is not None:
+        prefetch.append(lows.astype(jnp.int32))
 
     def slot(*shape):
         return pl.BlockSpec((None,) + shape,
-                            lambda s, tabs, lens: (s,) + (0,) * len(shape))
+                            lambda s, *_: (s,) + (0,) * len(shape))
 
     hbm = pl.BlockSpec(memory_space=pl.ANY)
     in_specs, operands = [slot(nh, hd), hbm, hbm], [q, kpool, vpool]
@@ -290,16 +324,19 @@ def _launch(q, kpool, vpool, tables, seq_lens, scale, scales=None,
                          ((0, 0), (0, pad), (0, 0)), constant_values=1.0)
             in_specs.append(slot(1, (mb + pad) * rows))
             operands.append(sc.reshape(S, 1, -1))
-    out_specs, out_shape = slot(nh, hd), jax.ShapeDtypeStruct(
-        (S, nh, hd), jnp.float32 if partials else q.dtype)
+    if sinks is not None:
+        in_specs.append(pl.BlockSpec((nh, 1), lambda s, *_: (0, 0)))
+        operands.append(sinks.astype(jnp.float32).reshape(nh, 1))
+    out_specs, out_shape = slot(nh, hdv), jax.ShapeDtypeStruct(
+        (S, nh, hdv), jnp.float32 if partials else q.dtype)
     if partials:
         out_specs = [out_specs, slot(nh, 1)]
         out_shape = [out_shape,
                      jax.ShapeDtypeStruct((S, nh, 1), jnp.float32)]
-    buffers = [pltpu.VMEM((2, group * rows, hd), pool.dtype)
+    buffers = [pltpu.VMEM((2, group * rows, pool.shape[-1]), pool.dtype)
                for pool in (kpool, vpool)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=len(prefetch),
         grid=(S,),
         in_specs=in_specs,
         out_specs=out_specs,
@@ -308,39 +345,57 @@ def _launch(q, kpool, vpool, tables, seq_lens, scale, scales=None,
             pltpu.SMEM((1,), jnp.int32),
             pltpu.VMEM((nh, 1), jnp.float32),
             pltpu.VMEM((nh, 1), jnp.float32),
-            pltpu.VMEM((nh, hd), jnp.float32),
+            pltpu.VMEM((nh, hdv), jnp.float32),
         ],
     )
     kernel = functools.partial(
         _step_kernel, bs=bs, nkv=nkv, nrep=nh // nkv,
         scale=np.float32(scale), group=group, chunk=chunk,
-        quant=scales is not None, partials=partials)
+        quant=scales is not None, partials=partials,
+        bounded=lows is not None, sunk=sinks is not None)
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=out_shape,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
-            vmem_limit_bytes=4 * group * block_bytes + 32 * 2**20),
+            vmem_limit_bytes=2 * group * (block_bytes + vblock_bytes)
+            + 32 * 2**20),
         interpret=_interpret(),
-    )(tables, seq_lens, *operands)
+    )(*prefetch, *operands)
 
 
-def ragged_paged_attention(q, kpool, vpool, tables, seq_lens, scale=None):
+def ragged_paged_attention(q, kpool, vpool, tables, seq_lens, scale=None,
+                           lows=None, sinks=None, kv_heads=None):
     """Grouped causal decode attention straight off the paged KV pool.
 
-    q [S, nh, hd]; kpool/vpool [num_blocks, block_size, nkv, hd];
+    q [S, nh, hd]; kpool [num_blocks, block_size, nkv, hd]; vpool the
+    same with its own last axis (V's rows may be narrower than K's);
     tables [S, blocks_per_seq] int32 pool-block ids; seq_lens [S] int32
     position of the token just written (the window is positions
     0..seq_lens[s] inclusive, matching the dense path's
-    `arange(W) <= pos` mask). Returns [S, nh, hd] in q.dtype.
+    `arange(W) <= pos` mask). Returns [S, nh, V's width] in q.dtype.
 
     Rows whose table entries past `seq_lens[s] // block_size` are
     unallocated (zeros) are safe: no copy is issued for them.
+
+    A sliding window hands in the table of its live blocks alone, first
+    live block first, positions counted from that block's start, and
+    `lows` [S] int32: the first position attended (the window is
+    lows[s]..seq_lens[s]; no block before it is in the table, so none is
+    copied). `sinks` [nh] float32: a learned bias a head that joins the
+    softmax's denominator and carries no value.
+
+    Pools may come as the kernel sees them, [num_blocks, block_size *
+    nkv, width] with `kv_heads` = nkv (row t * nkv + g of a block is
+    token t, KV head g): few KV heads of wide rows are not the same bytes
+    in the two shapes under the chip's tiled layouts, and the reshape
+    would copy the pool.
     """
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
-    return _launch(q, kpool, vpool, tables, seq_lens, float(scale))
+    return _launch(q, kpool, vpool, tables, seq_lens, float(scale),
+                   lows=lows, sinks=sinks, kv_heads=kv_heads)
 
 
 # -- context-length-sharded decode attention (ISSUE 19 tentpole a) ------------

@@ -108,6 +108,13 @@ class NemotronHConfig:
     def count(self, kind):
         return self.hybrid_override_pattern.count(kind)
 
+    @property
+    def cache_kinds(self):
+        """What each block keeps a slot between steps: the rule
+        `PagedDecoder(model)` picks its engine by."""
+        return tuple({"M": "state", "*": "kv"}.get(k)
+                     for k in self.hybrid_override_pattern)
+
     def param_shapes(self):
         """Ordered {parameter name: (shape, float32 only?)}. Matrices are
         [in, out]; an expert stack is [experts held, in, out]."""
@@ -954,15 +961,15 @@ class HybridPagedDecoder(PagedDecoder):
             [jax.vmap(self._encode_first_token)(logits), counts])
         return enc, kpool, vpool, ssm, conv
 
-    def decode_first_token(self, enc, seg=0):
+    def decode_first_token(self, encs, seg=0):
         """Segment `seg`'s first token as `PagedDecoder` encodes it. The
         pack's MoE counts ride behind the tokens on the same wire and
         are kept for `admit_metadata`: on the pack's first admission,
         the others carry none, so that the sums over admissions are the
         programs' own."""
-        v = np.asarray(enc)
+        v = np.asarray(encs[-1])
         self._admit_counts = [int(c) if seg == 0 else 0 for c in v[-4:]]
-        return super().decode_first_token(v[seg])
+        return super().decode_first_token([v[seg]])
 
     def admit_metadata(self):
         """The slot's recurrent state that the prefill overwrote, and

@@ -165,9 +165,10 @@ class PagedDecoder(CachedDecoder):
     What the serve loop carries chunk to chunk is the tuple `new_pools()`
     returns, `(kpool, vpool)` here: every program takes it as its last
     array arguments and returns it in the same order. An engine whose
-    model keeps further per-slot state (models/nemotron_h.py) makes the
-    tuple longer; `PagedDecoder(model)` builds that engine when the
-    model's configuration carries a layer pattern.
+    model keeps further per-slot state (models/nemotron_h.py), or a
+    second kind of KV cache (models/mimo_v2.py), makes the tuple longer;
+    `PagedDecoder(model)` builds that engine when the model's
+    configuration's layer pattern names such a cache (`cache_kinds`).
     """
 
     # writes past the host's view of a slot are harmless here: the next
@@ -178,11 +179,21 @@ class PagedDecoder(CachedDecoder):
     # the pools' places among `_prefill_paged`'s arguments
     _prefill_donate = (4, 5)
 
+    # the engine for a model whose layers do not all keep K and V of
+    # every token: by the kinds of cache its configuration's layer
+    # pattern names (`cache_kinds`, one a layer), not by family
+    ENGINE_OF_KIND = {"state": ("nemotron_h", "HybridPagedDecoder"),
+                      "window": ("mimo_v2", "WindowPagedDecoder")}
+
     def __new__(cls, model, *args, **kwargs):
-        if cls is PagedDecoder and getattr(
-                model.config, "hybrid_override_pattern", None):
-            from .nemotron_h import HybridPagedDecoder
-            cls = HybridPagedDecoder
+        if cls is PagedDecoder:
+            kinds = getattr(model.config, "cache_kinds", ())
+            engine = next((cls.ENGINE_OF_KIND[k] for k in kinds
+                           if k in cls.ENGINE_OF_KIND), None)
+            if engine is not None:
+                import importlib
+                cls = getattr(importlib.import_module(
+                    "." + engine[0], __package__), engine[1])
         return super().__new__(cls)
 
     def __init__(self, model, max_len=None, weight_quant=None,
@@ -427,6 +438,13 @@ class PagedDecoder(CachedDecoder):
         ids[:len(prompt)] = prompt
         return (jnp.asarray(ids), jnp.int32(len(prompt)),
                 jnp.asarray(tables[slot])), ()
+
+    def _prefill_calls(self, bucket, members, tables, pad):
+        """The (before, after the pools) inputs of each call of the
+        bucket's program that prefills `members`: one call here, one a
+        chunk on an engine that prefills a prompt in chunks.
+        `decode_first_token` gets the list of their results."""
+        return [self._prefill_inputs(bucket, members, tables, pad)]
 
     def chunk_counters(self, aux):
         """`serve:commit` metadata from what the chunk program returned
@@ -799,11 +817,12 @@ class PagedDecoder(CachedDecoder):
         return jnp.where(ok, tok, -tok - 1)
 
     @staticmethod
-    def decode_first_token(enc, seg=0):
+    def decode_first_token(encs, seg=0):
         """Host side of `_encode_first_token`: (first_token,
-        logits_nonfinite) from the one-int32 prefill result (`seg`:
-        which prompt of a packed prefill; this engine's holds one)."""
-        v = int(np.asarray(enc))
+        logits_nonfinite) from the results of a prompt's prefill calls,
+        the last of which holds the one int32 (`seg`: which prompt of a
+        packed prefill; this engine's holds one)."""
+        v = int(np.asarray(encs[-1]))
         return (-v - 1, True) if v < 0 else (v, False)
 
     def admit_metadata(self):

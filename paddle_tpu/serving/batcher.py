@@ -740,25 +740,30 @@ def serve_loop(eng, requests, *, max_new_tokens=32, eos_token_id=None,
         [(record, start row)]: one prompt from row 0, or a pack on an
         engine whose program takes one."""
         nonlocal pools
-        head, tail = eng._prefill_inputs(
+        calls = eng._prefill_calls(
             bucket, [(r["i"], r["ids"], start) for r, start in members],
             tables, pad_token_id)
-        args_p = (eng._params,) + head + pools + tail
         fn = eng._prefill_exec(bucket)
-        c0 = analysed(("prefill_b", bucket), fn, args_p) \
+        args_of = lambda call: (eng._params,) + call[0] + pools + call[1]
+        c0 = analysed(("prefill_b", bucket), fn, args_of(calls[0])) \
             if telemetry else 0.0
         rows = sum(r["s0"] for r, _ in members)
+        encs = []
         t0p = time.perf_counter()
         with _obs.span("serve:prefill", bucket=bucket,
                        prompts=len(members), rows=rows):
-            enc, *out = fn(*args_p)
+            # one call, or one a chunk of the prompt: each takes the
+            # pools the one before it returned
+            for call in calls:
+                enc, *out = fn(*args_of(call))
+                pools = tuple(out)
+                encs.append(enc)
         if telemetry:
             phase["execute"] -= compiled() - c0
-        pools = tuple(out)
-        eng.prefill_device_calls += 1
+        eng.prefill_device_calls += len(calls)
         eng.prefill_tokens_computed += rows
         for seg, (r, _) in enumerate(members):
-            r.update(enc=enc, seg=seg, t0p=t0p, bucket=bucket)
+            r.update(enc=encs, seg=seg, t0p=t0p, bucket=bucket)
 
     def dispatch_prefill(rec):
         """Dispatch one reserved prompt's prefill by itself."""
@@ -829,7 +834,7 @@ def serve_loop(eng, requests, *, max_new_tokens=32, eos_token_id=None,
             eng.prefill_tokens_computed += ns
             cache.record_admission(cached, rec["kb"],
                                    cow=cow_src is not None)
-            rec.update(enc=enc, t0p=t0p, bucket=bucket)
+            rec.update(enc=[enc], t0p=t0p, bucket=bucket)
 
     def join_prompt(rec, sp):
         """Read the first token of a dispatched prefill and let the

@@ -112,7 +112,7 @@ class PrefillWorker:
             # sign bit carries the non-finite flag, which transport
             # ignores exactly like the old host-side argmax did
             enc, kpool, vpool = fn(*args_w)
-            first, _ = eng.decode_first_token(enc)
+            first, _ = eng.decode_first_token([enc])
             eng.prefill_device_calls += 1
             eng.prefill_tokens_computed += ns
             if cache is not None:

@@ -39,12 +39,12 @@ def for_the_chip(monkeypatch):
     compiles make goes to or comes from the persistent cache: an entry
     written for a described device cannot be read back without one."""
     from jax.experimental.compilation_cache import compilation_cache
-    from paddle_tpu.kernels.pallas import (flash_attention,
+    from paddle_tpu.kernels.pallas import (flash_attention, flash_prefill,
                                            fused_elementwise,
                                            grouped_matmul,
                                            ragged_paged_attention, rms_norm)
-    for mod in (flash_attention, fused_elementwise, grouped_matmul,
-                ragged_paged_attention, rms_norm):
+    for mod in (flash_attention, flash_prefill, fused_elementwise,
+                grouped_matmul, ragged_paged_attention, rms_norm):
         monkeypatch.setattr(mod, "_interpret", lambda: False)
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
@@ -312,3 +312,52 @@ def test_grouped_matmul_sorted_fwd_bwd(one_chip, for_the_chip, rows, k, n):
     text = _compiled_text(fwd_bwd, one_chip, ((rows, k), BF16),
                           ((8, k, n), BF16), ((8,), jnp.int32))
     assert text.count("tpu_custom_call") >= 3       # forward, dx, dw
+
+
+@pytest.mark.parametrize("kind,nkv,blocks,blocks_per_seq", [
+    ("full", 4, 2 * 15361, 160),        # two full layers' paged pools
+    ("window", 8, 5 * 385, 3),          # five window layers' rings
+])
+def test_ragged_paged_attention_by_layer_kind(one_chip, for_the_chip, kind,
+                                              nkv, blocks, blocks_per_seq):
+    """mimo_v2_flash_serve_backlog_8k's decode attention: 64 query heads,
+    K rows of 192 stored in two lanes (256) and V rows of 128, pools as
+    the kernel sees them (`[blocks, 64 * nkv, width]`: with 4 or 8 KV
+    heads of 256 the merge out of `[64, nkv, 256]` is a copy of the pool,
+    not a bitcast); a window layer with its lower bound and its sink."""
+    from paddle_tpu.kernels.pallas.ragged_paged_attention import (
+        ragged_paged_attention)
+    slots, i32 = 128, jnp.int32
+    shapes = [((slots, 64, 256), BF16), ((blocks, 64 * nkv, 256), BF16),
+              ((blocks, 64 * nkv, 128), BF16),
+              ((slots, blocks_per_seq), i32), ((slots,), i32)]
+    if kind == "window":
+        shapes += [((slots,), i32), ((64,), jnp.float32)]
+        fn = lambda q, k, v, t, n, lo, sk: ragged_paged_attention(
+            q, k, v, t, n, lows=lo, sinks=sk, kv_heads=nkv)
+    else:
+        fn = lambda q, k, v, t, n: ragged_paged_attention(
+            q, k, v, t, n, kv_heads=nkv)
+    text = _compiled_text(fn, one_chip, *shapes)
+    assert "tpu_custom_call" in text
+    assert not re.search(rf"bf16\[{blocks},{64 * nkv},\d+\]\S* copy\(", text)
+
+
+@pytest.mark.parametrize("kind,nkv,keys", [("full", 4, 10240),
+                                           ("window", 8, 128 + 1024)])
+def test_flash_prefill_chunk(one_chip, for_the_chip, kind, nkv, keys):
+    """The same cell's prefill: a chunk of 1,024 queries x 64 heads x 192
+    against the sequence's keys gathered from the cache (a full layer) or
+    the window before the chunk and the chunk's own (a window layer, with
+    the band's lower bound and the sink); V rows of 128."""
+    from paddle_tpu.kernels.pallas.flash_prefill import (
+        flash_prefill_attention)
+    shapes = [((1024, 64, 192), BF16), ((keys, nkv, 192), BF16),
+              ((keys, nkv, 128), BF16), ((), jnp.int32)]
+    if kind == "window":
+        shapes += [((64,), jnp.float32)]
+        fn = lambda q, k, v, at, sk: flash_prefill_attention(
+            q, k, v, at, 0, window=128, sinks=sk)
+    else:
+        fn = lambda q, k, v, at: flash_prefill_attention(q, k, v, at)
+    assert "tpu_custom_call" in _compiled_text(fn, one_chip, *shapes)

@@ -186,7 +186,7 @@ def test_prefill_then_decode_gives_the_reference_logits(model, weights):
     enc, *pools = dec._prefill_paged(dec._params, *head, *pools, *tail)
     want = np.asarray(ref.logits_at(CFG, weights, jnp.asarray(ids),
                                     jnp.arange(10, 20)))
-    assert dec.decode_first_token(enc) == (int(want[0].argmax()), False)
+    assert dec.decode_first_token([enc]) == (int(want[0].argmax()), False)
     active = jnp.asarray([False, True])
     for step, token in enumerate(rest):
         tokens = jnp.asarray([0, token], jnp.int32)

@@ -126,7 +126,7 @@ def _prefilled(dec, bucket, members):
         pages = [np.asarray(pool)[:, blocks].reshape(
             pool.shape[0], -1, *pool.shape[3:])[:, :len(prompt)]
             for pool in (kpool, vpool)]
-        out.append((dec.decode_first_token(enc, seg), *pages,
+        out.append((dec.decode_first_token([enc], seg), *pages,
                     np.asarray(ssm)[:, slot], np.asarray(conv)[:, slot]))
     return out
 

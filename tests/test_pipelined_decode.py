@@ -239,8 +239,8 @@ class TestTeeth:
 
 class TestFusedFirstToken:
     def test_decode_roundtrip(self):
-        assert PagedDecoder.decode_first_token(np.int32(5)) == (5, False)
-        assert PagedDecoder.decode_first_token(np.int32(0)) == (0, False)
+        assert PagedDecoder.decode_first_token([np.int32(5)]) == (5, False)
+        assert PagedDecoder.decode_first_token([np.int32(0)]) == (0, False)
         # non-finite logits ride the sign bit; the argmax survives
-        assert PagedDecoder.decode_first_token(np.int32(-6)) == (5, True)
-        assert PagedDecoder.decode_first_token(np.int32(-1)) == (0, True)
+        assert PagedDecoder.decode_first_token([np.int32(-6)]) == (5, True)
+        assert PagedDecoder.decode_first_token([np.int32(-1)]) == (0, True)

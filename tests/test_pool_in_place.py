@@ -169,7 +169,7 @@ def test_layer_rows_land_in_their_layer(variant):
         enc, kp, vp = prefill(dec._params, jnp.asarray(ids),
                               jnp.int32(len(prompt)),
                               jnp.asarray(tables[s]), kp, vp)
-        first.append(dec.decode_first_token(enc)[0])
+        first.append(dec.decode_first_token([enc])[0])
     lens0 = np.array([len(p) for p in prompts] + [7], np.int32)
     toks, bad, *_, kp, vp = dec._paged_chunk_state_jit(
         dec._params, jnp.asarray(first + [3], jnp.int32),
