@@ -29,6 +29,7 @@ import jax
 import jax.numpy as jnp
 
 from ..framework.tensor import Parameter, Tensor
+from ..kernels.pallas.ssm_update import SCOPE as SSM_UPDATE, ssm_update
 from ..nn.layer.layers import Layer
 from .decode import _rms
 from .paged_decode import PagedDecoder
@@ -266,22 +267,20 @@ def conv_step(state, xbc, w, b):
     return jax.nn.silu(out).astype(xbc.dtype), window[:, 1:]
 
 
-def ssm_step(state, x, b, c, dt, a, d):
-    """The recurrence's one step for every slot. state [S, heads, hd, N]
-    float32; x [S, heads, hd]; b, c [S, G, N]; dt [S, heads] (after
-    softplus); a, d [heads]. `S_t = exp(dt A) S + dt x (x) B`,
-    `y = S_t C + D x`. Returns (y [S, heads, hd] float32, S_t)."""
-    s, heads, hd, n = state.shape
-    g = b.shape[1]
-    r = heads // g
-    st = state.reshape(s, g, r, hd, n)
-    xf = x.astype(F32).reshape(s, g, r, hd)
-    decay = jnp.exp(dt * a[None, :]).reshape(s, g, r, 1, 1)
-    dtx = dt.reshape(s, g, r, 1) * xf
-    new = st * decay + dtx[..., None] * b.astype(F32)[:, :, None, None, :]
-    y = jnp.sum(new * c.astype(F32)[:, :, None, None, :], axis=-1) \
-        + d.astype(F32).reshape(1, g, r, 1) * xf
-    return y.reshape(s, heads, hd), new.reshape(s, heads, hd, n)
+def ssm_step(state, x, b, c, dt, a, d, m=0, active=None):
+    """The recurrence's one step for every slot, by the kernel that reads
+    a slot's state once and writes it back where it was
+    (`kernels/pallas/ssm_update.py`). state: the pool [blocks, S, heads,
+    hd, N] float32, of which block `m` is stepped in place, or one
+    block's [S, heads, hd, N]; x [S, heads, hd]; b, c [S, G, N]; dt
+    [S, heads] (after softplus); a, d [heads]; a slot that is not
+    `active` [S] keeps its state. `S_t = exp(dt A) S + dt x (x)
+    B`, `y = S_t C + D x`. Returns (y [S, heads, hd] float32, the state
+    in the form it came in)."""
+    pool = state[None] if state.ndim == 4 else state
+    y, pool = ssm_update(pool, m, x, b, c, dt, a, active)
+    y = y + d.astype(F32)[None, :, None] * x.astype(F32)
+    return y, pool[0] if state.ndim == 4 else pool
 
 
 def last_chunk(starts, lens, chunk, t):
@@ -381,23 +380,22 @@ def mamba_sequence(cfg, p, u, starts, lens):
         conv_state
 
 
-def mamba_decode(cfg, p, u, state, conv_state, active=None):
-    """One position for every slot: u [S, H], state [S, heads, hd, N]
-    float32, conv_state [S, K-1, C]. A slot that is not `active` keeps
-    its state. Returns (out [S, H], state, conv_state)."""
+def mamba_decode(cfg, p, u, ssm, m, conv_state, active=None):
+    """One position for every slot: u [S, H], ssm the state pool
+    [blocks, S, heads, hd, N] float32 of which this is block `m`,
+    conv_state [S, K-1, C]. A slot that is not `active` keeps its state.
+    Returns (out [S, H], the pool, conv_state)."""
     z, xbc, dt = mamba_project(cfg, p, u)
     xbc, conv_new = conv_step(conv_state, xbc, p["conv_w"], p["conv_b"])
     x, b, c = mamba_split(cfg, xbc)
     dt, a = step_sizes(p, dt)
-    with jax.named_scope("decode.ssm_update"):
-        y, new = ssm_step(state, x, b, c, dt, a, p["D"])
-        if active is not None:
-            new = jnp.where(active[:, None, None, None], new, state)
+    with jax.named_scope(SSM_UPDATE):
+        y, ssm = ssm_step(ssm, x, b, c, dt, a, p["D"], m, active)
     if active is not None:
         conv_new = jnp.where(active[:, None, None], conv_new, conv_state)
     y = gated_group_norm(y.reshape(u.shape[0], -1), z, p["gnorm"],
                          cfg.n_groups, cfg.layer_norm_epsilon)
-    return y.astype(u.dtype) @ p["out_proj"].astype(u.dtype), new, conv_new
+    return y.astype(u.dtype) @ p["out_proj"].astype(u.dtype), ssm, conv_new
 
 
 def attention_sequence(cfg, p, u, starts, lens):
@@ -759,9 +757,8 @@ class HybridPagedDecoder(PagedDecoder):
             p = params["layers"][i]
             u = _rms(x, p["norm"], self.eps)
             if kind == "M":
-                out, s_new, c_new = mamba_decode(cfg, p, u, ssm[m], conv[m],
-                                                 active)
-                ssm = ssm.at[m].set(s_new)
+                out, ssm, c_new = mamba_decode(cfg, p, u, ssm, m, conv[m],
+                                               active)
                 conv = conv.at[m].set(c_new)
                 m += 1
             elif kind == "*":

@@ -42,9 +42,11 @@ def for_the_chip(monkeypatch):
     from paddle_tpu.kernels.pallas import (flash_attention, flash_prefill,
                                            fused_elementwise,
                                            grouped_matmul,
-                                           ragged_paged_attention, rms_norm)
+                                           ragged_paged_attention, rms_norm,
+                                           ssm_update)
     for mod in (flash_attention, flash_prefill, fused_elementwise,
-                grouped_matmul, ragged_paged_attention, rms_norm):
+                grouped_matmul, ragged_paged_attention, rms_norm,
+                ssm_update):
         monkeypatch.setattr(mod, "_interpret", lambda: False)
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
@@ -172,6 +174,33 @@ def test_grouped_matmul_sorted(one_chip, for_the_chip, m, k, n):
     assert "tpu_custom_call" in text
 
 
+@pytest.mark.parametrize("blocks,slots,heads,hd,n,groups", [
+    (5, 128, 128, 64, 128, 8),      # nemotron3_super_serve_backlog's pool
+    (2, 4, 8, 8, 16, 2),            # nemotron_h_tiny's
+])
+def test_ssm_update(one_chip, for_the_chip, blocks, slots, heads, hd, n,
+                    groups):
+    """One Mamba block's decode step over the whole stacked state pool:
+    a 4 MB tile a slot in and out (over the default VMEM limit, which the
+    launch raises), the pool aliased, so the program holds no second
+    copy of a block's state."""
+    from paddle_tpu.kernels.pallas.ssm_update import ssm_update
+    f32 = jnp.float32
+    pool = jax.ShapeDtypeStruct((blocks, slots, heads, hd, n), f32,
+                                sharding=one_chip)
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+            for shape, dtype in (
+                ((slots, heads, hd), BF16), ((slots, groups, n), BF16),
+                ((slots, groups, n), BF16), ((slots, heads), f32),
+                ((heads,), f32), ((slots,), jnp.bool_))]
+    compiled = jax.jit(
+        lambda pool, *a: ssm_update(pool, blocks - 1, *a),
+        donate_argnums=0).lower(pool, *args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < slots * heads * hd * n * 4 / 8
+
+
 @pytest.fixture(scope="module")
 def hybrid(one_chip):
     """The serving engine of `nemotron3_super_120b_ep4_l11` with its
@@ -225,10 +254,12 @@ def _kernels_and_spare(compiled, dec):
 
 
 def test_hybrid_chunk_program(hybrid, for_the_chip):
-    """8 decode steps for 128 slots: the grouped expert kernel and the
-    ragged paged kernel are in the program, its 12.3 GB of arguments fit
-    the chip, and its temporaries are too small for a second copy of a
-    layer of state or of an expert stack."""
+    """8 decode steps for 128 slots: the grouped expert kernel, the
+    ragged paged kernel and the state-update kernel (one launch a Mamba
+    block, named by its scope) are in the program, its 12.3 GB of
+    arguments fit the chip, and its temporaries are too small for a
+    second copy of a layer of state or of an expert stack. No XLA fusion
+    writes the state pool any more."""
     dec, pools, described = hybrid
     S, MB = dec.max_slots, dec.blocks_per_seq
     i32, flag = jnp.int32, jnp.bool_
@@ -238,7 +269,14 @@ def test_hybrid_chunk_program(hybrid, for_the_chip):
         described((S,), i32), described((S,), flag), *pools, 8, -1).compile()
     text = _kernels_and_spare(compiled, dec)
     assert "%moe.experts" in text and "%decode.attend" in text
-    assert text.count("tpu_custom_call") >= 11     # 5 x 2 products + attend
+    assert "%decode.ssm_update" in text
+    # 5 x 2 expert products + attend + 5 state updates
+    assert text.count("tpu_custom_call") >= 16
+    state_pool = "f32[%d,%d,%d,%d,%d]" % pools[2].shape
+    assert state_pool == "f32[5,128,128,64,128]"
+    assert not [line for line in text.splitlines()
+                if "dynamic-update-slice_fusion" in line
+                and state_pool in line]
     memory = compiled.memory_analysis()
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes \
         < 15.75 * 2**30

@@ -340,8 +340,8 @@ def test_nemotron_h_serve_programs_are_unchanged():
     programs have to lower to the text they lowered to before this family
     came (sha256 of the CPU lowering at the parent commit; a PR that
     means to change those programs replaces the two digests: PR 36 made
-    the prefill the packed program and replaced its digest, the chunk's
-    is PR 35's)."""
+    the prefill the packed program and replaced its digest; PR 38 put
+    the state-update kernel into the chunk and replaced the chunk's)."""
     from paddle_tpu.models.nemotron_h import (NemotronHForCausalLM,
                                               nemotron_h_tiny)
     from paddle_tpu.models.paged_decode import PagedDecoder
@@ -360,7 +360,7 @@ def test_nemotron_h_serve_programs_are_unchanged():
     digest = {name: hashlib.sha256(text.encode()).hexdigest()
               for name, text in (("chunk", chunk), ("prefill", prefill))}
     assert digest == {
-        "chunk": "af2e2181d25aed7d9edbaf0ae64963710e0c4cef9e71fce72659560255b4127d",
+        "chunk": "da1426d4458dda07d2353d2c54ff92caffc6607aa7662798a6839b03725ddb88",
         "prefill": "eaac8f468060fb8e54f85efccfcb015484e131c92691d6257d863892e09fed58"}
 
 

@@ -547,7 +547,7 @@ def test_ragged_kernel_without_window_sink_or_narrow_v_is_the_parents(
 
 PARENT_HYBRID = {
     "chunk":
-        "474fe85742b53562b66ac423af55aa8f104b329a841d35aacbb19b80f03b5f9e",
+        "500e3f499510b195009f1f0d4277bad39689248d8508e76e6a32af4640d768cd",
     "prefill":
         "fc9f9173ee1541c754147303a67d827a0718ac34f7819a4877b358d5a9ef8e19",
 }
@@ -558,7 +558,10 @@ def test_hybrid_engines_programs_lower_to_the_parents_text(program):
     """sha256 of the CPU lowering at the parent commit (the dense
     engine's programs and the LFM2 train step are held the same way by
     tests/test_packed_prefill.py): the rule that picks the engine and the
-    loop's prefill calls changed, no program of another engine did."""
+    loop's prefill calls changed, no program of another engine did.
+    PR 38 meant to change "chunk" and no other: its Mamba blocks' state
+    update is the kernel of `kernels/pallas/ssm_update.py` (the digest is
+    that commit's; "prefill" is still PR 36's)."""
     from paddle_tpu.models import nemotron_h as nh
     dec = PagedDecoder(
         nh.NemotronHForCausalLM(nh.nemotron_h_tiny(
