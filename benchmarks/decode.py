@@ -169,7 +169,7 @@ def paged_serving(model, cfg, pt, ctx, new_tokens, n_requests, max_slots,
     led = dec.request_ledger
     summ = led.summary()
     sl = dec._serve_ledger
-    host_gap_frac = (sl.totals.get("host_gap", 0.0) / sl.wall_total
+    starved_frac = (sl.totals.get("host_gap", 0.0) / sl.wall_total
                      if sl is not None and sl.wall_total > 0 else 0.0)
     h2d_per_chunk = dec.h2d_uploads / max(dec.chunk_dispatches, 1)
     obs.disable()
@@ -189,9 +189,10 @@ def paged_serving(model, cfg, pt, ctx, new_tokens, n_requests, max_slots,
         "retired_by_cause": summ["by_cause"],
         "reconcile_max_residual_frac":
             summ["reconcile_max_residual_frac"],
-        # zero-sync pipelined decode (ISSUE 20): device idle between
-        # chunks and steady-state upload rate — both lower-is-better
-        "host_gap_frac": round(host_gap_frac, 4),
+        # the share of the wall in which the loop knew the device's
+        # queue empty (the ledger's host_gap) and the steady-state
+        # upload rate — both lower-is-better
+        "starved_frac": round(starved_frac, 4),
         "h2d_uploads_per_chunk": round(h2d_per_chunk, 4),
         "lookahead_dispatches": dec.lookahead_dispatches,
     }))

@@ -347,11 +347,12 @@ def main():
     scrape_live = ("paddle_tpu_request_ttft_seconds" in scrape_txt
                    and 'quantile="0.99"' in scrape_txt)
 
-    # pipelined zero-sync decode (ISSUE 20): fraction of serve wall the
-    # device sat idle between chunks waiting on host bookkeeping, and
-    # the steady-state upload rate (0/chunk when composition is stable)
+    # the share of the serve wall in which the loop knew the device's
+    # queue empty (the ledger's host_gap: `serve:starved` stretches,
+    # admissions included; 0 between chunks under look-ahead), and the
+    # steady-state upload rate (0/chunk when composition is stable)
     sl = dec._serve_ledger
-    host_gap_frac = (sl.totals.get("host_gap", 0.0) / sl.wall_total
+    starved_frac = (sl.totals.get("host_gap", 0.0) / sl.wall_total
                      if sl is not None and sl.wall_total > 0 else 0.0)
     h2d_per_chunk = dec.h2d_uploads / max(dec.chunk_dispatches, 1)
 
@@ -399,9 +400,9 @@ def main():
         "reconcile_max_residual_frac":
             summ["reconcile_max_residual_frac"],
         "deferred_admissions": dec.admission_deferrals,
-        # pipelined zero-sync decode (ISSUE 20): both lower-is-better,
+        # the loop's hand-overs: both lower-is-better,
         # regression-gated by tools/bench_history.py
-        "host_gap_frac": round(host_gap_frac, 4),
+        "starved_frac": round(starved_frac, 4),
         "h2d_uploads_per_chunk": round(h2d_per_chunk, 4),
         "chunk_dispatches": dec.chunk_dispatches,
         "lookahead_dispatches": dec.lookahead_dispatches,

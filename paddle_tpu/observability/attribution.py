@@ -21,9 +21,11 @@ gated by tools/step_attribution.py):
   e.g. distributed/checkpoint saves, drained via note_external) and
   `data_wait` (the rest — the input pipeline's bill);
 - the in-call interval splits into `compile` + `execute` (measured),
-  `host_gap` (caller-measured device-idle seconds between consecutive
-  device executions — the serve loop's host-bookkeeping stall, ~0 when
-  the pipelined decode overlaps it; carved OUT OF `dispatch`), and
+  `host_gap` (caller-measured device-idle seconds — the serve loop's
+  `serve:starved` stretches that ended in the step: nothing was queued
+  on the device from a blocking read to the next device call's return;
+  ~0 between chunks when the pipelined decode overlaps the host's
+  bookkeeping; carved OUT OF `dispatch`), and
   `dispatch` (in-call host time that is none of those — argument prep,
   result rebinds), with `grad_sync_exposed` carved OUT OF `execute`;
 - buckets sum to wall EXACTLY by construction; `other` absorbs clock
@@ -120,11 +122,10 @@ class StepLedger:
         """Classify the step that ran [call_start, call_end] (perf_counter
         seconds) and emit the ledger record. Returns the record.
 
-        ``host_gap_s`` is caller-measured device-idle time between this
-        step's device execution and the previous one (the serve loop's
-        host-bookkeeping stall); it is carved out of `dispatch` and
-        clamped to the unmeasured in-call remainder so the sums-to-wall
-        invariant holds unconditionally."""
+        ``host_gap_s`` is caller-measured device-idle time (the serve
+        loop's starved stretches that ended in this step); it is carved
+        out of `dispatch` and clamped to the unmeasured in-call
+        remainder so the sums-to-wall invariant holds unconditionally."""
         compile_s = max(float(compile_s), 0.0)
         execute_s = max(float(execute_s), 0.0)
         gap = 0.0

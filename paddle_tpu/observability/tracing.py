@@ -4,12 +4,14 @@ ring-buffered, and on the device trace's clock when a profiler runs.
 The third observability layer (metrics -> **traces** -> attribution).
 PR 1's registry answers *how much*; this answers *where the time went*.
 The instrumented regions are the serve loop's phases (`serve:iteration`,
-`:feed`, `:admit`, `:prefill`, `:wait_first_token`, `:chunk`,
-`:wait_chunk`, `:commit`, ...), the train step (`train_step:call`, with
-`:execute` and `:analyse` under telemetry; `serve:analyse`), eager
-collectives (`collective:<op>`), backend compiles (`xla:compile`, from a
-`jax.monitoring` listener) and the per-request tracks the
-`RequestLedger` writes (`req:queue`, `req:prefill`, `req:decode`).
+`:feed`, `:admit`, `:reserve`, `:prefill_inputs`, `:prefill`,
+`:wait_first_token`, `:chunk`, `:wait_chunk`, `:commit`, ... and
+`serve:starved`, which lies across them: `open_span`), the train step
+(`train_step:call`, with `:execute` and `:analyse` under telemetry;
+`serve:analyse`), eager collectives (`collective:<op>`), backend
+compiles (`xla:compile`, from a `jax.monitoring` listener) and the
+per-request tracks the `RequestLedger` writes (`req:queue`,
+`req:prefill`, `req:decode`).
 
 Design contract:
 
@@ -61,7 +63,7 @@ __all__ = [
     "span", "record_span", "recording", "tracing_enabled", "enable_tracing",
     "disable_tracing", "drain", "clear", "tail", "chrome_events",
     "export_chrome", "write_rank_part", "merge_rank_parts", "trace_rank",
-    "set_track_name", "new_span_id", "compile_seconds",
+    "set_track_name", "new_span_id", "compile_seconds", "open_span",
 ]
 
 define_flag("enable_tracing", False,
@@ -168,6 +170,9 @@ class _NullSpan:
     def set(self, **meta):
         return self
 
+    def close(self, keep=True):
+        pass
+
 
 _NULL = _NullSpan()
 
@@ -188,11 +193,12 @@ def _stats(meta):
 
 
 class _Span:
-    __slots__ = ("name", "meta", "id", "parent", "_t0", "_ann")
+    __slots__ = ("name", "meta", "id", "parent", "_t0", "_ann", "_nests")
 
-    def __init__(self, name, meta):
+    def __init__(self, name, meta, nests=True):
         self.name = name
         self.meta = meta
+        self._nests = nests
         self.id = self.parent = self._t0 = self._ann = None
 
     def set(self, **meta):
@@ -207,10 +213,11 @@ class _Span:
         return self
 
     def __enter__(self):
-        open_ids = _open_ids()
-        self.parent = open_ids[-1] if open_ids else None
         self.id = next(_IDS)
-        open_ids.append(self.id)
+        if self._nests:
+            open_ids = _open_ids()
+            self.parent = open_ids[-1] if open_ids else None
+            open_ids.append(self.id)
         if _profiling():
             self._ann = _Annotation(self.name, **_stats(self.meta or {}))
             self._ann.__enter__()
@@ -218,21 +225,33 @@ class _Span:
         return self
 
     def __exit__(self, *exc):
+        self.close()
+        return False
+
+    def close(self, keep=True):
+        """End the span. `keep=False` leaves it out of the ring (a
+        stretch that turned out not to be one); a profiler session's
+        trace cannot take an opened event back and keeps it, marked
+        `dropped`."""
         t1 = time.perf_counter_ns()
         if self._t0 is None:
-            return False
+            return
         if self._ann is not None:
+            if not keep:
+                self._ann.set_metadata(dropped=1)
             self._ann.__exit__(None, None, None)
-        open_ids = _open_ids()
-        if open_ids and open_ids[-1] == self.id:
-            open_ids.pop()
-        elif self.id in open_ids:        # closed out of order
-            open_ids.remove(self.id)
-        rec = (self.id, self.parent, self.name, self._t0, t1,
-               threading.get_ident(), trace_rank(), self.meta)
-        with _LOCK:
-            _RING.append(rec)
-        return False
+        if self._nests:
+            open_ids = _open_ids()
+            if open_ids and open_ids[-1] == self.id:
+                open_ids.pop()
+            elif self.id in open_ids:        # closed out of order
+                open_ids.remove(self.id)
+        if keep:
+            rec = (self.id, self.parent, self.name, self._t0, t1,
+                   threading.get_ident(), trace_rank(), self.meta)
+            with _LOCK:
+                _RING.append(rec)
+        self._t0 = None
 
 
 # synthetic-track names: tid -> display name for tids that are NOT real
@@ -283,6 +302,19 @@ def span(name, **meta):
     if not (_ACTIVE[0] or _profiling()):
         return _NULL
     return _Span(name, meta or None)
+
+
+def open_span(name, **meta):
+    """Open a span that ends with `.close()` and not with a `with`
+    block: one that straddles the spans around it (the serve loop's
+    `serve:starved` begins inside one iteration and ends in a later
+    one). It takes no parent and is never one: the thread's stack of
+    open spans does not see it. Otherwise a span like any other: the
+    ring and, while a profiler session records, the trace under its own
+    name and extent. The shared null object when nothing records."""
+    if not (_ACTIVE[0] or _profiling()):
+        return _NULL
+    return _Span(name, meta or None, nests=False).__enter__()
 
 
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"

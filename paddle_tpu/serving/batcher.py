@@ -39,10 +39,19 @@ device-resident state BEFORE chunk N's tokens are consumed, so advance/
 retire/cache/ledger bookkeeping overlaps device compute; greedy parity
 with the serial loop holds by construction because the fed-back tokens
 are the ones the device wrote, and token streams are invariant to chunk
-partitioning (per-step gating depends only on per-slot budgets). The
-serve ledger's `host_gap` bucket measures the device-idle window
-between consecutive decode executions — the quantity the pipeline
-exists to eliminate.
+partitioning (per-step gating depends only on per-slot budgets).
+
+What the device has in flight: one chip runs what it is handed in
+order, and the loop makes every device call and every blocking read
+itself, so two integers say whether the device's queue is empty (the
+calls `launched`, the number of the last one a read has `landed`). The
+stretch from a read that leaves nothing queued to the next device call's
+return is one `serve:starved` span (`after`: what ran last, `before`:
+what ended it), the only account of device idle in this loop: the step
+ledger's `host_gap` bucket is the starved seconds that ended in an
+iteration, `paddle_tpu_serve_device_starved_seconds_total{before}` their
+sum under telemetry. Nothing of it reads a clock or asks `is_ready()`
+unless telemetry is on or the tracer records.
 
 Telemetry observes this loop and never steers it: every program is
 called by one expression whatever `telemetry` says. Around the calls it
@@ -160,15 +169,20 @@ def serve_loop(eng, requests, *, max_new_tokens=32, eos_token_id=None,
     # re-uploads from the host mirrors above). poison_mirror tracks the
     # device poison column so a changed coin set swaps ONE component.
     # pending[0] holds the one-chunk-lookahead dispatch not yet
-    # consumed; last_ready[0]/dev_busy[0] feed the host_gap bucket
-    # (device-idle between consecutive decode executions, net of
-    # prefill device time billed inside the window).
+    # consumed; last_ready[0] is when the last chunk's tokens reached
+    # the host (the request ledger bills chunks from it).
     eos_dev = -1 if eos_token_id is None else int(eos_token_id)
     dev = {"state": None}
     poison_mirror = np.zeros(eng.max_slots, bool)
     pending = [None]
     last_ready = [None]
-    dev_busy = [0.0]
+    # device calls made so far, the number of the last one known to have
+    # finished (a blocking read of call k's result says so of every call
+    # up to k), the kind of the last call, admissions begun; `dry` is
+    # the open `serve:starved` stretch while something records
+    issued = done = begun = 0
+    ran = "start"
+    dry = None
     spec_mirror = {}
     # pipelined admission (`PagedDecoder(pipelined_admission=True)`): the
     # prefills an admission scan has dispatched and not yet read
@@ -180,6 +194,72 @@ def serve_loop(eng, requests, *, max_new_tokens=32, eos_token_id=None,
     # then reserves only, and `join_staged` dispatches the packs
     plan_packs = getattr(eng, "prefill_packs", None) \
         if stage_admissions else None
+
+    def watching():
+        return telemetry or _obs.recording()
+
+    def unready(arr):
+        """Asked just before a blocking read of `arr`, while something
+        records: 1 if the read will have to wait, so that a stretch that
+        begins behind it begins at the program's end to within a
+        wake-up; 0 if the result is there already, so the device went
+        dry earlier and the stretch is a lower reading."""
+        return int(not arr.is_ready()) if watching() else 0
+
+    def starve(after, blocked):
+        """The loop knows the device's queue is empty from here on."""
+        nonlocal dry
+        dry = {"span": _obs.tracing.open_span(
+                   "serve:starved", after=after, blocked=blocked),
+               "t0": time.perf_counter() if telemetry else 0.0,
+               "uploads": eng.h2d_uploads, "admitted": begun}
+
+    def landed(number, blocked):
+        """A blocking read of device call `number`'s result has
+        returned: every call up to it has finished. With nothing queued
+        behind it the device is starved from this instant."""
+        nonlocal done
+        done = max(done, number)
+        if done == issued and dry is None and watching():
+            starve("prefill" if ran == "warm_prefill" else ran, blocked)
+
+    def launched(kind):
+        """A device call of `kind` has returned to the loop: it takes
+        the next number (returned) and ends a starved stretch."""
+        nonlocal issued, ran, dry
+        issued += 1
+        ran = kind
+        if dry is not None:
+            dry["span"].set(before=kind,
+                            uploads=eng.h2d_uploads - dry["uploads"],
+                            admitted=begun - dry["admitted"]).close()
+            if telemetry:
+                secs = time.perf_counter() - dry["t0"]
+                phase["host_gap"] += secs
+                _obs.registry().counter(
+                    "paddle_tpu_serve_device_starved_seconds_total",
+                    "seconds the serve loop knew the device's queue "
+                    "empty, by the kind of device call that ended the "
+                    "stretch", ("before",)).inc(secs, before=kind)
+            dry = None
+        return issued
+
+    def unstarve():
+        """Drop the open stretch: no device call ends it (the loop
+        returns, or sleeps until traffic comes: idle, not starved)."""
+        nonlocal dry
+        if dry is not None:
+            dry["span"].close(keep=False)
+            dry = None
+
+    def idle(seconds):
+        """Nothing live and nothing due: the device is idle for want of
+        traffic (the step ledger's data_wait), not starved by this loop;
+        a fresh stretch begins when the loop looks again."""
+        unstarve()
+        time.sleep(seconds)
+        if watching():
+            starve("start", 0)
 
     def note_uploads(k):
         eng.h2d_uploads += k
@@ -510,18 +590,11 @@ def serve_loop(eng, requests, *, max_new_tokens=32, eos_token_id=None,
             analysed(("chunkst_n", int(n), eos_dev),
                      eng._paged_chunk_state_jit, args)
         t_disp = time.perf_counter()
-        # device-idle attribution: host time between the previous
-        # chunk's results landing and THIS dispatch, net of prefill
-        # device work billed inside the window. A lookahead dispatch
-        # is gap-free by construction (the device never waited).
-        gap = 0.0
-        if telemetry and after_n is None and last_ready[0] is not None:
-            gap = max(0.0, t_disp - last_ready[0] - dev_busy[0])
-        dev_busy[0] = 0.0
         with _obs.span("serve:chunk", steps=int(n),
                        lookahead=int(after_n is not None),
                        uploads=uploads):
             out = eng._paged_chunk_state_jit(*args)
+            number = launched("chunk")
         # the batch state, the pools in their order, then whatever
         # counters the engine's program sends home with the tokens
         toks, bad, tok_o, len_o, live_o, budg_o = out[:6]
@@ -539,7 +612,7 @@ def serve_loop(eng, requests, *, max_new_tokens=32, eos_token_id=None,
         eng._record_traffic(lens_now, n, live, budg)
         return {"toks": toks, "bad": bad, "aux": aux, "n": int(n),
                 "lookahead": after_n is not None, "t_disp": t_disp,
-                "gap": gap,
+                "number": number,
                 "slots": [(i, eng._slots[i])
                           for i in range(eng.max_slots) if live[i]]}
 
@@ -557,7 +630,9 @@ def serve_loop(eng, requests, *, max_new_tokens=32, eos_token_id=None,
         t_w0 = time.perf_counter()
         with _obs.span("serve:wait_chunk", steps=rec["n"]):
             # the loop's wait for the device: the tokens are read here
+            waited = unready(rec["toks"])
             toks = np.asarray(rec["toks"])
+            landed(rec["number"], waited)
             bad = np.asarray(rec["bad"])
             counters = eng.chunk_counters(rec["aux"])
         t_ready = time.perf_counter()
@@ -566,7 +641,6 @@ def serve_loop(eng, requests, *, max_new_tokens=32, eos_token_id=None,
             # wait (results not ready when the host asked); overlapped
             # device time the host never waited on is the win
             phase["execute"] += t_ready - t_w0
-            phase["host_gap"] += rec["gap"]
         # pipelined chunks overlap the previous consume's host work:
         # clamp this chunk's billing interval to start where the last
         # one ended so per-request decode seconds never double-count
@@ -587,8 +661,10 @@ def serve_loop(eng, requests, *, max_new_tokens=32, eos_token_id=None,
                 advance(i, [int(t) for t in toks[i, :take]], ct0,
                         t_ready)
                 took += take
+            # `steps` the device ran for this chunk, `committed` of them
+            # kept (fewer where a look-ahead chunk was trimmed)
             sp.set(tokens=took, retired=live_before - int(live.sum()),
-                   **counters)
+                   steps=rec["n"], committed=int(n_eff), **counters)
         if n_eff < rec["n"]:
             # the device ran the full overshot chunk — its state is
             # ahead of the trimmed mirrors; resync at next dispatch
@@ -624,10 +700,10 @@ def serve_loop(eng, requests, *, max_new_tokens=32, eos_token_id=None,
         with _obs.span("serve:kv_import", blocks=used):
             pools = tuple(eng.import_blocks(
                 *pools, blocks[:used], payload.kv))
+            launched("import")
         t1p = time.perf_counter()
         if telemetry:
             phase["execute"] += t1p - t0p
-            dev_busy[0] += t1p - t0p
         # the import IS this request's prefill segment on this
         # engine; every prompt token arrived cached
         ledger.prefill(req_id, t0p, t1p, bucket=0, cached_tokens=s0)
@@ -652,6 +728,8 @@ def serve_loop(eng, requests, *, max_new_tokens=32, eos_token_id=None,
         scan has dispatched them all, so the device runs the scan's
         prefills back to back and a host that is slow or held up between
         two of them leaves it no gap."""
+        nonlocal begun
+        begun += 1
         if stage_admissions and not isinstance(prompt, KVBlockPayload):
             rec = reserve_prompt(i, req_id, prompt, max_new, t_admit)
             if plan_packs is None:
@@ -675,10 +753,16 @@ def serve_loop(eng, requests, *, max_new_tokens=32, eos_token_id=None,
         dispatched here, back to back, before the first read (one read a
         pack: the others of its admissions find their token on the
         host)."""
-        if plan_packs is not None:
-            for bucket, members in plan_packs([r["s0"] for r in staged]):
-                dispatch_cold(bucket,
-                              [(staged[j], start) for j, start in members])
+        if plan_packs is not None and staged:
+            packs = None        # planned under the first pack's span
+            while packs is None or packs:
+                with _obs.span("serve:prefill_inputs") as sp:
+                    if packs is None:
+                        packs = plan_packs([r["s0"] for r in staged])
+                    bucket, members = packs.pop(0)
+                    members = [(staged[j], start) for j, start in members]
+                    calls = prefill_calls(bucket, members, sp)
+                dispatch_cold(bucket, members, calls)
         for rec in staged:
             slot = rec["slot"]
             with _obs.span("serve:admit", rid=slot.req_id, slot=rec["i"],
@@ -691,58 +775,67 @@ def serve_loop(eng, requests, *, max_new_tokens=32, eos_token_id=None,
         the slot, its blocks and table row, the ledger. Returns the
         record that `dispatch_prefill` fills in and `join_prompt`
         reads."""
-        mark_state_dirty()
-        prompt = list(map(int, prompt))
-        # chunked-prefill replay: a previously evicted incarnation
-        # re-enters with its retained tokens appended to the
-        # prompt — ONE prefill recomputes the whole KV prefix into
-        # fresh pages and its argmax IS the next token of the
-        # stream (greedy replay is token-identical to the
-        # uninterrupted serve; the chaos drill's parity anchor)
-        prefix = replays.prefix(req_id)
-        ids_full = prompt + prefix
-        s0 = len(ids_full)
-        total = len(prompt) + max_new
-        if total > eng.max_len:
-            raise ValueError(f"{total} tokens exceed max_len "
-                             f"{eng.max_len}")
-        # prefix-cache admission plan: which cached blocks to map
-        # copy-on-write, and whether the boundary block needs a device
-        # fork (fully-cached prompt). Planned BEFORE the alloc so the
-        # fresh-block bill excludes the shared span.
-        m, kb, cached, cow_src = cache_sync(plan_prefix, cache,
-                                            ids_full, s0)
-        # allocate pages for the whole run up front (admission is
-        # the backpressure point; a growth-on-demand variant would
-        # allocate per chunk). Fresh blocks first — alloc can fault
-        # (chaos) — then the infallible shared-block acquire.
-        fresh = eng.allocator.alloc(blocks_needed(total) - kb)
-        shared = cache_sync(cache.acquire, m, kb) if kb else []
-        blocks = shared + fresh
-        slot = _Slot(req_id=req_id, length=s0, blocks=blocks,
-                     prompt=prompt, budget=max_new - len(prefix))
-        slot.emitted = list(prefix)
-        eng._slots[i] = slot
-        row = np.zeros(MB, np.int32)
-        row[:len(blocks)] = blocks
-        tables[i] = row
-        ledger.admit(req_id, slot=i, blocks=len(blocks), ts=t_admit)
-        # chaos site: prefill execution failure — fires BEFORE the
-        # device call (pools untouched, donation not yet consumed),
-        # the window where recovery is clean unwind + replay
-        _faults.inject("prefill_chunk")
-        return {"i": i, "slot": slot, "s0": s0, "ids": ids_full,
-                "cached": cached, "kb": kb, "cow_src": cow_src,
-                "fresh": fresh, "seg": 0}
+        with _obs.span("serve:reserve", rid=req_id) as sp:
+            mark_state_dirty()
+            prompt = list(map(int, prompt))
+            # chunked-prefill replay: a previously evicted incarnation
+            # re-enters with its retained tokens appended to the
+            # prompt — ONE prefill recomputes the whole KV prefix into
+            # fresh pages and its argmax IS the next token of the
+            # stream (greedy replay is token-identical to the
+            # uninterrupted serve; the chaos drill's parity anchor)
+            prefix = replays.prefix(req_id)
+            ids_full = prompt + prefix
+            s0 = len(ids_full)
+            total = len(prompt) + max_new
+            if total > eng.max_len:
+                raise ValueError(f"{total} tokens exceed max_len "
+                                 f"{eng.max_len}")
+            # prefix-cache admission plan: which cached blocks to map
+            # copy-on-write, and whether the boundary block needs a device
+            # fork (fully-cached prompt). Planned BEFORE the alloc so the
+            # fresh-block bill excludes the shared span.
+            m, kb, cached, cow_src = cache_sync(plan_prefix, cache,
+                                                ids_full, s0)
+            # allocate pages for the whole run up front (admission is
+            # the backpressure point; a growth-on-demand variant would
+            # allocate per chunk). Fresh blocks first — alloc can fault
+            # (chaos) — then the infallible shared-block acquire.
+            fresh = eng.allocator.alloc(blocks_needed(total) - kb)
+            shared = cache_sync(cache.acquire, m, kb) if kb else []
+            blocks = shared + fresh
+            slot = _Slot(req_id=req_id, length=s0, blocks=blocks,
+                         prompt=prompt, budget=max_new - len(prefix))
+            slot.emitted = list(prefix)
+            eng._slots[i] = slot
+            row = np.zeros(MB, np.int32)
+            row[:len(blocks)] = blocks
+            tables[i] = row
+            ledger.admit(req_id, slot=i, blocks=len(blocks), ts=t_admit)
+            sp.set(blocks=len(blocks))
+            # chaos site: prefill execution failure — fires BEFORE the
+            # device call (pools untouched, donation not yet consumed),
+            # the window where recovery is clean unwind + replay
+            _faults.inject("prefill_chunk")
+            return {"i": i, "slot": slot, "s0": s0, "ids": ids_full,
+                    "cached": cached, "kb": kb, "cow_src": cow_src,
+                    "fresh": fresh, "seg": 0}
 
-    def dispatch_cold(bucket, members):
+    def prefill_calls(bucket, members, sp):
+        """The inputs of the bucket's program calls for `members`
+        [(record, start row)], made and uploaded under the open
+        `serve:prefill_inputs` span `sp`."""
+        calls = eng._prefill_calls(
+            bucket, [(r["i"], r["ids"], start) for r, start in members],
+            tables, pad_token_id)
+        sp.set(bucket=bucket, prompts=len(members), calls=len(calls))
+        return calls
+
+    def dispatch_cold(bucket, members, calls):
         """One bucketed in-prompt prefill program over `members`
         [(record, start row)]: one prompt from row 0, or a pack on an
         engine whose program takes one."""
         nonlocal pools
-        calls = eng._prefill_calls(
-            bucket, [(r["i"], r["ids"], start) for r, start in members],
-            tables, pad_token_id)
         fn = eng._prefill_exec(bucket)
         args_of = lambda call: (eng._params,) + call[0] + pools + call[1]
         c0 = analysed(("prefill_b", bucket), fn, args_of(calls[0])) \
@@ -756,6 +849,7 @@ def serve_loop(eng, requests, *, max_new_tokens=32, eos_token_id=None,
             # pools the one before it returned
             for call in calls:
                 enc, *out = fn(*args_of(call))
+                number = launched("prefill")
                 pools = tuple(out)
                 encs.append(enc)
         if telemetry:
@@ -763,7 +857,8 @@ def serve_loop(eng, requests, *, max_new_tokens=32, eos_token_id=None,
         eng.prefill_device_calls += len(calls)
         eng.prefill_tokens_computed += rows
         for seg, (r, _) in enumerate(members):
-            r.update(enc=encs, seg=seg, t0p=t0p, bucket=bucket)
+            r.update(enc=encs, seg=seg, t0p=t0p, bucket=bucket,
+                     number=number)
 
     def dispatch_prefill(rec):
         """Dispatch one reserved prompt's prefill by itself."""
@@ -772,7 +867,10 @@ def serve_loop(eng, requests, *, max_new_tokens=32, eos_token_id=None,
         if cache is None:
             # historical cold path: bucketed in-prompt prefill —
             # cache-off engines keep their executables byte-identical
-            dispatch_cold(eng.prefill_bucket(s0), [(rec, 0)])
+            bucket = eng.prefill_bucket(s0)
+            with _obs.span("serve:prefill_inputs") as sp:
+                calls = prefill_calls(bucket, [(rec, 0)], sp)
+            dispatch_cold(bucket, [(rec, 0)], calls)
         else:
             # warm path: every cache-on prefill — hit or miss — runs
             # the pool-mapped suffix executable (cold is just
@@ -801,17 +899,21 @@ def serve_loop(eng, requests, *, max_new_tokens=32, eos_token_id=None,
             for off, piece in pieces:
                 npiece = len(piece)
                 bucket = eng.prefill_bucket(npiece)
-                ids = np.full(bucket, pad_token_id, np.int32)
-                ids[:npiece] = piece
-                args_w = (eng._params, jnp.asarray(ids),
-                          jnp.int32(cached + off), jnp.int32(npiece),
-                          jnp.asarray(tables[i])) + pools
+                with _obs.span("serve:prefill_inputs", bucket=bucket,
+                               prompts=1, calls=1):
+                    ids = np.full(bucket, pad_token_id, np.int32)
+                    ids[:npiece] = piece
+                    args_w = (eng._params, jnp.asarray(ids),
+                              jnp.int32(cached + off), jnp.int32(npiece),
+                              jnp.asarray(tables[i])) + pools
                 fn = eng._warmfill_exec(bucket)
                 c0 = analysed(("warmfill_b", bucket), fn, args_w) \
                     if telemetry else 0.0
                 if off == 0:
                     t0p = time.perf_counter()
-                    if cow_src is not None:
+                with _obs.span("serve:warm_prefill", bucket=bucket,
+                               cached=cached + off):
+                    if off == 0 and cow_src is not None:
                         # fully-cached prompt: fork the boundary block
                         # before the one-token suffix recompute writes
                         # into it (timed inside the prefill window —
@@ -819,12 +921,12 @@ def serve_loop(eng, requests, *, max_new_tokens=32, eos_token_id=None,
                         pools = tuple(eng._cow_copy_jit(
                             *pools, jnp.int32(cow_src),
                             jnp.int32(fresh[0])))
+                        launched("warm_prefill")
                         # rebuild args against the post-COW pools (the
                         # copy donated the ones args_w captured)
                         args_w = args_w[:5] + pools
-                with _obs.span("serve:warm_prefill", bucket=bucket,
-                               cached=cached + off):
                     enc, *out = fn(*args_w)
+                    number = launched("warm_prefill")
                 if telemetry:
                     phase["execute"] -= compiled() - c0
                 pools = tuple(out)
@@ -834,7 +936,7 @@ def serve_loop(eng, requests, *, max_new_tokens=32, eos_token_id=None,
             eng.prefill_tokens_computed += ns
             cache.record_admission(cached, rec["kb"],
                                    cow=cow_src is not None)
-            rec.update(enc=[enc], t0p=t0p, bucket=bucket)
+            rec.update(enc=[enc], t0p=t0p, bucket=bucket, number=number)
 
     def join_prompt(rec, sp):
         """Read the first token of a dispatched prefill and let the
@@ -848,8 +950,10 @@ def serve_loop(eng, requests, *, max_new_tokens=32, eos_token_id=None,
         # waits for the prefill (a pack's tokens come in one array, read
         # once)
         with _obs.span("serve:wait_first_token", rid=req_id):
+            waited = unready(rec["enc"][-1])
             first, nonfinite = eng.decode_first_token(rec["enc"],
                                                       rec["seg"])
+            landed(rec["number"], waited)
         bad_prefill = quarantine_on and nonfinite
         t1p = time.perf_counter()
         if telemetry:
@@ -857,7 +961,6 @@ def serve_loop(eng, requests, *, max_new_tokens=32, eos_token_id=None,
             # billed from where the one before it was read
             since = max(t0p, joined[0])
             phase["execute"] += t1p - since
-            dev_busy[0] += t1p - since
         joined[0] = t1p
         ledger.prefill(req_id, t0p, t1p, bucket=bucket,
                        cached_tokens=cached)
@@ -904,14 +1007,19 @@ def serve_loop(eng, requests, *, max_new_tokens=32, eos_token_id=None,
     feeding = (lambda: False) if feed_active is None else feed_active
 
     try:
+        if watching():
+            starve("start", 0)
         if plan_packs is not None:
             # a pack's bucket follows from what a scan happens to stage:
             # every bucket's program exists before the first admission
             with _obs.span("serve:warm_packs"):
-                pools = eng.warm_prefill(pools, pad_token_id)
+                warmed = eng.warm_prefill(pools, pad_token_id)
+                if warmed[0] is not pools[0]:    # a bucket's program ran
+                    launched("warm_prefill")
+                pools = warmed
         while queue or live.any() or feeding():
             with _obs.span("serve:iteration", live=int(live.sum()),
-                           queued=len(queue)):
+                           queued=len(queue)) as it_sp:
                 it0 = time.perf_counter() if telemetry else 0.0
                 compiled0 = compiled() if telemetry else 0.0
                 phase["execute"] = phase["host_gap"] = 0.0
@@ -947,13 +1055,17 @@ def serve_loop(eng, requests, *, max_new_tokens=32, eos_token_id=None,
                         except Exception:
                             pass
                 # admission: fill free slots while blocks allow
+                # (`admit_stop`: why the scan ended)
                 deferred_scan = False
+                admit_stop = "full"
                 for i in range(eng.max_slots):
                     shed_heads(now)
                     if not queue:
+                        admit_stop = "queue_empty"
                         break
                     rid, prompt, mnt, arr = queue.head()
                     if t_start + arr > now:
+                        admit_stop = "not_due"
                         break                # next arrival is in the future
                     if not eng._slots[i].done:
                         continue
@@ -966,6 +1078,7 @@ def serve_loop(eng, requests, *, max_new_tokens=32, eos_token_id=None,
                             cache_sync(cache.evict,
                                        need - eng.allocator.free_count)
                         if need > eng.allocator.free_count:
+                            admit_stop = "no_blocks"
                             break            # backpressure: decode first
                     # the pool itself is preallocated — admitting consumes no
                     # pool HBM. What admission DOES allocate is transient: the
@@ -981,6 +1094,7 @@ def serve_loop(eng, requests, *, max_new_tokens=32, eos_token_id=None,
                             and not eng.headroom_guard.check(prefill_est)):
                         eng.admission_deferrals += 1
                         deferred_scan = True
+                        admit_stop = "deferred"
                         defer_counts[rid] = defer_counts.get(rid, 0) + 1
                         ledger.defer(rid)
                         if _obs.enabled():
@@ -1039,19 +1153,14 @@ def serve_loop(eng, requests, *, max_new_tokens=32, eos_token_id=None,
                             requeue(rid, plain, mnt, replays.prefix(rid),
                                     t_fail, admitted=False)
                 join_staged()
+                it_sp.set(free=eng.max_slots - int(live.sum()),
+                          admit_stop=admit_stop)
                 if not live.any():
-                    # an empty batch ends the pipelined stream: whatever
-                    # happens next (idle sleep, admission scan) the next
-                    # dispatch opens a fresh device-idle window — a gap
-                    # measured across the break would bill queue idle
-                    # (data_wait by the step ledger's clock) as host_gap
-                    last_ready[0] = None
-                    dev_busy[0] = 0.0
                     if not queue:
                         if feeding():
                             # disaggregation: prefill workers still
                             # running — idle until a payload lands
-                            time.sleep(0.002)
+                            idle(0.002)
                             continue
                         break
                     if deferred_scan:
@@ -1067,7 +1176,7 @@ def serve_loop(eng, requests, *, max_new_tokens=32, eos_token_id=None,
                         # open-loop idle: nothing live, next arrival in the
                         # future — sleep to it (the serve ledger bills the
                         # gap as data_wait, which it is)
-                        time.sleep(next_arrival - fresh)
+                        idle(next_arrival - fresh)
                         continue
                     if next_arrival > now:
                         # the head arrived BETWEEN the admission scan's
@@ -1142,19 +1251,17 @@ def serve_loop(eng, requests, *, max_new_tokens=32, eos_token_id=None,
                     c0 = analysed(("spec_k", int(K)), eng._spec_verify_jit,
                                   args_s) if telemetry else 0.0
                     t0c = time.perf_counter()
-                    if telemetry:
-                        if last_ready[0] is not None:
-                            phase["host_gap"] += max(
-                                0.0, t0c - last_ready[0] - dev_busy[0])
-                        dev_busy[0] = 0.0
                     with _obs.span("serve:spec_verify", k=int(K)):
                         g, bad, *out = eng._spec_verify_jit(*args_s)
+                        number = launched("verify")
                         pools = tuple(out)
                     if telemetry:
                         phase["execute"] -= compiled() - c0
                     with _obs.span("serve:wait_chunk", steps=int(K + 1)):
                         # the pass's results reach the host here
+                        waited = unready(g)
                         g = np.asarray(g)
+                        landed(number, waited)
                         bad = np.asarray(bad)
                     t1c = time.perf_counter()
                     if telemetry:
@@ -1192,7 +1299,8 @@ def serve_loop(eng, requests, *, max_new_tokens=32, eos_token_id=None,
                             took += len(emit)
                             advance(i, emit, t0c, t1c)
                         sp.set(tokens=took,
-                               retired=live_before - int(live.sum()))
+                               retired=live_before - int(live.sum()),
+                               steps=int(K + 1), committed=int(K + 1))
                     st["proposed"] += call_prop
                     st["accepted"] += call_acc
                     if telemetry:
@@ -1250,12 +1358,14 @@ def serve_loop(eng, requests, *, max_new_tokens=32, eos_token_id=None,
         # the engine may be unusable, but the OBSERVABILITY
         # must stay truthful: drop this call's unfinished
         # ledger records before propagating
+        unstarve()
         abort_cleanup()
         if cache is not None:
             # donation may have consumed the persistent pools
             # mid-call — the cached KV is gone with them
             eng.release_pools()
         raise
+    unstarve()
     if cache is not None:
         # the loop's final pool bindings ARE the persistent pools now
         # (every device call rebound them through donation)

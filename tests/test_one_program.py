@@ -35,6 +35,7 @@ def plain():
     obs.registry().reset()
     memory_profile.reset()
     roofline.reset()
+    tracing.clear()      # whatever an earlier file's tests left in the ring
     yield
     obs.disable()
     obs.set_jsonl_path(None)

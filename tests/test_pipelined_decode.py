@@ -217,6 +217,86 @@ class TestLedger:
         assert depth and sum(depth["values"].values()) >= 1
 
 
+    @pytest.mark.parametrize("pipeline", [False, None],
+                             ids=["serial", "lookahead"])
+    def test_host_gap_is_the_starved_seconds_that_ended_in_the_iteration(
+            self, model, tmp_path, monkeypatch, pipeline):
+        """ISSUE 39: the ledger's `host_gap` has one source, the loop's
+        `serve:starved` stretches. On a clock that moves a microsecond a
+        read, shared by the loop and the tracer, an iteration's bucket
+        is the sum of the stretches that ended in it (the ledger holds
+        it to the iteration's own unmeasured time), and the registry's
+        counter is their sum by `before`."""
+        import time
+        import types
+        from paddle_tpu.observability import tracing
+        from paddle_tpu.serving import batcher
+        # every program compiled before the clock is swapped: a compile's
+        # seconds are the listener's own, on the real clock
+        dec = _dec(model)
+        dec.serve(_reqs(), chunk=4, pipeline=pipeline)
+        now = [100.0]
+
+        def tick():
+            now[0] += 1e-6
+            return now[0]
+        fake = types.SimpleNamespace(
+            perf_counter=tick, perf_counter_ns=lambda: round(tick() * 1e9),
+            time_ns=time.time_ns, sleep=lambda s: None)
+        monkeypatch.setattr(batcher, "time", fake)
+        monkeypatch.setattr(tracing, "time", fake)
+        obs.registry().reset()
+        obs.enable()
+        path = str(tmp_path / "steps.jsonl")
+        obs.set_jsonl_path(path)
+        tracing.clear()
+        tracing.enable_tracing()
+        try:
+            dec.serve(_reqs(), chunk=4, pipeline=pipeline)
+        finally:
+            tracing.disable_tracing()
+            obs.set_jsonl_path(None)
+        spans = tracing.tail()
+        tracing.clear()
+        recs = [json.loads(l) for l in open(path)]
+        recs = [r["attribution"] for r in recs
+                if r.get("event") == "step_attribution"
+                and r.get("source") == "serve"]
+        its = [s for s in spans if s["name"] == "serve:iteration"]
+        starved = [s for s in spans if s["name"] == "serve:starved"]
+        assert len(recs) == len(its) and starved
+        end = lambda s: s["t0_ns"] + s["dur_ns"]
+        slack = 5e-6        # the loop's reads lie a few ticks off the span's
+        ended = []
+        for a, it in zip(recs, its):
+            mine = [s for s in starved
+                    if it["t0_ns"] <= end(s) <= end(it)]
+            secs = sum(s["dur_ns"] for s in mine) * 1e-9
+            ended.append(len(mine))
+            assert a["host_gap"] == pytest.approx(
+                min(secs, a["host_gap"] + a["dispatch"]),
+                abs=slack * max(len(mine), 1))
+        assert sum(ended) == len(starved)
+        assert sum(a["host_gap"] for a in recs) > 0
+        if pipeline is None:
+            # look-ahead: nothing is starved once the chunks follow
+            # each other
+            assert not any(ended[1:])
+            assert all(a["host_gap"] == 0 for a in recs[1:])
+        else:
+            assert all(ended)
+        counted = obs.dump()[
+            "paddle_tpu_serve_device_starved_seconds_total"]["values"]
+        by_before = {}
+        for s in starved:
+            k = s["meta"]["before"]
+            by_before[k] = by_before.get(k, 0.0) + s["dur_ns"] * 1e-9
+        assert set(counted) == set(by_before)
+        for k, secs in by_before.items():
+            assert counted[k] == pytest.approx(secs,
+                                               abs=slack * len(starved))
+
+
 class TestTeeth:
     def test_force_sync_disables_lookahead(self, model, serial,
                                            monkeypatch):

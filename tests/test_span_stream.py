@@ -21,7 +21,8 @@ from paddle_tpu.observability import tracing
 
 SERVE_SPANS = ("serve:iteration", "serve:feed", "serve:admit",
                "serve:prefill", "serve:wait_first_token", "serve:chunk",
-               "serve:wait_chunk", "serve:commit")
+               "serve:wait_chunk", "serve:commit", "serve:starved",
+               "serve:reserve", "serve:prefill_inputs")
 
 
 @pytest.fixture
@@ -399,6 +400,197 @@ def test_profiler_leaves_a_ring_someone_else_armed(armed):
     prof.start()
     prof.stop()
     assert tracing.tracing_enabled()
+
+
+# -- the device's queue, as the loop knows it (ISSUE 39) ----------------------
+def _end(s):
+    return s["t0_ns"] + s["dur_ns"]
+
+
+def _uniform(n, budget):
+    rng = np.random.default_rng(11)
+    return [(i, [int(t) for t in rng.integers(0, 97, 6)], budget)
+            for i in range(n)]
+
+
+@pytest.mark.parametrize("staged", [False, True],
+                         ids=["one_at_a_time", "pipelined_admission"])
+def test_starved_lies_across_the_loops_spans(armed, staged):
+    dec = _tiny_decoder(pipelined_admission=staged)
+    out = _serve(dec)
+    spans = tracing.tail()
+    by = _by_name(spans)
+    ids = {s["id"]: s for s in spans}
+    starved = sorted(by["serve:starved"], key=lambda s: s["t0_ns"])
+    # no parent, nobody's parent, one at a time
+    assert all(s["parent"] is None for s in starved)
+    assert not {s["id"] for s in starved} & {s["parent"] for s in spans}
+    assert all(_end(a) <= b["t0_ns"] for a, b in zip(starved, starved[1:]))
+    # each ends inside the dispatch that ended it, and names it
+    enders = [d for name in ("serve:chunk", "serve:prefill",
+                             "serve:warm_prefill") for d in by.get(name, [])]
+    for s in starved:
+        inside, = [d for d in enders
+                   if d["t0_ns"] <= _end(s) <= _end(d)]
+        assert "serve:" + s["meta"]["before"] == inside["name"]
+        assert s["meta"]["after"] in ("start", "chunk", "prefill")
+        assert s["meta"]["blocked"] in (0, 1)
+        assert {"uploads", "admitted"} <= set(s["meta"])
+    assert starved[0]["meta"]["after"] == "start"
+    assert {s["meta"]["before"] for s in starved} == {"chunk", "prefill"}
+    # a stretch a chunk ended saw that chunk's state go up, whole
+    assert {s["meta"]["uploads"] for s in starved
+            if s["meta"]["before"] == "chunk"} == {6}
+    # an admission's host work, once an admission, where the existing
+    # tree allows it
+    where = "serve:iteration" if staged else "serve:admit"
+    for name in ("serve:reserve", "serve:prefill_inputs"):
+        assert len(by[name]) == len(by["serve:admit"]) == len(out)
+        assert {ids[s["parent"]]["name"] for s in by[name]} == {where}
+    assert {s["meta"]["rid"] for s in by["serve:reserve"]} == set(out)
+    assert all(s["meta"]["blocks"] >= 1 for s in by["serve:reserve"])
+    assert all(s["meta"]["calls"] == s["meta"]["prompts"] == 1
+               for s in by["serve:prefill_inputs"])
+    # the scan's end, on every iteration
+    assert all(s["meta"]["admit_stop"] in ("full", "queue_empty")
+               and 0 <= s["meta"]["free"] <= 2
+               for s in by["serve:iteration"])
+
+
+def test_no_starved_stretch_between_chunks_under_lookahead(armed):
+    """A full batch and nothing to admit: chunk N + 1 is queued before
+    chunk N is read, so the loop never knows the device's queue empty
+    between two chunks."""
+    dec = _tiny_decoder()
+    dec.serve(_uniform(2, 9), max_new_tokens=9, chunk=2)
+    by = _by_name(tracing.tail())
+    assert dec.lookahead_dispatches >= 3
+    first_chunk = min(s["t0_ns"] for s in by["serve:chunk"])
+    assert [s["meta"]["before"] for s in by["serve:starved"]] \
+        == ["prefill", "prefill", "chunk"]
+    assert all(s["t0_ns"] < first_chunk for s in by["serve:starved"])
+    # without look-ahead every chunk but the first is waited for dry
+    tracing.clear()
+    dec = _tiny_decoder()
+    dec.serve(_uniform(2, 9), max_new_tokens=9, chunk=2, pipeline=False)
+    between = [s for s in _by_name(tracing.tail())["serve:starved"]
+               if s["meta"]["after"] == "chunk"]
+    assert len(between) == dec.chunk_dispatches - 1
+    assert all(s["meta"]["before"] == "chunk" and s["meta"]["uploads"] == 0
+               for s in between)
+
+
+@pytest.mark.parametrize("requests,share", [(2, 1.0), (1, 0.5)],
+                         ids=["full_batch", "one_of_two_slots"])
+def test_commit_counts_the_rows_the_device_ran(armed, requests, share):
+    """`tokens` over `steps` x slots: 1.0 for a batch that is full
+    throughout, k / slots with k live (`serve_loop.live_slot_share`)."""
+    dec = _tiny_decoder()
+    dec.serve(_uniform(requests, 9), max_new_tokens=9, chunk=2)
+    commits = _by_name(tracing.tail())["serve:commit"]
+    assert all(s["meta"]["committed"] == s["meta"]["steps"]
+               for s in commits)
+    tokens = sum(s["meta"]["tokens"] for s in commits)
+    steps = sum(s["meta"]["steps"] for s in commits)
+    assert tokens == requests * 8 and steps == 8
+    assert tokens / (steps * dec.max_slots) == share
+
+
+def test_a_trimmed_lookahead_chunk_commits_fewer_steps(armed):
+    """An eos retires the slot with the largest budget while the chunk
+    sized by it is in flight: the device ran `steps`, `committed` of
+    them count."""
+    reqs = [(0, [5, 6, 7, 8], 7), (1, [9, 10, 11], 12)]
+    plain = _tiny_decoder().serve(reqs, max_new_tokens=12, chunk=4)
+    # a token of the first chunk that request 1 alone emits, once
+    eos = next(t for k, t in enumerate(plain[1][1:5], 1)
+               if t not in plain[1][:k] and t not in plain[0])
+    tracing.clear()
+    dec = _tiny_decoder()
+    dec.serve(reqs, max_new_tokens=12, chunk=4, eos_token_id=eos)
+    assert dec.lookahead_dispatches >= 1
+    commits = _by_name(tracing.tail())["serve:commit"]
+    assert all(s["meta"]["committed"] <= s["meta"]["steps"]
+               for s in commits)
+    assert any(s["meta"]["committed"] < s["meta"]["steps"]
+               for s in commits)
+
+
+def test_the_queue_account_reads_nothing_when_nothing_records(
+        quiet, monkeypatch):
+    """`test_ledger_cost_per_iteration_is_small`'s way of counting, on
+    the account of the device's queue: with nothing recording it asks no
+    array whether it is ready and reads no clock; with the ring armed it
+    asks before every blocking read and still reads no clock of its own
+    (the spans do)."""
+    import sys
+    import types
+    from paddle_tpu.serving import batcher
+    account = {"watching", "unready", "starve", "landed", "launched",
+               "unstarve", "idle"}
+    asked, clock = [0], [0]
+    array = type(jax.numpy.zeros(1))
+    is_ready = array.is_ready
+
+    def counted_is_ready(self):
+        asked[0] += 1
+        return is_ready(self)
+
+    def perf_counter():
+        clock[0] += sys._getframe(1).f_code.co_name in account
+        return time.perf_counter()
+
+    monkeypatch.setattr(array, "is_ready", counted_is_ready)
+    monkeypatch.setattr(batcher, "time", types.SimpleNamespace(
+        perf_counter=perf_counter, sleep=time.sleep))
+    dec = _tiny_decoder()
+    out = _serve(dec)
+    assert (asked[0], clock[0]) == (0, 0)
+    assert tracing.tail() == []
+    tracing.enable_tracing()
+    dec = _tiny_decoder()
+    assert _serve(dec) == out
+    tracing.disable_tracing()
+    by = _by_name(tracing.tail())
+    reads = len(by["serve:wait_chunk"]) + len(by["serve:wait_first_token"])
+    assert asked[0] == reads and clock[0] == 0
+    assert by["serve:starved"]
+
+
+def test_open_span_takes_no_parent_and_is_none(armed):
+    with tracing.span("outer") as outer:
+        across = tracing.open_span("across", k=1)
+        with tracing.span("inner") as inner:
+            assert inner.parent == outer.id
+    with tracing.span("later") as later:
+        across.set(done=2).close()
+        assert later.parent is None
+    dropped = tracing.open_span("never")
+    dropped.close(keep=False)
+    by = _by_name(tracing.tail())
+    assert "never" not in by
+    got, = by["across"]
+    assert got["parent"] is None and got["meta"] == {"k": 1, "done": 2}
+    assert got["t0_ns"] > by["outer"][0]["t0_ns"]
+    assert _end(got) > _end(by["inner"][0])
+    tracing.disable_tracing()
+    assert tracing.open_span("off") is tracing._NULL
+    tracing._NULL.set(a=1).close()
+
+
+def test_starved_lies_in_a_profiler_sessions_trace(quiet, tmp_path):
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        _serve(_tiny_decoder())
+    finally:
+        jax.profiler.stop_trace()
+    ring = _by_name(tracing.tail())["serve:starved"]
+    host = _host_events(str(tmp_path))["serve:starved"]
+    kept = [e for e in host if "dropped" not in e]
+    assert len(kept) == len(ring)
+    assert sorted(e["before"] for e in kept) \
+        == sorted(s["meta"]["before"] for s in ring)
+    assert {"after", "blocked", "uploads", "admitted"} <= set(kept[0])
 
 
 # -- what the always-on ledger costs ------------------------------------------

@@ -78,13 +78,16 @@ DIRECTIONS = {
     "llama_spec_decode.accept_rate": "higher",
     "train_step_telemetry.checkpoint_async_exposed_s": "lower",
     "train_step_telemetry.recompiles": "lower",
-    # zero-sync pipelined decode (ISSUE 20): device idle between chunks
-    # and host->device batch-state uploads per chunk — a pipelined
-    # steady state drives both toward zero, and neither spelling is
-    # covered by the suffix heuristics
-    "serving_load_telemetry.host_gap_frac": "lower",
+    # the serve loop's hand-overs: the share of the wall in which the
+    # loop knew the device's queue empty (the step ledger's host_gap,
+    # fed by `serve:starved` since ISSUE 39: admissions count, which
+    # the `host_gap_frac` of older rows, an estimate between chunks,
+    # left out; that name is record-only now) and host->device
+    # batch-state uploads per chunk; neither spelling is covered by the
+    # suffix heuristics
+    "serving_load_telemetry.starved_frac": "lower",
     "serving_load_telemetry.h2d_uploads_per_chunk": "lower",
-    "llama_paged_request_latency.host_gap_frac": "lower",
+    "llama_paged_request_latency.starved_frac": "lower",
     "llama_paged_request_latency.h2d_uploads_per_chunk": "lower",
 }
 # metrics whose rolling best can legitimately sit at 0.0 (a pipelined
@@ -92,8 +95,8 @@ DIRECTIONS = {
 # around a zero best flags ANY nonzero jitter as a regression, so
 # these carry a small absolute slack on top of the tolerance band
 ABS_SLACK = {
-    "serving_load_telemetry.host_gap_frac": 0.01,
-    "llama_paged_request_latency.host_gap_frac": 0.01,
+    "serving_load_telemetry.starved_frac": 0.01,
+    "llama_paged_request_latency.starved_frac": 0.01,
 }
 _HIGHER_SUFFIXES = ("tokens_per_sec", "tokens_per_sec_per_chip",
                     "goodput_tokens_per_sec", "imgs_per_sec",
