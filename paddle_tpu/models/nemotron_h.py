@@ -22,6 +22,7 @@ chip there is no exchange and nothing stands in for the absent chips.
 """
 from __future__ import annotations
 
+import functools
 import math
 
 import numpy as np
@@ -465,32 +466,78 @@ def pair_counts(n_here, sizes, rows, k):
                       jnp.max(sizes)])
 
 
+# rows of `grouped_matmul_sorted`'s kernel tile: a sorted buffer is
+# whole tiles
+ROW_TILE = 128
+
+
+def buffer_rows(cfg, pairs):
+    """Rows of the sorted buffer an expert layer of `pairs` token-expert
+    pairs (T x k) works on in one pass: 1.5 times the share of the
+    router this chip holds (`experts_held` over `n_routed_experts`), in
+    whole row tiles, and at most every pair. Where the pairs computed
+    here do not fit, the layer runs another pass: no routing can lose a
+    pair."""
+    share = cfg.experts_held[1] / cfg.n_routed_experts
+    return min(pairs,
+               -(-math.ceil(1.5 * share * pairs) // ROW_TILE) * ROW_TILE)
+
+
+@functools.partial(jax.jit, static_argnames="cap")
+def _experts_pass(r, v, order, sizes, weights, w1, w2, start, cap):
+    """Adds into r [T, latent] float32 the held experts' part of the
+    routed sum for the pairs at sorted places start .. start + cap of
+    `order` (padded to whole buffers), each row into its token's.
+    Jitted with the weights as operands: the expert blocks of a program
+    share one trace of it (a Pallas body's trace is paid at every start
+    of a process), and the scope is opened inside, since a jitted
+    function names the instructions in it."""
+    from ..kernels.pallas.grouped_matmul import grouped_matmul_sorted
+    k = weights.shape[1]
+    here = jax.lax.dynamic_slice_in_dim(order, start, cap)
+    tok = here // k
+    # each held expert's rows inside this buffer
+    ends = jnp.cumsum(sizes, dtype=jnp.int32) - start
+    part = jnp.clip(ends, 0, cap) - jnp.clip(ends - sizes, 0, cap)
+    with jax.named_scope("moe.experts"):
+        # in bounds by construction: "clip" spares the select over the
+        # buffer that "fill" would add
+        xs = jnp.take(v, tok, axis=0, mode="clip")
+        h = grouped_matmul_sorted(xs, w1, part, row_tile=ROW_TILE)
+        y = grouped_matmul_sorted(relu2(h).astype(v.dtype), w2, part,
+                                  row_tile=ROW_TILE)
+    # rows past the held pairs were never written: select, do not scale
+    wy = jnp.where((jnp.arange(cap, dtype=jnp.int32)
+                    < jnp.sum(part, dtype=jnp.int32))[:, None],
+                   y * jnp.take(weights.reshape(-1), here)[:, None], 0.0)
+    return r.at[tok].add(wy)
+
+
 def moe_experts(cfg, p, v, idx, weights, active=None):
     """The held experts' part of the routed sum: `sum over chosen k held
     here of w_k relu(v W1_k)^2 W2_k` for v [T, latent]. The token-expert
     pairs are sorted by held expert (pairs of experts held elsewhere,
     and of rows that are not `active`, go last and are not computed) and
     the two products run grouped over exactly the rows each expert got:
-    no capacity, no dropped pair. Returns (r [T, latent] float32, counts
-    int32 [4]: pairs computed here, pairs routed anywhere, held experts
-    that got a row, the most rows one expert got; `merge_counts` adds
-    them up)."""
-    from ..kernels.pallas.grouped_matmul import grouped_matmul_sorted
+    no capacity, no dropped pair. They run in buffers of `buffer_rows`,
+    as many passes as the pairs computed here take (counted on the
+    device; none where there are none), each row added into its token's.
+    Returns (r [T, latent] float32, counts int32 [4]: pairs computed
+    here, pairs routed anywhere, held experts that got a row, the most
+    rows one expert got (`merge_counts` adds them up), the rows of the
+    buffers int32)."""
     t, k = idx.shape
     order, sizes, rows = sort_pairs(cfg, idx, active)
-    with jax.named_scope("moe.experts"):
-        xs = jnp.take(v, order // k, axis=0)
-        h = grouped_matmul_sorted(xs, p["w1"], sizes)
-        y = grouped_matmul_sorted(relu2(h).astype(v.dtype), p["w2"], sizes)
     n_here = jnp.sum(sizes, dtype=jnp.int32)
-    w_sorted = jnp.take(weights.reshape(-1), order)
-    # rows past the held pairs were never written: select, do not scale
-    wy = jnp.where((jnp.arange(t * k, dtype=jnp.int32) < n_here)[:, None],
-                   y.astype(F32) * w_sorted[:, None], 0.0)
-    back = jnp.zeros((t * k,), jnp.int32).at[order].set(
-        jnp.arange(t * k, dtype=jnp.int32))
-    r = jnp.sum(jnp.take(wy, back, axis=0).reshape(t, k, -1), axis=1)
-    return r, pair_counts(n_here, sizes, rows, k)
+    cap = buffer_rows(cfg, t * k)
+    order = jnp.pad(order, (0, -(t * k) % cap))
+    passes = (n_here + cap - 1) // cap
+    r = jax.lax.fori_loop(
+        0, passes, lambda i, r: _experts_pass(
+            r, v, order, sizes, weights, p["w1"], p["w2"], i * cap,
+            cap=cap),
+        jnp.zeros(v.shape, F32))
+    return r, pair_counts(n_here, sizes, rows, k), passes * cap
 
 
 NO_COUNTS = np.zeros(4, np.int32)
@@ -505,13 +552,15 @@ def merge_counts(a, b):
 def latent_moe(cfg, p, u, active=None):
     """The LatentMoE mixer for u [T, H]: the routed experts work in the
     latent space (`u W_down`, back through `W_up`), the shared expert on
-    the hidden state itself. Returns (out [T, H], counts)."""
+    the hidden state itself. Returns (out [T, H], counts, the sorted
+    buffer's rows)."""
     with jax.named_scope("moe.route"):
         idx, weights = moe_route(cfg, p, u)
     v = u @ p["w_down"].astype(u.dtype)
-    r, counts = moe_experts(cfg, p, v, idx, weights, active)
+    r, counts, buffered = moe_experts(cfg, p, v, idx, weights, active)
     shared = relu2(u @ p["ws1"].astype(u.dtype)) @ p["ws2"].astype(u.dtype)
-    return r.astype(u.dtype) @ p["w_up"].astype(u.dtype) + shared, counts
+    return (r.astype(u.dtype) @ p["w_up"].astype(u.dtype) + shared, counts,
+            buffered)
 
 
 def forward_sequence(cfg, params, ids):
@@ -741,7 +790,8 @@ class HybridPagedDecoder(PagedDecoder):
     def _hybrid_step(self, params, tokens, seqlens, tables, active, kpool,
                      vpool, ssm, conv):
         """One decode step for every slot through the pattern. Returns
-        (logits [S, V], the four pools, per-step MoE counts)."""
+        (logits [S, V], the four pools, per-step MoE counts, the rows of
+        the expert blocks' sorted buffers)."""
         cfg, bs = self.cfg, self.block_size
         S = tokens.shape[0]
         x = jnp.take(params["embed"], tokens, axis=0)
@@ -752,7 +802,7 @@ class HybridPagedDecoder(PagedDecoder):
         widx = blk * bs + seqlens % bs
         kflat, vflat, NB, _ = self._flat_pools(kpool, vpool)
         m = a = 0
-        counts = jnp.asarray(NO_COUNTS)
+        counts, buffered = jnp.asarray(NO_COUNTS), jnp.int32(0)
         for i, kind in enumerate(cfg.hybrid_override_pattern):
             p = params["layers"][i]
             u = _rms(x, p["norm"], self.eps)
@@ -773,14 +823,14 @@ class HybridPagedDecoder(PagedDecoder):
                 out = o @ p["wo"].astype(dtype)
                 a += 1
             else:
-                out, c = latent_moe(cfg, p, u, active)
-                counts = merge_counts(counts, c)
+                out, c, b = latent_moe(cfg, p, u, active)
+                counts, buffered = merge_counts(counts, c), buffered + b
             x = x + out
         kpool = self._stacked_pools(kflat, kpool)
         vpool = self._stacked_pools(vflat, vpool)
         x = _rms(x, params["norm"], self.eps)
         return (self._head_logits(params, x), kpool, vpool, ssm, conv,
-                counts)
+                counts, buffered)
 
     def _paged_chunk_state_impl(self, params, tok0, seqlens0, tables, live,
                                 budgets, poison, kpool, vpool, ssm, conv,
@@ -788,11 +838,11 @@ class HybridPagedDecoder(PagedDecoder):
         """The state-carrying chunk of `PagedDecoder` (same arithmetic
         of liveness, budgets and eos), with the four pools in the step
         loop's carry and, after them in what it returns, the chunk's
-        counters `COUNTERS` (int32 [5]) that ride home with the tokens."""
+        counters `COUNTERS` (int32 [6]) that ride home with the tokens."""
         def body(carry, i):
-            tok, lens, bad, eos, stats, rows, pools = carry
+            tok, lens, bad, eos, stats, rows, buffered, pools = carry
             act = live & (i < budgets)
-            logits, *pools, c = self._hybrid_step(
+            logits, *pools, c, b = self._hybrid_step(
                 params, tok, lens, tables, act, *pools)
             logits = jnp.where(poison[:, None],
                                jnp.asarray(jnp.nan, logits.dtype), logits)
@@ -805,23 +855,25 @@ class HybridPagedDecoder(PagedDecoder):
             rows = rows + jnp.sum(act, dtype=jnp.int32) \
                 * jnp.int32(self.state_layers)
             return (nxt, lens, bad, eos, merge_counts(stats, c), rows,
-                    tuple(pools)), nxt
+                    buffered + b, tuple(pools)), nxt
 
         bad0 = jnp.zeros(tok0.shape, bool)
-        (tok, lens, bad, eos, stats, rows, pools), toks = jax.lax.scan(
-            body, (tok0, seqlens0, bad0, jnp.zeros_like(bad0),
-                   jnp.asarray(NO_COUNTS), jnp.int32(0),
-                   (kpool, vpool, ssm, conv)),
+        (tok, lens, bad, eos, stats, rows, buffered, pools), toks = \
+            jax.lax.scan(body, (tok0, seqlens0, bad0, jnp.zeros_like(bad0),
+                                jnp.asarray(NO_COUNTS), jnp.int32(0),
+                                jnp.int32(0), (kpool, vpool, ssm, conv)),
             jnp.arange(n, dtype=jnp.int32))
         took = jnp.minimum(jnp.int32(n), jnp.maximum(budgets, 0))
         budgets = jnp.where(live, budgets - took, budgets)
         live_out = live & (budgets > 0) & ~eos
         return (jnp.swapaxes(toks, 0, 1), bad, tok, lens, live_out,
                 budgets) + tuple(pools) \
-            + (jnp.concatenate([stats, rows[None]]),)
+            + (jnp.concatenate([stats, rows[None], buffered[None]]),)
 
     COUNTERS = ("moe_pairs_here", "moe_pairs_all", "moe_experts_touched",
-                "moe_max_load", "ssm_rows")
+                "moe_max_load", "ssm_rows", "moe_rows_buffered")
+    # what a prefill program counts: the MoE counts and the buffers' rows
+    ADMIT_COUNTERS = COUNTERS[:4] + COUNTERS[5:]
 
     def chunk_counters(self, aux):
         """The chunk's counters as `serve:commit` metadata; `aux` is
@@ -906,9 +958,10 @@ class HybridPagedDecoder(PagedDecoder):
         and V of the attention blocks go into each segment's pages, the
         state of every Mamba block at a segment's end (padded rows
         contribute nothing) into its slot's rows of `ssm` and `conv`.
-        Returns int32 [segments + 4] (each segment's encoded first
-        token, then the pack's MoE counts as `moe_experts` gives them,
-        merged over the expert blocks) and the pools."""
+        Returns int32 [segments + 5] (each segment's encoded first
+        token, then the pack's `ADMIT_COUNTERS`: the MoE counts as
+        `moe_experts` gives them, merged over the expert blocks, and the
+        rows of their sorted buffers) and the pools."""
         cfg, bs = self.cfg, self.block_size
         rows = ids.shape[0]
         x = jnp.take(params["embed"], ids, axis=0)
@@ -922,7 +975,7 @@ class HybridPagedDecoder(PagedDecoder):
         used = jnp.sum(lens > 0, dtype=jnp.int32)
         ends = last_chunk(starts, lens, cfg.chunk_size, rows)
         m = a = 0
-        counts = jnp.asarray(NO_COUNTS)
+        counts, buffered = jnp.asarray(NO_COUNTS), jnp.int32(0)
         for i, kind in enumerate(cfg.hybrid_override_pattern):
             p = params["layers"][i]
             u = _rms(x, p["norm"], self.eps)
@@ -946,8 +999,8 @@ class HybridPagedDecoder(PagedDecoder):
                     kflat, vflat, k, v, a * (NB * bs) + widx)
                 a += 1
             else:
-                out, c = latent_moe(cfg, p, u, valid)
-                counts = merge_counts(counts, c)
+                out, c, b = latent_moe(cfg, p, u, valid)
+                counts, buffered = merge_counts(counts, c), buffered + b
             x = x + out
         kpool = self._stacked_pools(kflat, kpool)
         vpool = self._stacked_pools(vflat, vpool)
@@ -955,21 +1008,23 @@ class HybridPagedDecoder(PagedDecoder):
         logits = self._head_logits(
             params, _rms(last, params["norm"], self.eps))
         enc = jnp.concatenate(
-            [jax.vmap(self._encode_first_token)(logits), counts])
+            [jax.vmap(self._encode_first_token)(logits), counts,
+             buffered[None]])
         return enc, kpool, vpool, ssm, conv
 
     def decode_first_token(self, encs, seg=0):
         """Segment `seg`'s first token as `PagedDecoder` encodes it. The
-        pack's MoE counts ride behind the tokens on the same wire and
-        are kept for `admit_metadata`: on the pack's first admission,
-        the others carry none, so that the sums over admissions are the
+        pack's counts ride behind the tokens on the same wire and are
+        kept for `admit_metadata`: on the pack's first admission, the
+        others carry none, so that the sums over admissions are the
         programs' own."""
         v = np.asarray(encs[-1])
-        self._admit_counts = [int(c) if seg == 0 else 0 for c in v[-4:]]
+        n = len(self.ADMIT_COUNTERS)
+        self._admit_counts = [int(c) if seg == 0 else 0 for c in v[-n:]]
         return super().decode_first_token([v[seg]])
 
     def admit_metadata(self):
         """The slot's recurrent state that the prefill overwrote, and
-        the pack's MoE counts under the chunk counters' names."""
+        the pack's counts under the chunk counters' names."""
         return {"state_bytes": self.slot_state_bytes,
-                **dict(zip(self.COUNTERS[:4], self._admit_counts))}
+                **dict(zip(self.ADMIT_COUNTERS, self._admit_counts))}

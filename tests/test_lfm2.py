@@ -341,7 +341,9 @@ def test_nemotron_h_serve_programs_are_unchanged():
     came (sha256 of the CPU lowering at the parent commit; a PR that
     means to change those programs replaces the two digests: PR 36 made
     the prefill the packed program and replaced its digest; PR 38 put
-    the state-update kernel into the chunk and replaced the chunk's)."""
+    the state-update kernel into the chunk and replaced the chunk's; PR
+    41 sized the expert layer's sorted buffer by the pairs held here and
+    replaced both)."""
     from paddle_tpu.models.nemotron_h import (NemotronHForCausalLM,
                                               nemotron_h_tiny)
     from paddle_tpu.models.paged_decode import PagedDecoder
@@ -360,8 +362,8 @@ def test_nemotron_h_serve_programs_are_unchanged():
     digest = {name: hashlib.sha256(text.encode()).hexdigest()
               for name, text in (("chunk", chunk), ("prefill", prefill))}
     assert digest == {
-        "chunk": "da1426d4458dda07d2353d2c54ff92caffc6607aa7662798a6839b03725ddb88",
-        "prefill": "eaac8f468060fb8e54f85efccfcb015484e131c92691d6257d863892e09fed58"}
+        "chunk": "4e632dec47f75144f038bbcf0103e55c0992aa2ace0ccd7dbae785ad515ee976",
+        "prefill": "033483e6dc0cc406a84f6295debe9163209a6db0cd4c2b73f2156ccfd542c2a4"}
 
 
 def test_rows_the_kernel_never_wrote_do_not_reach_a_gradient(

@@ -547,9 +547,9 @@ def test_ragged_kernel_without_window_sink_or_narrow_v_is_the_parents(
 
 PARENT_HYBRID = {
     "chunk":
-        "500e3f499510b195009f1f0d4277bad39689248d8508e76e6a32af4640d768cd",
+        "5b1ab2acda8f744d6089c2955830132b7b230b08240e3b4edafa2a50d73512d1",
     "prefill":
-        "fc9f9173ee1541c754147303a67d827a0718ac34f7819a4877b358d5a9ef8e19",
+        "680390d8f9038aa24f2e73fd8253e93e08370034e19ba2f605ce6e2373677287",
 }
 
 
@@ -560,8 +560,10 @@ def test_hybrid_engines_programs_lower_to_the_parents_text(program):
     tests/test_packed_prefill.py): the rule that picks the engine and the
     loop's prefill calls changed, no program of another engine did.
     PR 38 meant to change "chunk" and no other: its Mamba blocks' state
-    update is the kernel of `kernels/pallas/ssm_update.py` (the digest is
-    that commit's; "prefill" is still PR 36's)."""
+    update is the kernel of `kernels/pallas/ssm_update.py`. PR 41 meant to
+    change both: the expert layer's sorted buffer holds the pairs held
+    here, in as many passes of `nemotron_h.buffer_rows` as they take; the
+    digests are that commit's."""
     from paddle_tpu.models import nemotron_h as nh
     dec = PagedDecoder(
         nh.NemotronHForCausalLM(nh.nemotron_h_tiny(

@@ -191,7 +191,7 @@ def test_prefill_then_decode_gives_the_reference_logits(model, weights):
     for step, token in enumerate(rest):
         tokens = jnp.asarray([0, token], jnp.int32)
         lens = jnp.asarray([0, 11 + step], jnp.int32)
-        logits, *pools, counts = dec._hybrid_step(
+        logits, *pools, counts, _ = dec._hybrid_step(
             dec._params, tokens, lens, jnp.asarray(tables), active, *pools)
         _close(logits[slot], want[1 + step])
         assert int(counts[1]) == 2 * 4        # pairs of the one active row
@@ -396,6 +396,89 @@ def test_grouped_product_kernel_matches_the_gathered_reference():
     for e, (lo, hi) in [(0, (0, 10)), (2, (10, 110)), (3, (110, 140)),
                         (4, (140, 147))]:           # group 1 is empty
         _close(want[lo:hi], x[lo:hi] @ w[e], 1e-4)
+
+
+# -- the sorted buffer holds the pairs computed here, in as many passes as they take --------
+
+PASS_CFG = nh.nemotron_h_tiny(experts_held=(4, 4))   # a quarter of 16 held
+
+
+def _routing_with(n_here, t=256, k=4, seed=0):
+    """idx [t, k] with exactly `n_here` pairs on the held experts 4..7
+    (at most k a row, the first rows fullest) and routing weights."""
+    rng = np.random.default_rng(seed)
+    held = np.minimum(k, np.maximum(0, n_here - k * np.arange(t)))
+    others = np.r_[0:4, 8:16]
+    idx = np.stack([np.r_[rng.choice(np.arange(4, 8), h, replace=False),
+                          rng.choice(others, k - h, replace=False)]
+                    for h in held])
+    idx = np.take_along_axis(idx, rng.permuted(
+        np.tile(np.arange(k), (t, 1)), axis=1), 1)
+    return (jnp.asarray(idx, jnp.int32),
+            jnp.asarray(rng.uniform(0.1, 1.0, (t, k)), F32))
+
+
+def _full_buffer(cfg, p, v, idx, weights, active):
+    """The layer with a row for every pair routed anywhere, gathered
+    back to the tokens (what `moe_experts` computed before PR 41)."""
+    from paddle_tpu.kernels.pallas.grouped_matmul import grouped_matmul_sorted
+    t, k = idx.shape
+    order, sizes, rows = nh.sort_pairs(cfg, idx, active)
+    h = grouped_matmul_sorted(jnp.take(v, order // k, axis=0), p["w1"], sizes)
+    y = grouped_matmul_sorted(nh.relu2(h).astype(v.dtype), p["w2"], sizes)
+    n_here = jnp.sum(sizes, dtype=jnp.int32)
+    wy = jnp.where((jnp.arange(t * k) < n_here)[:, None],
+                   y * jnp.take(weights.reshape(-1), order)[:, None], 0.0)
+    back = jnp.zeros((t * k,), jnp.int32).at[order].set(
+        jnp.arange(t * k, dtype=jnp.int32))
+    r = jnp.sum(jnp.take(wy, back, axis=0).reshape(t, k, -1), axis=1)
+    return r, nh.pair_counts(n_here, sizes, rows, k)
+
+
+@pytest.mark.parametrize("n_here,active_rows,buffered,kernel", [
+    (100, None, 384, False),    # one pass: 1.5 x a quarter of 1,024 pairs
+    (500, None, 768, False),    # two
+    (900, None, 1152, False),   # three: the pairs past 768
+    (900, None, 1152, True),    # the same through the grouped kernel
+    (384, None, 384, False),    # exactly a buffer
+    (385, None, 768, False),    # one past it
+    (1024, None, 1152, False),  # every pair held
+    (0, None, 0, False),        # no pair held: no pass
+    (900, 80, 384, False),      # 580 held pairs on rows not active
+], ids=["one_pass", "two_passes", "three_passes", "three_passes_kernel",
+        "at_a_buffer", "one_past_a_buffer", "every_pair_held",
+        "no_pair_held", "inactive_rows"])
+def test_buffer_passes_give_the_full_buffers_layer(n_here, active_rows,
+                                                   buffered, kernel,
+                                                   monkeypatch):
+    """The passes give the full buffer's `r` and counts. Through the
+    grouped kernel (interpreted), the rows a pass leaves unwritten are
+    NaN: the select has to keep them out of the sum."""
+    from paddle_tpu.kernels.pallas import grouped_matmul as gm
+    if kernel:
+        plain = gm.grouped_matmul_sorted
+        monkeypatch.setattr(gm, "grouped_matmul_sorted", lambda *a, **kw:
+                            plain(*a, **dict(kw, impl="kernel")))
+        nh._experts_pass.clear_cache()      # traced with the reference
+    cfg = PASS_CFG
+    assert nh.buffer_rows(cfg, 256 * 4) == 384
+    rng = np.random.default_rng(n_here)
+    lat, f = cfg.moe_latent_size, cfg.moe_intermediate_size
+    p = {"w1": jnp.asarray(rng.normal(0, 0.2, (4, lat, f)), F32),
+         "w2": jnp.asarray(rng.normal(0, 0.2, (4, f, lat)), F32)}
+    v = jnp.asarray(rng.normal(size=(256, lat)), F32)
+    idx, weights = _routing_with(n_here)
+    active = None if active_rows is None \
+        else jnp.arange(256) < active_rows
+    r, counts, rows = nh.moe_experts(cfg, p, v, idx, weights, active)
+    if kernel:
+        monkeypatch.undo()
+        nh._experts_pass.clear_cache()
+    want_r, want_counts = _full_buffer(cfg, p, v, idx, weights, active)
+    _close(r, want_r, 1e-6)
+    assert np.asarray(counts).tolist() == np.asarray(want_counts).tolist()
+    assert int(counts[0]) == (n_here if active_rows is None else 320)
+    assert int(rows) == buffered
 
 
 # -- what does not compose with a recurrent state refuses -----------------------------------
