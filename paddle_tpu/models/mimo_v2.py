@@ -769,38 +769,24 @@ class WindowPagedDecoder(PagedDecoder):
         tokens."""
         window = jnp.int32(self.cfg.sliding_window)
 
-        def body(carry, i):
-            tok, lens, bad, eos, stats, seen, pools = carry
-            act = live & (i < budgets)
+        def step(tok, lens, act, pools):
             logits, *pools, c = self._step(params, tok, lens, tables, act,
                                            *pools)
-            logits = jnp.where(poison[:, None],
-                               jnp.asarray(jnp.nan, logits.dtype), logits)
-            bad = bad | (act & jnp.any(~jnp.isfinite(logits), axis=-1))
-            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            nxt = jnp.where(act, nxt, tok)
+            return logits, pools, c
+
+        def tally(acc, c, act, lens):
+            stats, seen = acc
             keys = jnp.where(act, lens + 1, 0)
-            seen = seen + jnp.stack([
+            return merge_counts(stats, c), seen + jnp.stack([
                 jnp.sum(act, dtype=jnp.int32),
                 jnp.sum(keys, dtype=jnp.int32),
                 jnp.sum(jnp.minimum(keys, window), dtype=jnp.int32)])
-            lens = jnp.where(act, lens + 1, lens)
-            if eos_id >= 0:
-                eos = eos | (act & (nxt == jnp.int32(eos_id)))
-            return (nxt, lens, bad, eos, merge_counts(stats, c), seen,
-                    tuple(pools)), nxt
 
-        bad0 = jnp.zeros(tok0.shape, bool)
-        (tok, lens, bad, eos, stats, seen, pools), toks = jax.lax.scan(
-            body, (tok0, seqlens0, bad0, jnp.zeros_like(bad0),
-                   jnp.asarray(NO_COUNTS), jnp.zeros(3, jnp.int32),
-                   (kf, vf, kw, vw)),
-            jnp.arange(n, dtype=jnp.int32))
-        took = jnp.minimum(jnp.int32(n), jnp.maximum(budgets, 0))
-        budgets = jnp.where(live, budgets - took, budgets)
-        live_out = live & (budgets > 0) & ~eos
-        return (jnp.swapaxes(toks, 0, 1), bad, tok, lens, live_out,
-                budgets) + tuple(pools) + (jnp.concatenate([stats, seen]),)
+        out, (stats, seen) = self._chunk_scan(
+            step, tok0, seqlens0, live, budgets, poison, (kf, vf, kw, vw), n,
+            eos_id, tally,
+            lambda: (jnp.asarray(NO_COUNTS), jnp.zeros(3, jnp.int32)))
+        return out + (jnp.concatenate([stats, seen]),)
 
     def chunk_counters(self, aux):
         """The chunk's counters as `serve:commit` metadata; `aux` is

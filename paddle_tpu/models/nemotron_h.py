@@ -425,12 +425,24 @@ def moe_route(cfg, p, u):
     weights [T, k] float32). `s = sigmoid(u W_r)` in float32; the
     correction bias takes part in the choice only; the weights are `s`
     normalised over all k chosen, held here or not, times the routed
-    scaling factor."""
+    scaling factor. With `n_group` > 1 (a configuration without the key
+    has one group) the choice is limited to the `topk_group` groups of
+    experts whose two best choice scores sum highest."""
     logits = jnp.dot(u.astype(F32), p["router"].astype(F32),
                      precision=jax.lax.Precision.HIGHEST)
     s = jax.nn.sigmoid(logits)
-    _, idx = jax.lax.top_k(s + p["b_corr"].astype(F32)[None, :],
-                           cfg.num_experts_per_tok)
+    choice = s + p["b_corr"].astype(F32)[None, :]
+    groups = getattr(cfg, "n_group", 1)
+    if groups > 1:
+        t, e = choice.shape
+        by_group = choice.reshape(t, groups, e // groups)
+        score = jnp.sum(jax.lax.top_k(by_group, 2)[0], axis=-1)
+        _, kept = jax.lax.top_k(score, cfg.topk_group)
+        allowed = jnp.zeros((t, groups), bool).at[
+            jnp.arange(t, dtype=jnp.int32)[:, None], kept].set(True)
+        choice = jnp.where(jnp.repeat(allowed, e // groups, axis=1), choice,
+                           -jnp.inf)
+    _, idx = jax.lax.top_k(choice, cfg.num_experts_per_tok)
     picked = jnp.take_along_axis(s, idx, axis=1)
     if cfg.norm_topk_prob:
         picked = picked / (jnp.sum(picked, -1, keepdims=True)
@@ -839,36 +851,22 @@ class HybridPagedDecoder(PagedDecoder):
         of liveness, budgets and eos), with the four pools in the step
         loop's carry and, after them in what it returns, the chunk's
         counters `COUNTERS` (int32 [6]) that ride home with the tokens."""
-        def body(carry, i):
-            tok, lens, bad, eos, stats, rows, buffered, pools = carry
-            act = live & (i < budgets)
+        def step(tok, lens, act, pools):
             logits, *pools, c, b = self._hybrid_step(
                 params, tok, lens, tables, act, *pools)
-            logits = jnp.where(poison[:, None],
-                               jnp.asarray(jnp.nan, logits.dtype), logits)
-            bad = bad | (act & jnp.any(~jnp.isfinite(logits), axis=-1))
-            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            nxt = jnp.where(act, nxt, tok)
-            lens = jnp.where(act, lens + 1, lens)
-            if eos_id >= 0:
-                eos = eos | (act & (nxt == jnp.int32(eos_id)))
+            return logits, pools, (c, b)
+
+        def tally(acc, aux, act, lens):
+            (stats, rows, buffered), (c, b) = acc, aux
             rows = rows + jnp.sum(act, dtype=jnp.int32) \
                 * jnp.int32(self.state_layers)
-            return (nxt, lens, bad, eos, merge_counts(stats, c), rows,
-                    buffered + b, tuple(pools)), nxt
+            return merge_counts(stats, c), rows, buffered + b
 
-        bad0 = jnp.zeros(tok0.shape, bool)
-        (tok, lens, bad, eos, stats, rows, buffered, pools), toks = \
-            jax.lax.scan(body, (tok0, seqlens0, bad0, jnp.zeros_like(bad0),
-                                jnp.asarray(NO_COUNTS), jnp.int32(0),
-                                jnp.int32(0), (kpool, vpool, ssm, conv)),
-            jnp.arange(n, dtype=jnp.int32))
-        took = jnp.minimum(jnp.int32(n), jnp.maximum(budgets, 0))
-        budgets = jnp.where(live, budgets - took, budgets)
-        live_out = live & (budgets > 0) & ~eos
-        return (jnp.swapaxes(toks, 0, 1), bad, tok, lens, live_out,
-                budgets) + tuple(pools) \
-            + (jnp.concatenate([stats, rows[None], buffered[None]]),)
+        out, (stats, rows, buffered) = self._chunk_scan(
+            step, tok0, seqlens0, live, budgets, poison,
+            (kpool, vpool, ssm, conv), n, eos_id, tally,
+            lambda: (jnp.asarray(NO_COUNTS), jnp.int32(0), jnp.int32(0)))
+        return out + (jnp.concatenate([stats, rows[None], buffered[None]]),)
 
     COUNTERS = ("moe_pairs_here", "moe_pairs_all", "moe_experts_touched",
                 "moe_max_load", "ssm_rows", "moe_rows_buffered")

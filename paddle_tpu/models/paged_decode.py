@@ -165,8 +165,9 @@ class PagedDecoder(CachedDecoder):
     What the serve loop carries chunk to chunk is the tuple `new_pools()`
     returns, `(kpool, vpool)` here: every program takes it as its last
     array arguments and returns it in the same order. An engine whose
-    model keeps further per-slot state (models/nemotron_h.py), or a
-    second kind of KV cache (models/mimo_v2.py), makes the tuple longer;
+    model keeps further per-slot state (models/nemotron_h.py), a second
+    kind of KV cache (models/mimo_v2.py) or latent rows and indexer keys
+    in place of K and V (models/deepseek_v32.py), makes its own tuple;
     `PagedDecoder(model)` builds that engine when the model's
     configuration's layer pattern names such a cache (`cache_kinds`).
     """
@@ -183,7 +184,8 @@ class PagedDecoder(CachedDecoder):
     # every token: by the kinds of cache its configuration's layer
     # pattern names (`cache_kinds`, one a layer), not by family
     ENGINE_OF_KIND = {"state": ("nemotron_h", "HybridPagedDecoder"),
-                      "window": ("mimo_v2", "WindowPagedDecoder")}
+                      "window": ("mimo_v2", "WindowPagedDecoder"),
+                      "latent": ("deepseek_v32", "LatentPagedDecoder")}
 
     def __new__(cls, model, *args, **kwargs):
         if cls is PagedDecoder:
@@ -729,32 +731,52 @@ class PagedDecoder(CachedDecoder):
         pools). tok0/seqlens0/live/budgets and the pools are donated
         (the chunk-to-chunk chain); tables/poison are not — the same
         device arrays serve every chunk until a composition change."""
-        def body(carry, i):
-            tok, lens, bad, eos, kc, vc = carry
-            act = live & (i < budgets)
+        def step(tok, lens, act, pools):
             logits, kc, vc = self._paged_step_impl(
-                params, tok, lens, tables, kc, vc, active=act)
+                params, tok, lens, tables, *pools, active=act)
+            return logits, (kc, vc), None
+
+        out, _ = self._chunk_scan(step, tok0, seqlens0, live, budgets,
+                                  poison, (kpool, vpool), n, eos_id)
+        return out
+
+    @staticmethod
+    def _chunk_scan(step, tok0, seqlens0, live, budgets, poison, pools, n,
+                    eos_id, tally=None, acc=lambda: ()):
+        """The liveness, budget, eos and poison arithmetic of every
+        engine's decode chunk (see `_paged_chunk_state_impl`) around
+        `step(tok, lens, act, pools) -> (logits, pools, aux)`, one
+        engine's decode step. `tally(acc, aux, act, lens)` folds a step's
+        `aux` into the running `acc` (`lens` as the step found them),
+        which starts as `acc()`. Returns ((toks [S, n], bad, tok',
+        seqlens', live', budgets') + pools, acc)."""
+        def body(carry, i):
+            tok, lens, bad, eos, acc, pools = carry
+            act = live & (i < budgets)
+            logits, pools, aux = step(tok, lens, act, pools)
             logits = jnp.where(poison[:, None],
                                jnp.asarray(jnp.nan, logits.dtype),
                                logits)
             bad = bad | (act & jnp.any(~jnp.isfinite(logits), axis=-1))
             nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             nxt = jnp.where(act, nxt, tok)
-            lens = jnp.where(act, lens + 1, lens)
+            after = jnp.where(act, lens + 1, lens)
             if eos_id >= 0:
                 eos = eos | (act & (nxt == jnp.int32(eos_id)))
-            return (nxt, lens, bad, eos, kc, vc), nxt
+            if tally is not None:
+                acc = tally(acc, aux, act, lens)
+            return (nxt, after, bad, eos, acc, tuple(pools)), nxt
 
         bad0 = jnp.zeros(tok0.shape, bool)
-        (tok, lens, bad, eos, kpool, vpool), toks = jax.lax.scan(
-            body, (tok0, seqlens0, bad0, jnp.zeros_like(bad0), kpool,
-                   vpool),
+        (tok, lens, bad, eos, acc, pools), toks = jax.lax.scan(
+            body, (tok0, seqlens0, bad0, jnp.zeros_like(bad0), acc(),
+                   tuple(pools)),
             jnp.arange(n, dtype=jnp.int32))
         took = jnp.minimum(jnp.int32(n), jnp.maximum(budgets, 0))
         budgets = jnp.where(live, budgets - took, budgets)
         live_out = live & (budgets > 0) & ~eos
         return (jnp.swapaxes(toks, 0, 1), bad, tok, lens, live_out,
-                budgets, kpool, vpool)
+                budgets) + pools, acc
 
     def _spec_verify_impl(self, params, toks, seqlens, tables, live,
                           budgets, poison, kpool, vpool):

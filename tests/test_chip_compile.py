@@ -41,12 +41,13 @@ def for_the_chip(monkeypatch):
     from jax.experimental.compilation_cache import compilation_cache
     from paddle_tpu.kernels.pallas import (flash_attention, flash_prefill,
                                            fused_elementwise,
-                                           grouped_matmul,
+                                           grouped_matmul, lightning_index,
+                                           mla_decode, mla_prefill,
                                            ragged_paged_attention, rms_norm,
                                            ssm_update)
     for mod in (flash_attention, flash_prefill, fused_elementwise,
-                grouped_matmul, ragged_paged_attention, rms_norm,
-                ssm_update):
+                grouped_matmul, lightning_index, mla_decode, mla_prefill,
+                ragged_paged_attention, rms_norm, ssm_update):
         monkeypatch.setattr(mod, "_interpret", lambda: False)
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
@@ -399,3 +400,88 @@ def test_flash_prefill_chunk(one_chip, for_the_chip, kind, nkv, keys):
     else:
         fn = lambda q, k, v, at: flash_prefill_attention(q, k, v, at)
     assert "tpu_custom_call" in _compiled_text(fn, one_chip, *shapes)
+
+
+# -- the latent engine (deepseek_v32_serve_backlog_16k) ---------------------------
+
+@pytest.fixture(scope="module")
+def latent(one_chip):
+    """The serving engine of `deepseek_v32_ep16_l5` with its 4.64 B
+    parameters described, not made: (decoder, pools, described)."""
+    from paddle_tpu.models.deepseek_v32 import (DeepseekV32Config,
+                                                LatentPagedDecoder)
+    cfg = DeepseekV32Config(
+        num_hidden_layers=5, first_k_dense_replace=1, vocab_size=16160,
+        experts_held=(0, 16), dtype="bfloat16",
+        rope_scaling=dict(type="yarn", factor=40,
+                          original_max_position_embeddings=4096,
+                          beta_fast=32, beta_slow=1, mscale=1,
+                          mscale_all_dim=1))
+
+    def described(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+
+    class Described:
+        config = cfg
+
+        def param_tree(self):
+            tree = {"layers": [{} for _ in range(cfg.num_hidden_layers)]}
+            for name, (shape, f32) in cfg.param_shapes().items():
+                leaf = described(shape, jnp.float32 if f32 else BF16)
+                if name.startswith("layers."):
+                    _, i, key = name.split(".")
+                    tree["layers"][int(i)][key] = leaf
+                else:
+                    tree[name] = leaf
+            return tree
+
+    dec = LatentPagedDecoder(Described(), max_len=16384, block_size=64,
+                             num_blocks=9216, max_slots=48,
+                             prefill_chunk=1024)
+    pools = tuple(described(p.shape, p.dtype)
+                  for p in jax.eval_shape(dec.new_pools))
+    return dec, pools, described
+
+
+def _fits_beside_its_pools(compiled, pools):
+    """The program's arguments and temporaries fit the chip, and its
+    temporaries are too small for a second copy of one layer's latent
+    pool."""
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes \
+        < 15.75 * 2**30
+    one_layer = pools[0].size * 2 // pools[0].shape[0]
+    assert memory.temp_size_in_bytes < one_layer
+    return compiled.as_text()
+
+
+def test_latent_chunk_program(latent, for_the_chip):
+    """8 decode steps for 48 slots at 16,384 positions: a slot's indexer
+    kernel scores its cached keys, the chosen rows' attention is a
+    kernel of its own, the grouped expert products run, and the 13.8 GB
+    of arguments fit the chip beside the temporaries."""
+    dec, pools, described = latent
+    S, MB = dec.max_slots, dec.blocks_per_seq
+    i32, flag = jnp.int32, jnp.bool_
+    compiled = dec._paged_chunk_state_jit.lower(
+        dec._params, described((S,), i32), described((S,), i32),
+        described((S, MB), i32), described((S,), flag),
+        described((S,), i32), described((S,), flag), *pools, 8, -1).compile()
+    text = _fits_beside_its_pools(compiled, pools)
+    assert "%decode.index" in text and "%decode.attend.sparse" in text
+    assert "%moe.experts" in text
+
+
+def test_latent_prefill_program(latent, for_the_chip):
+    """A 1,024-row chunk against 16,384 positions: the indexer's kernel
+    and the attention kernel that forms the heads' keys and values from
+    the latent rows, no [keys, heads, dims] array in HBM."""
+    dec, pools, described = latent
+    i32 = jnp.int32
+    compiled = dec._prefill_exec(1024).lower(
+        dec._params, described((1024,), i32), described((), i32),
+        described((), i32), described((dec.blocks_per_seq,), i32),
+        *pools).compile()
+    text = _fits_beside_its_pools(compiled, pools)
+    assert "%prefill.index" in text and "%prefill.attend" in text
+    assert not re.search(r"bf16\[16384,128,\d+\]", text)
