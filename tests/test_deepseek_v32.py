@@ -482,6 +482,8 @@ def test_prefill_index_kernel_is_the_plain_scores(tq, tk, q_start):
 
 
 def test_decode_index_kernel_is_the_plain_scores():
+    """Three slots, each the sole owner of a contiguous run of blocks,
+    against the plain scores of its own keys."""
     from paddle_tpu.kernels.pallas.lightning_index import (
         lightning_index_decode)
     rng = np.random.default_rng(2)
@@ -489,10 +491,113 @@ def test_decode_index_kernel_is_the_plain_scores():
     w = jnp.asarray(rng.normal(size=(3, 8)), F32)
     k = jnp.asarray(rng.normal(size=(3, 256, 16)), F32)
     pos = jnp.asarray([0, 100, 255], jnp.int32)
-    got = np.asarray(lightning_index_decode(q, w, k, pos))
+    tables = jnp.arange(3 * 32, dtype=jnp.int32).reshape(3, 32)
+    got = np.asarray(lightning_index_decode(q, w, k.reshape(96, 8, 16),
+                                            tables, pos))
     want = np.asarray(dm.index_scores(q, w, k))
     for s, p in enumerate([0, 100, 255]):
         _close(got[s, :p + 1], want[s, :p + 1], 1e-5)
+
+
+# four slots through one pool of 2 layers x 40 blocks of 8 keys, tables
+# of 12 blocks: one key; a position inside its 5th block; all 12 blocks;
+# an idle slot whose table names the trash block 0 only
+PAGED_POS = [0, 37, 95, 21]
+
+
+def _paged_index_case(layer):
+    """(q, w, pool, tables, pos, base) for `PAGED_POS`: each slot's blocks
+    drawn out of order from the layer's 40, none shared; every block no
+    table names as live is NaN, those behind a slot's position too."""
+    rng = np.random.default_rng(12)
+    nb, bs, d = 40, 8, 16
+    pool = rng.normal(size=(2 * nb, bs, d)).astype(np.float32)
+    owned = rng.permutation(np.arange(1, nb))[:3 * 12].reshape(3, 12)
+    tables = np.zeros((4, 12), np.int32)
+    tables[:3] = owned
+    live = np.zeros(2 * nb, bool)
+    for s, p in enumerate(PAGED_POS):
+        live[tables[s, :p // bs + 1] + layer * nb] = True
+    pool[~live] = np.nan
+    q = rng.normal(size=(4, 8, d)).astype(np.float32)
+    w = rng.normal(size=(4, 8)).astype(np.float32)
+    return (jnp.asarray(q), jnp.asarray(w), jnp.asarray(pool),
+            jnp.asarray(tables), jnp.asarray(PAGED_POS, jnp.int32), layer * nb)
+
+
+@pytest.mark.parametrize("layer", [0, 1])
+@pytest.mark.parametrize("sizes", ["one_product", "groups_of_4"])
+def test_paged_index_kernel_is_the_gathered_path(monkeypatch, layer, sizes):
+    """The kernel reads the slot's live blocks through its table: the
+    scores of today's gathered path (every block the table names copied
+    out, then scored) on each live block's positions, 0 behind, and no
+    NaN from a block it must not read. `groups_of_4` steers the sizing
+    rule to products of 2 blocks and groups of 4, so that the one-key
+    slot is shorter than a group, the 5-block slot ends inside its
+    second group's second product and the full one takes three
+    groups."""
+    from paddle_tpu.kernels.pallas import ragged_paged_attention as rpa
+    from paddle_tpu.kernels.pallas.lightning_index import (
+        lightning_index_decode)
+    if sizes == "groups_of_4":
+        monkeypatch.setattr(rpa, "_PRODUCT_COLS", 16)
+        monkeypatch.setattr(rpa, "_BUFFER_BYTES", 4096)
+        assert rpa._blocks_per_step(8 * 16 * 4 // 2, 8, 12) == (4, 2)
+    q, w, pool, tables, pos, base = _paged_index_case(layer)
+    got = np.asarray(lightning_index_decode(q, w, pool, tables, pos, base))
+    gathered = jnp.take(pool, tables + base, axis=0).reshape(4, 96, 16)
+    want = np.asarray(dm.index_scores(q, w, gathered))
+    assert got.shape == (4, 96) and np.isfinite(got).all()
+    for s, p in enumerate(PAGED_POS):
+        live = (p // 8 + 1) * 8
+        _close(got[s, :live], want[s, :live], 1e-5)
+        assert (got[s, live:] == 0).all()
+
+
+@pytest.mark.parametrize("layer", [1, 2])
+def test_paged_selection_is_the_gathered_paths(model, layer):
+    """`_select` with the kernel reading the pool through the tables
+    chooses the positions today's gathered path chooses (every key the
+    tables name copied out, scored in plain XLA, the same top-k): a slot
+    below `index_topk` (count < k), one mid-block, one idle on the trash
+    block, at layers past the first."""
+    dec = _decoder(model, slots=4)
+    L, nb, bs, d = 3, dec.num_blocks, BLOCK, CFG["index_head_dim"]
+    rng = np.random.default_rng(13)
+    idx = jnp.asarray(rng.normal(size=(L * nb * bs, d)), F32)
+    tables = np.zeros((4, dec.blocks_per_seq), np.int32)
+    tables[:3] = rng.permutation(np.arange(1, nb))[:3 * 32].reshape(3, 32)
+    tables = jnp.asarray(tables)
+    pos = jnp.asarray([9, 100, 255, 30], jnp.int32)
+    qi = jnp.asarray(rng.normal(size=(4, 4, d)), F32)
+    wi = jnp.asarray(rng.normal(size=(4, 4)), F32)
+    got, count = jax.jit(dec._select, static_argnums=3)(qi, wi, idx, layer,
+                                                        tables, pos)
+    keys = dec._context(idx, layer, tables)
+    valid = jnp.arange(keys.shape[1])[None] <= pos[:, None]
+    want, want_count = dm.mask_positions(
+        dm.topk_mask(dm.index_scores(qi, wi, keys), valid, 16), 16)
+    assert count.tolist() == want_count.tolist() == [10, 16, 16, 16]
+    assert (np.asarray(got) == np.asarray(want)).all()
+
+
+@pytest.mark.parametrize("layer", [0, 2])
+def test_chosen_rows_are_the_tables_rows(model, layer):
+    """Decode's pool rows of the chosen positions, each table entry
+    picked by a compare-and-select, are the rows `_rows` gathers: tables
+    out of order, positions in every block of a slot and on the trash
+    block of an idle one."""
+    dec = _decoder(model, slots=4)
+    rng = np.random.default_rng(14)
+    tables = np.zeros((4, dec.blocks_per_seq), np.int32)
+    tables[:3] = rng.permutation(np.arange(1, dec.num_blocks))[:3 * 32] \
+        .reshape(3, 32)
+    pos = rng.integers(0, MAX_LEN, size=(4, 16)).astype(np.int32)
+    pos[3] = 0
+    tables, pos = jnp.asarray(tables), jnp.asarray(pos)
+    got = jax.jit(dec._chosen_rows, static_argnums=0)(layer, tables, pos)
+    assert got.dtype == jnp.int32
+    assert (np.asarray(got) == np.asarray(dec._rows(layer, tables, pos))).all()
 
 
 @pytest.mark.parametrize("q_start", [0, 64])
