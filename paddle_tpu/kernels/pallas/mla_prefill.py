@@ -1,7 +1,7 @@
 """Pallas TPU flash attention of a prefill chunk in multi-head latent
 attention's expanded form, each head's keys and values formed from the
-latent rows inside the kernel, masked to a selection of keys (forward
-only).
+latent rows inside the kernel, causal and, where a selection is given,
+masked to it (forward only).
 
 For query rows t of a chunk (row t at key position `q_start + t`) and
 every key j of the sequence, head h:
@@ -9,7 +9,8 @@ every key j of the sequence, head h:
     k_nope_h(j), v_h(j) = c(j) Wkvb_h          (c: the latent row's first
                                                kv_lora_rank dims)
     s_h(t, j) = (q_nope_h(t) . k_nope_h(j) + q_pe_h(t) . k_pe(j)) * scale
-    o_h(t) = softmax over the j <= q_start + t with mask[t, j] != 0
+    o_h(t) = softmax over the j <= q_start + t (with mask[t, j] != 0
+             where a mask is given: a sparse configuration's choice)
 
 A key tile's latent rows [bk, W] and the head's [kv_lora_rank, dn + dv]
 slice of Wkvb are all the kernel reads besides q and the mask: no [keys,
@@ -47,11 +48,14 @@ def _interpret():
     return jax.default_backend() != "tpu"
 
 
-def _kernel(at_ref, qn_ref, qp_ref, lat_ref, w_ref, mask_ref, o_ref, m_sc,
-            l_sc, acc_sc, *, scale, bq, bk, kvr, dn):
+def _kernel(at_ref, qn_ref, qp_ref, lat_ref, w_ref, *refs, scale, bq, bk,
+            kvr, dn, masked):
     """One (head, query block, key step). at_ref (SMEM) [q_start];
     qn_ref [bq, dn]; qp_ref [bq, W - kvr]; lat_ref [bk, W]; w_ref [kvr,
-    dn + dv]; mask_ref [bq, bk] int8; o_ref [bq, dv]."""
+    dn + dv]; then, where `masked`, mask_ref [bq, bk] int8; o_ref [bq,
+    dv] and the scratch."""
+    mask_ref = refs[0] if masked else None
+    o_ref, m_sc, l_sc, acc_sc = refs[1:] if masked else refs
     i, j = pl.program_id(1), pl.program_id(2)
     row0 = at_ref[0] + i * np.int32(bq)
     last = (row0 + np.int32(bq - 1)) // np.int32(bk)
@@ -72,7 +76,9 @@ def _kernel(at_ref, qn_ref, qp_ref, lat_ref, w_ref, mask_ref, o_ref, m_sc,
             * scale
         row = row0 + lax.broadcasted_iota(jnp.int32, st.shape, 0)
         col = j * np.int32(bk) + lax.broadcasted_iota(jnp.int32, st.shape, 1)
-        sees = jnp.logical_and(col <= row, mask_ref[...] != 0)
+        sees = col <= row
+        if masked:
+            sees = jnp.logical_and(sees, mask_ref[...] != 0)
         st = jnp.where(sees, st, NEG_INF)
         m = m_sc[:]
         m_new = jnp.maximum(m, st.max(axis=-1, keepdims=True))
@@ -91,16 +97,18 @@ def _kernel(at_ref, qn_ref, qp_ref, lat_ref, w_ref, mask_ref, o_ref, m_sc,
 def _launch(qn, qp, lat, w, mask, at, scale, kvr):
     nh, tq, dn = qn.shape
     tk = lat.shape[0]
-    dv = w.shape[1] // nh - dn
+    dv = w.shape[-1] // (1 if w.ndim == 3 else nh) - dn
     bq, bk = _block(tq, 1024), _block(tk, 512)
 
     def key_tile(i, j, at_ref):
         last = (at_ref[0] + (i + 1) * np.int32(bq) - 1) // np.int32(bk)
         return jnp.minimum(j, last)
 
+    masks = [] if mask is None else [
+        pl.BlockSpec((bq, bk), lambda h, i, j, at: (i, key_tile(i, j, at)))]
     return pl.pallas_call(
         functools.partial(_kernel, scale=np.float32(scale), bq=bq, bk=bk,
-                          kvr=kvr, dn=dn),
+                          kvr=kvr, dn=dn, masked=mask is not None),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(nh, tq // bq, tk // bk),
@@ -110,9 +118,11 @@ def _launch(qn, qp, lat, w, mask, at, scale, kvr):
                              lambda h, i, j, *_: (h, i, 0)),
                 pl.BlockSpec((bk, lat.shape[1]),
                              lambda h, i, j, at: (key_tile(i, j, at), 0)),
-                pl.BlockSpec((kvr, dn + dv), lambda h, i, j, *_: (0, h)),
-                pl.BlockSpec((bq, bk),
-                             lambda h, i, j, at: (i, key_tile(i, j, at)))],
+                pl.BlockSpec((kvr, dn + dv), lambda h, i, j, *_: (0, h))
+                if w.ndim == 2 else
+                pl.BlockSpec((None, kvr, dn + dv),
+                             lambda h, i, j, *_: (h, 0, 0))]
+            + masks,
             out_specs=pl.BlockSpec((None, bq, dv),
                                    lambda h, i, j, *_: (h, i, 0)),
             scratch_shapes=[pltpu.VMEM((bq, 1), jnp.float32),
@@ -123,18 +133,20 @@ def _launch(qn, qp, lat, w, mask, at, scale, kvr):
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=64 * 2**20),
         interpret=_interpret(),
-    )(at, qn, qp, lat, w, mask)
+    )(at, qn, qp, lat, w, *([] if mask is None else [mask]))
 
 
 def mla_prefill_attention(q, latent, wkv_b, mask, q_start, kv_lora_rank,
                           rope_dims, scale):
-    """Masked causal MLA of a chunk of queries against latent rows.
+    """Causal MLA of a chunk of queries against latent rows, masked to a
+    selection where one is given.
 
     q [Tq, nh, dn + dr] ([q_nope | q_pe], the rotary term applied, dr =
     `rope_dims`); latent [Tk, W] rows [c | k_pe | zeros] with c
     `kv_lora_rank` wide, for key positions 0 .. Tk - 1; wkv_b
     [kv_lora_rank, nh * (dn + dv)] (head h's k_nope and v columns side
-    by side); mask [Tq, Tk], nonzero where a query may attend a key;
+    by side); mask [Tq, Tk], nonzero where a query may attend a key, or
+    None where every causal key is attended;
     query row i lies at key position `q_start + i` (int32 scalar, traced)
     and attends no key past it. Tq and Tk are whole tiles (the largest of
     1024 .. 8 rows and 512 .. 8 keys that divides them). Returns [Tq, nh,
@@ -145,6 +157,13 @@ def mla_prefill_attention(q, latent, wkv_b, mask, q_start, kv_lora_rank,
     qp = jnp.swapaxes(jnp.pad(q[..., dn:], ((0, 0), (0, 0),
                                             (0, pe_width - rope_dims))), 0, 1)
     at = jnp.asarray(q_start, jnp.int32).reshape(1)
-    o = _launch(qn, qp, latent, wkv_b, mask.astype(jnp.int8), at,
+    nh, per_head = q.shape[1], wkv_b.shape[1] // q.shape[1]
+    if per_head % 128:
+        # a head's columns are no whole lanes: Mosaic copies a block of
+        # whole lanes or the whole dimension, so a head's slice of Wkvb
+        # becomes a [kv_lora_rank, dn + dv] array of its own
+        wkv_b = jnp.swapaxes(wkv_b.reshape(kv_lora_rank, nh, per_head), 0, 1)
+    o = _launch(qn, qp, latent, wkv_b,
+                None if mask is None else mask.astype(jnp.int8), at,
                 float(scale), kv_lora_rank)
     return jnp.swapaxes(o, 0, 1)

@@ -2,7 +2,10 @@
 keys are chosen by a learned indexer (DeepSeek sparse attention), YaRN
 rotary positions, and sparse SwiGLU experts with a group-limited sigmoid
 router and a shared expert behind leading dense layers (deepseek-ai
-DeepSeek-V3.2 is the published member served here).
+DeepSeek-V3.2 is the published member served here). A configuration
+without `index_topk` is the DENSE member of the family: no indexer, every
+causal latent row attended (`models/glm4_moe_lite.py`); one with
+`num_nextn_predict_layers` keeps a multi-token-prediction (MTP) layer.
 
 Block `l` on x [tokens, hidden], no bias anywhere but the indexer's
 LayerNorm; RMS is RMSNorm (eps `rms_norm_eps`):
@@ -19,8 +22,10 @@ LayerNorm; RMS is RMSNorm (eps `rms_norm_eps`):
              I(t, j) = sum_h wI_h(t) relu(qI_h(t) . kI(j)),  j <= t
              sel(t) = the top min(index_topk, t + 1) of I(t, .), exact,
                       ties to the lower position
+             (no indexer: sel(t) = every j <= t)
     s_h(t, j) = (q_nope_h . k_nope_h(j) + q_pe_h . k_pe(j)) * tau,
                 tau = (dn + dr)^-1/2 * m^2, m = 0.1 ln(factor) + 1
+                (m = 1 without YaRN)
     x = x + (sum_h softmax_{j in sel(t)} s_h(t, j) v_h(j)) Wo
     h2 = RMS(x; ln2)
     x = x + Wd(silu(Wg h2) * (Wu h2))            l < first_k_dense_replace
@@ -30,6 +35,17 @@ LayerNorm; RMS is RMSNorm (eps `rms_norm_eps`):
     the sum of its two best choices; the top `topk_group` of `n_group`
     groups, then top-k among their experts; w = s_chosen / sum(s_chosen)
     * routed_scaling_factor)
+    logits = RMS(x; norm) Whead
+
+The MTP layer (DeepSeek-V3 report, 2.2) drafts the token after next from
+the main model's last hidden state AFTER its final norm, hn_i = RMS(x_i;
+norm), and the embedding of the next token; it shares the embedding and
+the head:
+
+    u_i = Weh [RMS(emb(t_{i+1}); enorm) ; RMS(hn_i; hnorm)]
+    u = block L (an expert block of the main blocks' shape, with its own
+        latent rows) on u, at the positions i
+    draft logits_i = RMS(u_i; mtp norm) Whead            predicts t_{i+2}
 
 The operators are pure functions of (weights, activations), shared by
 `DeepseekV32ForCausalLM.forward` (whole sequences) and
@@ -37,14 +53,20 @@ The operators are pure functions of (weights, activations), shared by
 `nemotron_h`'s, the expert layer `mimo_v2.moe_experts`.
 
 Serving keeps a LATENT cache (`LatentPagedDecoder`): a token keeps one
-row [c | k_pe] a layer and, beside it, its indexer key, both paged by the
-same block tables and priced by the allocator. A decode step scores
-every cached indexer key of a slot, takes the exact top-k and reads only
-the chosen latent rows, in the absorbed form (the key up-projection
-folded into the query, the value up-projection into the output). A
-prompt is prefilled in chunks against the cache: a flash kernel forms
-the heads' keys and values from the latent rows and attends them masked
-to each query's selection.
+row [c | k_pe] a layer (the MTP layer's too) and, in the sparse
+configuration, its indexer key beside it, both paged by the same block
+tables and priced by the allocator. Decode attends in the absorbed form
+(the key up-projection folded into the query, the value up-projection
+into the output). In the sparse configuration a decode step scores every
+cached indexer key of a slot, takes the exact top-k and reads only the
+chosen latent rows; in the dense one `mla_paged_decode_attention` reads
+every latent row of the slot through its block table. A prompt is
+prefilled in chunks against the cache: a flash kernel forms the heads'
+keys and values from the latent rows and attends them, masked to each
+query's selection (sparse) or causal (dense). A dense configuration with
+an MTP layer also serves `spec_decode="mtp"`: each step of the decode
+chunk is one verify pass that drafts its next token on the device
+(`LatentPagedDecoder._verify_step`).
 """
 from __future__ import annotations
 
@@ -74,7 +96,9 @@ INDEX_NORM_EPS = 1e-6
 class DeepseekV32Config:
     """The published keys of a `deepseek_v32` `config.json` that shape the
     language model, under their own names, plus `experts_held` (which
-    routed experts this chip holds; default all) and `dtype`."""
+    routed experts this chip holds; default all) and `dtype`.
+    `index_topk=None` makes the dense member (no indexer);
+    `num_nextn_predict_layers` 1 keeps an MTP layer."""
 
     def __init__(self, vocab_size=129280, hidden_size=7168,
                  intermediate_size=18432, moe_intermediate_size=2048,
@@ -87,14 +111,19 @@ class DeepseekV32Config:
                  n_shared_experts=1, routed_scaling_factor=2.5,
                  norm_topk_prob=True, rms_norm_eps=1e-6, rope_theta=10000,
                  rope_scaling=None, max_position_embeddings=163840,
-                 experts_held=None, dtype="float32"):
+                 num_nextn_predict_layers=0, experts_held=None,
+                 dtype="float32"):
         if n_routed_experts % n_group or topk_group > n_group:
             raise ValueError(f"{n_routed_experts} experts do not split into "
                              f"{n_group} groups of which {topk_group} are "
                              f"kept")
-        if qk_rope_head_dim % 2 or qk_rope_head_dim > index_head_dim:
+        if qk_rope_head_dim % 2 or (index_topk is not None
+                                    and qk_rope_head_dim > index_head_dim):
             raise ValueError("the rotary dims must be even and fit the "
                              "indexer's head")
+        if int(num_nextn_predict_layers or 0) > 1:
+            raise ValueError(f"{num_nextn_predict_layers} MTP layers: one "
+                             f"is served")
         self.vocab_size = vocab_size
         self.hidden_size = hidden_size
         self.intermediate_size = intermediate_size
@@ -107,7 +136,7 @@ class DeepseekV32Config:
         self.qk_rope_head_dim = qk_rope_head_dim
         self.v_head_dim = v_head_dim
         self.index_n_heads, self.index_head_dim = index_n_heads, index_head_dim
-        self.index_topk = int(index_topk)
+        self.index_topk = None if index_topk is None else int(index_topk)
         self.n_routed_experts = n_routed_experts
         self.num_experts_per_tok = num_experts_per_tok
         self.n_group, self.topk_group = int(n_group), int(topk_group)
@@ -118,6 +147,7 @@ class DeepseekV32Config:
         self.rope_theta = rope_theta
         self.rope_scaling = dict(rope_scaling or {})
         self.max_position_embeddings = max_position_embeddings
+        self.num_nextn_predict_layers = int(num_nextn_predict_layers or 0)
         first, count = experts_held or (0, n_routed_experts)
         if not 0 <= first <= first + count <= n_routed_experts:
             raise ValueError(f"experts_held {(first, count)} outside the "
@@ -132,7 +162,14 @@ class DeepseekV32Config:
         return (LATENT,) * self.num_hidden_layers
 
     def is_sparse(self, l):
+        """Whether block l is an expert block (the MTP block, l =
+        num_hidden_layers, is one)."""
         return l >= self.first_k_dense_replace
+
+    @property
+    def has_indexer(self):
+        """The sparse member: a learned indexer chooses the keys."""
+        return self.index_topk is not None
 
     @property
     def qk_head_dim(self):
@@ -156,49 +193,63 @@ class DeepseekV32Config:
 
     def param_shapes(self):
         """Ordered {parameter name: (shape, float32 only?)}. Matrices are
-        [in, out]; an expert stack is [experts held, in, out]."""
+        [in, out]; an expert stack is [experts held, in, out]. The MTP
+        layer's parameters are `mtp.<leaf>`, its block's under the main
+        blocks' leaf names."""
         h, v = self.hidden_size, self.vocab_size
+        out = {"embed": ((v, h), False)}
+        for i in range(self.num_hidden_layers):
+            out.update(self._block_shapes(f"layers.{i}.", i))
+        out["norm"] = ((h,), False)
+        out["head"] = ((h, v), False)
+        if self.num_nextn_predict_layers:
+            out.update({"mtp.enorm": ((h,), False),
+                        "mtp.hnorm": ((h,), False),
+                        "mtp.eh_proj": ((2 * h, h), False)})
+            out.update(self._block_shapes("mtp.", self.num_hidden_layers))
+            out["mtp.norm"] = ((h,), False)
+        return out
+
+    def _block_shapes(self, pre, i):
+        h = self.hidden_size
         nh, qr, kvr = self.num_attention_heads, self.q_lora_rank, \
             self.kv_lora_rank
         dr, dv = self.qk_rope_head_dim, self.v_head_dim
         ih, idim = self.index_n_heads, self.index_head_dim
         f, fe = self.intermediate_size, self.moe_intermediate_size
         held = self.experts_held[1]
-        out = {"embed": ((v, h), False)}
-        for i in range(self.num_hidden_layers):
-            pre = f"layers.{i}."
+        out = {
+            pre + "ln1": ((h,), False), pre + "wq_a": ((h, qr), False),
+            pre + "q_norm": ((qr,), False),
+            pre + "wq_b": ((qr, nh * self.qk_head_dim), False),
+            pre + "wkv_a": ((h, kvr + dr), False),
+            pre + "kv_norm": ((kvr,), False),
+            pre + "wkv_b": ((kvr, nh * (self.qk_nope_head_dim + dv)),
+                            False),
+            pre + "wo": ((nh * dv, h), False)}
+        if self.has_indexer:
             out.update({
-                pre + "ln1": ((h,), False), pre + "wq_a": ((h, qr), False),
-                pre + "q_norm": ((qr,), False),
-                pre + "wq_b": ((qr, nh * self.qk_head_dim), False),
-                pre + "wkv_a": ((h, kvr + dr), False),
-                pre + "kv_norm": ((kvr,), False),
-                pre + "wkv_b": ((kvr, nh * (self.qk_nope_head_dim + dv)),
-                                False),
-                pre + "wo": ((nh * dv, h), False),
                 pre + "wq_idx": ((qr, ih * idim), False),
                 pre + "wk_idx": ((h, idim), False),
                 pre + "k_norm": ((idim,), False),
                 pre + "k_norm_b": ((idim,), False),
-                pre + "w_idx": ((h, ih), False),
-                pre + "ln2": ((h,), False)})
-            if self.is_sparse(i):
-                fs = fe * self.n_shared_experts
-                out.update({
-                    pre + "router": ((h, self.n_routed_experts), True),
-                    pre + "b_corr": ((self.n_routed_experts,), True),
-                    pre + "w1": ((held, h, fe), False),
-                    pre + "w3": ((held, h, fe), False),
-                    pre + "w2": ((held, fe, h), False),
-                    pre + "ws_g": ((h, fs), False),
-                    pre + "ws_u": ((h, fs), False),
-                    pre + "ws_d": ((fs, h), False)})
-            else:
-                out.update({pre + "wg": ((h, f), False),
-                            pre + "wu": ((h, f), False),
-                            pre + "wd": ((f, h), False)})
-        out["norm"] = ((h,), False)
-        out["head"] = ((h, v), False)
+                pre + "w_idx": ((h, ih), False)})
+        out[pre + "ln2"] = ((h,), False)
+        if self.is_sparse(i):
+            fs = fe * self.n_shared_experts
+            out.update({
+                pre + "router": ((h, self.n_routed_experts), True),
+                pre + "b_corr": ((self.n_routed_experts,), True),
+                pre + "w1": ((held, h, fe), False),
+                pre + "w3": ((held, h, fe), False),
+                pre + "w2": ((held, fe, h), False),
+                pre + "ws_g": ((h, fs), False),
+                pre + "ws_u": ((h, fs), False),
+                pre + "ws_d": ((fs, h), False)})
+        else:
+            out.update({pre + "wg": ((h, f), False),
+                        pre + "wu": ((h, f), False),
+                        pre + "wd": ((f, h), False)})
         return out
 
 
@@ -286,7 +337,7 @@ def project(cfg, p, h, pos):
     """One layer's projections of h [T, H] at positions pos [T]: (q [T,
     nh, dn + dr] with the rotary term on its last dr dims, the latent
     rows [T, kvr + dr] = [c | k_pe], and the indexer's qI [T, ih, id], kI
-    [T, id], wI [T, ih] float32)."""
+    [T, id], wI [T, ih] float32; three Nones without an indexer)."""
     t, dtype = h.shape[0], h.dtype
     eps, nh = cfg.rms_norm_eps, cfg.num_attention_heads
     dn, kvr = cfg.qk_nope_head_dim, cfg.kv_lora_rank
@@ -298,6 +349,8 @@ def project(cfg, p, h, pos):
     latent = jnp.concatenate([_rms(kv[:, :kvr], p["kv_norm"], eps),
                               rope_interleaved(cfg, kv[:, kvr:], pos)],
                              axis=-1)
+    if not cfg.has_indexer:
+        return q, latent, None, None, None
     ih, idim = cfg.index_n_heads, cfg.index_head_dim
     qi = (cq @ p["wq_idx"].astype(dtype)).reshape(t, ih, idim)
     qi = rope_halves(cfg, qi, pos)
@@ -392,38 +445,68 @@ def attend_expanded(cfg, q, k, v, sel):
     return o.astype(q.dtype).reshape(q.shape[0], -1)
 
 
-def mlp(cfg, l, p, x, active=None):
+def mlp(cfg, l, p, x, active=None, experts_scope="moe.experts"):
     """The second half of block l on the residual stream x [T, H]: (x,
-    MoE counts; `NO_COUNTS` from a dense layer)."""
+    MoE counts; `NO_COUNTS` from a dense layer). The routed experts'
+    grouped products run under `experts_scope`."""
     h2 = _rms(x, p["ln2"], cfg.rms_norm_eps)
     if not cfg.is_sparse(l):
         return x + swiglu(p, h2), jnp.asarray(NO_COUNTS)
     with jax.named_scope("moe.route"):
         idx, weights = moe_route(cfg, p, h2)
-    r, counts = moe_experts(cfg, p, h2, idx, weights, active)
+    r, counts = moe_experts(cfg, p, h2, idx, weights, active, experts_scope)
     shared = swiglu({"wg": p["ws_g"], "wu": p["ws_u"], "wd": p["ws_d"]}, h2)
     return x + r.astype(x.dtype) + shared, counts
 
 
-def forward_sequence(cfg, params, ids):
+def block_sequence(cfg, l, p, x, pos):
+    """Block l on one whole sequence x [T, H] at positions pos [T] (plain
+    XLA, [T, T] scores)."""
+    t = x.shape[0]
+    causal = pos[None, :] <= pos[:, None]
+    h = _rms(x, p["ln1"], cfg.rms_norm_eps)
+    q, latent, qi, ki, wi = project(cfg, p, h, pos)
+    sel = causal if qi is None else topk_mask(
+        index_scores(qi, wi, ki), causal, min(cfg.index_topk, t))
+    k, v = expand(cfg, p, latent)
+    o = attend_expanded(cfg, q, k, v, sel)
+    x = x + o @ p["wo"].astype(x.dtype)
+    x, _ = mlp(cfg, l, p, x)
+    return x
+
+
+def mtp_input(cfg, params, hn, next_ids):
+    """The MTP block's input [T, H]: Weh [RMS(emb(next); enorm) ;
+    RMS(hn; hnorm)] for the main model's normed last hidden states hn [T,
+    H] and the ids of the tokens after them."""
+    m, eps = params["mtp"], cfg.rms_norm_eps
+    e = jnp.take(params["embed"], next_ids, axis=0).astype(hn.dtype)
+    both = jnp.concatenate([_rms(e, m["enorm"], eps),
+                            _rms(hn, m["hnorm"], eps)], axis=-1)
+    return both @ m["eh_proj"].astype(hn.dtype)
+
+
+def forward_sequence(cfg, params, ids, with_mtp=False):
     """Full causal forward over one sequence ids [T]: logits [T, V]
-    float32 (plain XLA, [T, T] scores: for tests and short sequences)."""
+    float32 (plain XLA, [T, T] scores: for tests and short sequences).
+    `with_mtp`: (logits, the MTP layer's draft logits [T, V]), row i's
+    from (hn_i, emb(ids[i + 1])), the last row's from the greedy token
+    after the sequence."""
     t = ids.shape[0]
     pos = jnp.arange(t, dtype=jnp.int32)
-    causal = pos[None, :] <= pos[:, None]
     x = jnp.take(params["embed"], ids, axis=0)
     for l in range(cfg.num_hidden_layers):
-        p = params["layers"][l]
-        h = _rms(x, p["ln1"], cfg.rms_norm_eps)
-        q, latent, qi, ki, wi = project(cfg, p, h, pos)
-        sel = topk_mask(index_scores(qi, wi, ki), causal,
-                        min(cfg.index_topk, t))
-        k, v = expand(cfg, p, latent)
-        o = attend_expanded(cfg, q, k, v, sel)
-        x = x + o @ p["wo"].astype(x.dtype)
-        x, _ = mlp(cfg, l, p, x)
-    x = _rms(x, params["norm"], cfg.rms_norm_eps)
-    return x.astype(F32) @ params["head"].astype(F32)
+        x = block_sequence(cfg, l, params["layers"][l], x, pos)
+    hn = _rms(x, params["norm"], cfg.rms_norm_eps)
+    logits = hn.astype(F32) @ params["head"].astype(F32)
+    if not with_mtp:
+        return logits
+    nxt = jnp.concatenate([ids[1:], jnp.argmax(logits[-1:], axis=-1)
+                           .astype(ids.dtype)])
+    u = block_sequence(cfg, cfg.num_hidden_layers, params["mtp"],
+                       mtp_input(cfg, params, hn, nxt), pos)
+    u = _rms(u, params["mtp"]["norm"], cfg.rms_norm_eps)
+    return logits, u.astype(F32) @ params["head"].astype(F32)
 
 
 # -- the dygraph model ----------------------------------------------------------------
@@ -465,7 +548,8 @@ class DeepseekV32ForCausalLM(Layer):
     @staticmethod
     def _draw(rng, name, shape):
         kind = name.rsplit(".", 1)[-1]
-        if kind in ("ln1", "ln2", "norm", "q_norm", "kv_norm", "k_norm"):
+        if kind in ("ln1", "ln2", "norm", "q_norm", "kv_norm", "k_norm",
+                    "enorm", "hnorm"):
             return np.ones(shape, np.float32)
         if kind == "k_norm_b":
             return np.zeros(shape, np.float32)
@@ -475,14 +559,16 @@ class DeepseekV32ForCausalLM(Layer):
 
     def param_tree(self):
         """The parameters as the operators take them: {"embed", "norm",
-        "head", "layers": [one dict a block]}; the arrays themselves, no
-        copy."""
+        "head", "layers": [one dict a block]} and, with an MTP layer,
+        "mtp": {its leaves}; the arrays themselves, no copy."""
         tree = {"layers": [{} for _ in range(self.config.num_hidden_layers)]}
         for name, attr in self._names.items():
             data = getattr(self, attr)._data
             if name.startswith("layers."):
                 _, i, leaf = name.split(".")
                 tree["layers"][int(i)][leaf] = data
+            elif name.startswith("mtp."):
+                tree.setdefault("mtp", {})[name[4:]] = data
             else:
                 tree[name] = data
         return tree
@@ -505,14 +591,15 @@ class LatentPagedDecoder(PagedDecoder):
     are the ones every engine runs; the cache it carries chunk to chunk
     is
 
-        (lat [L, NB, bs, W], idx [L, NB, bs, id])
+        (lat [L, NB, bs, W], idx [L, NB, bs, id])    sparse configuration
+        (lat [L (+ 1: the MTP layer), NB, bs, W],)    dense configuration
 
-    both donated and updated in place and paged by the one block table:
-    a token's latent row [c | k_pe] (`W` = kv_lora_rank + rope dims
-    rounded up to whole lanes, zeros behind) and its indexer key. An
-    admission is priced in these blocks.
+    donated and updated in place and paged by the one block table: a
+    token's latent row [c | k_pe] (`W` = kv_lora_rank + rope dims
+    rounded up to whole lanes, zeros behind) and, sparse, its indexer
+    key. An admission is priced in these blocks.
 
-    A decode step scores every cached indexer key of a slot
+    Sparse: a decode step scores every cached indexer key of a slot
     (`lightning_index_decode` copies the slot's live key blocks itself,
     through its table), takes the exact top `index_topk` (`topk_mask`)
     and gathers only the chosen latent rows, attended in the absorbed
@@ -523,27 +610,46 @@ class LatentPagedDecoder(PagedDecoder):
     `mla_prefill_attention` attends the heads' keys and values, formed
     from the latent rows inside the kernel, masked to each row's
     selection: no [rows, k, width] gather and no expanded keys in HBM.
-    What does not compose yet refuses at construction (or,
-    for `serve()` options, at the call) with a NotImplementedError that
-    names the option."""
+
+    Dense: a decode step reads every latent row of a slot through its
+    block table (`mla_paged_decode_attention`) and a prefill chunk is
+    causal. With an MTP layer, a prompt's prefill also writes the MTP
+    layer's rows and returns the first draft beside the first token, and
+    `serve(spec_decode="mtp")` runs each step of the decode chunk as one
+    verify pass that drafts the next token on the device
+    (`_verify_step`), so it composes with the pipelined loop.
+
+    What does not compose yet refuses at construction (or, for `serve()`
+    options, at the call) with a NotImplementedError that names the
+    option and the configuration it holds for."""
 
     _prefill_donate = (5, 6)
     LANES = 128
 
     REFUSED = {
-        "weight_quant": "the latent projections have no quantized form",
-        "kv_quant": "the latent and indexer pools have no codec",
-        "prefix_cache": "a shared prefix would need its latent and "
-                        "indexer rows mapped by the radix tree",
+        "weight_quant": "the latent projections have no quantized form "
+                        "(sparse and dense)",
+        "kv_quant": "the latent pool and the sparse configuration's "
+                    "indexer pool have no codec",
+        "prefix_cache": "a shared prefix would need its latent rows (and, "
+                        "sparse, its indexer keys) mapped by the radix "
+                        "tree",
         "prefix_cache_blocks": "it sizes the prefix cache",
-        "attn_shards": "the selection is over a slot's whole context",
+        "attn_shards": "the sparse selection and the dense kernel's walk "
+                       "are over a slot's whole context",
         "shard_block_budget": "it picks attn_shards",
-        "kv_offload": "page-out has not been tried on the latent pools",
+        "kv_offload": "page-out has not been tried on the latent pools "
+                      "(sparse and dense)",
         "hbm_budget_gib": "it prices kv_offload",
-        "ragged_kernel": "decode's indexer kernel reads the keys through "
-                         "the block table and the chosen latent rows are "
-                         "gathered; there are no K and V blocks to attend",
+        "ragged_kernel": "there are no K and V blocks to attend: sparse, "
+                         "the indexer kernel reads the keys through the "
+                         "block table and the chosen latent rows are "
+                         "gathered; dense, mla_paged_decode_attention "
+                         "reads the latent rows through the block table",
     }
+    DENSE_COUNTERS = ("moe_pairs_here", "moe_pairs_all",
+                      "moe_experts_touched", "moe_max_load", "attn_rows",
+                      "attn_pairs", "latent_rows_read")
 
     def __init__(self, model, max_len=None, block_size=64, num_blocks=None,
                  max_slots=8, headroom_guard=None,
@@ -559,7 +665,7 @@ class LatentPagedDecoder(PagedDecoder):
         block_size = int(block_size)
         limit = int(max_len or cfg.max_position_embeddings)
         limit -= limit % block_size
-        if limit < cfg.index_topk:
+        if cfg.has_indexer and limit < cfg.index_topk:
             raise ValueError(f"max_len {limit} below index_topk "
                              f"{cfg.index_topk}")
         chunk = int(prefill_chunk or min(1024, limit))
@@ -571,10 +677,18 @@ class LatentPagedDecoder(PagedDecoder):
                          headroom_guard=headroom_guard, ragged_kernel=False,
                          pipelined_admission=pipelined_admission,
                          prefill_chunk=chunk)
-        # the parent's other programs (verify, COW copy) serve options
-        # this engine refuses
+        # the parent's other programs (host-side verify, COW copy) serve
+        # options this engine refuses
         self._spec_verify_jit = self._cow_copy_jit = None
         self._admit_counts = [0] * len(self.ADMIT_COUNTERS)
+        self._admit_draft = None
+        if not cfg.has_indexer:
+            # one pool: the chunk program's static arguments move up one
+            self._paged_chunk_state_jit = jax.jit(
+                self._dense_chunk_impl, donate_argnums=(1, 2, 4, 5, 7),
+                static_argnums=(8, 9))
+            self._prefill_donate = (5,)
+            self.COUNTERS = self.DENSE_COUNTERS
 
     def _prepare_weights(self, model, max_len, weight_quant):
         cfg = model.config
@@ -583,12 +697,17 @@ class LatentPagedDecoder(PagedDecoder):
         self.nh, self.nkv = cfg.num_attention_heads, 1
         self.hd, self.eps = cfg.latent_width, cfg.rms_norm_eps
         self.weight_quant = None
-        self.kv_layers = cfg.num_hidden_layers
+        # an MTP layer keeps latent rows of its own, behind the main ones
+        self.draft_layers = 0 if cfg.has_indexer \
+            else cfg.num_nextn_predict_layers
+        self.kv_layers = cfg.num_hidden_layers + self.draft_layers
         w = cfg.latent_width
         self.lat_row = -(-w // self.LANES) * self.LANES
         self._params = model.param_tree()
         body = sum(x.size * x.dtype.itemsize for x in
                    jax.tree_util.tree_leaves(self._params["layers"]))
+        body += sum(x.size * x.dtype.itemsize for x in
+                    jax.tree_util.tree_leaves(self._params.get("mtp", {})))
         body += self._params["head"].size * self._params["head"].dtype.itemsize
         self.weight_stream_bytes = {"quant": int(body), "bf16eq": int(body)}
 
@@ -596,14 +715,17 @@ class LatentPagedDecoder(PagedDecoder):
     def new_pools(self):
         dt = jnp.bfloat16 if self.cfg.dtype == "bfloat16" else F32
         shape = (self.kv_layers, self.num_blocks, self.block_size)
-        return (jnp.zeros(shape + (self.lat_row,), dt),
-                jnp.zeros(shape + (self.cfg.index_head_dim,), dt))
+        lat = jnp.zeros(shape + (self.lat_row,), dt)
+        if not self.cfg.has_indexer:
+            return (lat,)
+        return (lat, jnp.zeros(shape + (self.cfg.index_head_dim,), dt))
 
     def kv_token_bytes(self):
-        """Bytes a token keeps in ONE layer, as stored: its latent row and
-        its indexer key."""
+        """Bytes a token keeps in ONE layer, as stored: its latent row and,
+        sparse, its indexer key."""
         itemsize = 2 if self.cfg.dtype == "bfloat16" else 4
-        return (self.lat_row + self.cfg.index_head_dim) * itemsize
+        index = self.cfg.index_head_dim if self.cfg.has_indexer else 0
+        return (self.lat_row + index) * itemsize
 
     def pool_bytes(self):
         return self.num_blocks * self.bytes_per_block()
@@ -630,10 +752,21 @@ class LatentPagedDecoder(PagedDecoder):
         self._refuse("page-in", "it has not been tried on the latent pools")
 
     def serve(self, requests, spec_decode=None, **kw):
+        """`PagedDecoder.serve`; `spec_decode="mtp"` (dense, with an MTP
+        layer) drafts on the device, every other draft refuses."""
         if spec_decode is not None:
-            self._refuse("spec_decode", "a draft's verify pass would select "
-                         "keys for several rows of a slot at once")
-        return super().serve(requests, spec_decode=None, **kw)
+            if self.cfg.has_indexer:
+                self._refuse("spec_decode", "the sparse configuration's "
+                             "verify pass would select keys for several "
+                             "rows of a slot at once")
+            from .spec_decode import resolve_spec
+            spec, _ = resolve_spec(spec_decode, self)
+            if spec.draft != "mtp":
+                self._refuse(
+                    "a host-side draft (spec_decode=k, n-gram or a draft "
+                    "model)", "the dense configuration verifies the draft "
+                    "its MTP layer makes on the device: spec_decode='mtp'")
+        return super().serve(requests, spec_decode=spec_decode, **kw)
 
     # -- addressing ---------------------------------------------------------------
     @staticmethod
@@ -782,6 +915,150 @@ class LatentPagedDecoder(PagedDecoder):
         latent rows its attention read."""
         return dict(zip(self.COUNTERS, (int(v) for v in np.asarray(aux[0]))))
 
+    # -- the dense configuration: every latent row, and the MTP draft -----------------
+    def _attend_paged(self, p, q, lat, layer, tables, lens, scope):
+        """Absorbed MLA of decode rows q [S, R, nh, dn + dr] over every
+        latent row of their slot that each sees (lens [S, R] keys), read
+        by the kernel, under the named `scope`, from `layer` of the flat
+        latent pool through the block tables [S, MB]. Returns [S * R, nh
+        * dv]."""
+        from ..kernels.pallas.mla_paged_decode import (
+            mla_paged_decode_attention)
+        cfg = self.cfg
+        S, R, nh = q.shape[:3]
+        dn, kvr, dv = cfg.qk_nope_head_dim, cfg.kv_lora_rank, cfg.v_head_dim
+        wkv = p["wkv_b"].astype(q.dtype).reshape(kvr, nh, dn + dv)
+        with jax.named_scope("decode.attend"):
+            q_c = jnp.einsum("srhd,chd->srhc", q[..., :dn], wkv[..., :dn])
+            with jax.named_scope(scope):
+                o_c = mla_paged_decode_attention(
+                    q_c, q[..., dn:], lat.reshape(-1, self.block_size,
+                                                  lat.shape[-1]),
+                    tables, lens, layer * self.num_blocks, kvr,
+                    cfg.softmax_scale)
+            o = jnp.einsum("srhc,chd->srhd", o_c, wkv[..., dn:])
+        return o.reshape(S * R, nh * dv)
+
+    def _decode_block(self, p, l, x, pos, tables, act, lat_f, at,
+                      scope=None):
+        """Block l (the MTP block where l = num_hidden_layers) on decode
+        rows x [S * R, H], row r of slot s at position pos[s, r]: its
+        latent rows written at the flat rows `at` [S * R] of layer 0 (the
+        trash block where a slot is not `act` [S, R]), then attended up to
+        their own positions. Its kernels run under `scope` where one is
+        given (the MTP block's, so that the trace tells them apart), else
+        under `decode.attend.dense` and `moe.experts`. Returns (x, lat_f,
+        MoE counts)."""
+        cfg = self.cfg
+        S, R = pos.shape
+        h = _rms(x, p["ln1"], self.eps)
+        q, latent, *_ = project(cfg, p, h, pos.reshape(-1))
+        with jax.named_scope("decode.kv_pool"):
+            lat_f = self._write(lat_f, latent,
+                                at + l * self.num_blocks * self.block_size)
+        o = self._attend_paged(p, q.reshape((S, R) + q.shape[1:]), lat_f, l,
+                               tables, pos + 1, scope or "decode.attend.dense")
+        x = x + o @ p["wo"].astype(x.dtype)
+        x, counts = mlp(cfg, l, p, x, act.reshape(-1),
+                        scope or "moe.experts")
+        return x, lat_f, counts
+
+    def _decode_rows(self, params, ids, pos, tables, act, lat):
+        """The main blocks on decode rows: ids [S, R] at positions pos [S,
+        R] (`act` [S, R]: the rows of slots that advance). Returns (the
+        normed last hidden states [S * R, H], the flat pool, MoE counts,
+        attention counts int32 [3]: the rows, the (row, key) pairs and the
+        latent rows ONE block reads, a slot's once), and the flat rows
+        the rows' latent rows went to in layer 0."""
+        cfg = self.cfg
+        x = jnp.take(params["embed"], ids.reshape(-1), axis=0)
+        at = jnp.where(act, self._rows(0, tables, pos),
+                       pos % self.block_size).reshape(-1)
+        lat_f = self._flat(lat)
+        counts = jnp.asarray(NO_COUNTS)
+        for l in range(cfg.num_hidden_layers):
+            x, lat_f, c = self._decode_block(params["layers"][l], l, x, pos,
+                                             tables, act, lat_f, at)
+            counts = merge_counts(counts, c)
+        attn = jnp.stack([
+            jnp.sum(act, dtype=jnp.int32),
+            jnp.sum(jnp.where(act, pos + 1, 0), dtype=jnp.int32),
+            jnp.sum(jnp.where(act[:, 0], pos[:, -1] + 1, 0),
+                    dtype=jnp.int32)])
+        return _rms(x, params["norm"], self.eps), lat_f, counts, attn, at
+
+    def _dense_step(self, params, tokens, seqlens, tables, active, lat):
+        """One plain decode step for every slot (dense configuration).
+        Returns (logits [S, V], the pool, MoE counts int32 [4], attention
+        counts int32 [3])."""
+        hn, lat_f, counts, attn, _ = self._decode_rows(
+            params, tokens[:, None], seqlens[:, None], tables,
+            active[:, None], lat)
+        return (self._head_logits(params, hn), lat_f.reshape(lat.shape),
+                counts, attn)
+
+    def _verify_step(self, params, tok, draft, seqlens, tables, active,
+                     lat):
+        """One verify pass for every slot: its current token tok [S] at
+        position seqlens [S] and its draft [S] one position on go through
+        the main blocks as two rows (their latent rows written, each
+        attending up to itself): the target's logits [S, 2, V] and its
+        tokens g [S, 2]. Then the MTP block on (hn_p, emb g0) and
+        (hn_{p+1}, emb g1) at the same two positions (its own latent rows
+        written) proposes the draft that would follow either: cand [S,
+        2]. Rows past a rejected draft are rewritten by the next pass.
+        Returns (logits, g, cand, the pool, the main blocks' MoE counts,
+        attention counts of one main block)."""
+        cfg = self.cfg
+        S = tok.shape[0]
+        pos = seqlens[:, None] + jnp.arange(2, dtype=jnp.int32)[None]
+        act = jnp.broadcast_to(active[:, None], pos.shape)
+        hn, lat_f, counts, attn, at = self._decode_rows(
+            params, jnp.stack([tok, draft], axis=1), pos, tables, act, lat)
+        logits = self._head_logits(params, hn).reshape(S, 2, -1)
+        g = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        with jax.named_scope("decode.mtp"):
+            m = params["mtp"]
+            u, lat_f, _ = self._decode_block(
+                m, cfg.num_hidden_layers,
+                mtp_input(cfg, params, hn, g.reshape(-1)), pos, tables, act,
+                lat_f, at, "decode.mtp")
+            cand = jnp.argmax(self._head_logits(
+                params, _rms(u, m["norm"], self.eps)), axis=-1)
+        return (logits, g, cand.astype(jnp.int32).reshape(S, 2),
+                lat_f.reshape(lat.shape), counts, attn)
+
+    def _dense_chunk_impl(self, params, tok0, seqlens0, tables, live,
+                          budgets, poison, lat, n, eos_id):
+        """The dense configuration's decode chunk: `_chunk_scan` of plain
+        steps where tok0 is [S], `_draft_scan` of verify passes where it
+        is [S, 2] (each slot's token and its draft). The counters behind
+        the pool are `DENSE_COUNTERS` (int32 [7])."""
+        def tally(acc, aux, act, lens):
+            (stats, seen), (c, attn) = acc, aux
+            return merge_counts(stats, c), seen + attn
+
+        def start():
+            return jnp.asarray(NO_COUNTS), jnp.zeros(3, jnp.int32)
+
+        if tok0.ndim == 1:
+            def step(tok, lens, act, pools):
+                logits, pool, c, attn = self._dense_step(
+                    params, tok, lens, tables, act, *pools)
+                return logits, (pool,), (c, attn)
+            out, (stats, seen) = self._chunk_scan(
+                step, tok0, seqlens0, live, budgets, poison, (lat,), n,
+                eos_id, tally, start)
+        else:
+            def step(tok, draft, lens, act, pools):
+                logits, g, cand, pool, c, attn = self._verify_step(
+                    params, tok, draft, lens, tables, act, *pools)
+                return logits, g, cand, (pool,), (c, attn)
+            out, (stats, seen) = self._draft_scan(
+                step, tok0, seqlens0, live, budgets, poison, (lat,), n,
+                eos_id, tally, start)
+        return out + (jnp.concatenate([stats, seen]),)
+
     # -- the chunked prefill ------------------------------------------------------------
     def prefill_bucket(self, n):
         """Rows of the one prefill program, whatever the prompt's
@@ -800,8 +1077,11 @@ class LatentPagedDecoder(PagedDecoder):
             ids = np.full(bucket, pad, np.int32)
             piece = prompt[start:start + bucket]
             ids[:len(piece)] = piece
+            # dense: the token after the chunk, for its last MTP row
+            tail = () if self.cfg.has_indexer else (jnp.int32(
+                prompt[start + bucket] if start + bucket < n else pad),)
             calls.append(((jnp.asarray(ids), jnp.int32(start), jnp.int32(n),
-                           table), ()))
+                           table), tail))
         return calls
 
     def _prefill_paged(self, params, ids, start, true_len, table, lat, idx):
@@ -855,16 +1135,99 @@ class LatentPagedDecoder(PagedDecoder):
             jnp.sum(table != 0, dtype=jnp.int32)[None]])
         return enc, lat_f.reshape(lat.shape), idx_f.reshape(idx.shape)
 
+    def _prefill_block(self, p, l, x, pos, valid, table, lat_f, at, start,
+                       scope=None):
+        """Block l (the MTP block where l = num_hidden_layers) on a
+        prompt's chunk x [C, H] at positions pos [C] (dense
+        configuration): its latent rows written at the flat rows `at` of
+        layer 0, then the causal attention over the slot's latent rows.
+        Its kernels run under `scope` where one is given, else under
+        `prefill.attend` and `moe.experts`. Returns (x, lat_f, MoE
+        counts)."""
+        from ..kernels.pallas.mla_prefill import mla_prefill_attention
+        cfg = self.cfg
+        h = _rms(x, p["ln1"], self.eps)
+        q, latent, *_ = project(cfg, p, h, pos)
+        lat_f = self._write(lat_f, latent,
+                            at + l * self.num_blocks * self.block_size)
+        with jax.named_scope(scope or "prefill.attend"):
+            o = mla_prefill_attention(
+                q, self._context(lat_f, l, table), p["wkv_b"].astype(x.dtype),
+                None, start, cfg.kv_lora_rank, cfg.qk_rope_head_dim,
+                cfg.softmax_scale).reshape(x.shape[0], -1)
+        x = x + o @ p["wo"].astype(x.dtype)
+        x, counts = mlp(cfg, l, p, x, valid, scope or "moe.experts")
+        return x, lat_f, counts
+
+    def _prefill_dense(self, params, ids, start, true_len, table, lat, nxt):
+        """`_prefill_paged` for the dense configuration: causal, no
+        indexer. With an MTP layer the chunk's MTP rows are written too:
+        row i from (hn_i, emb of the token after it), which is the
+        chunk's next row, `nxt` (the next chunk's first token) behind
+        its last row, and the first generated token behind the prompt's
+        last row. Returns int32 [1 + 5] as `_prefill_paged` does, then,
+        with an MTP layer, the draft after the first token (the last
+        chunk's call holds it), and the pool."""
+        cfg = self.cfg
+        C = ids.shape[0]
+        pos = start + jnp.arange(C, dtype=jnp.int32)
+        valid = pos < true_len
+        x = jnp.take(params["embed"], ids, axis=0)
+        at = jnp.where(valid, self._rows(0, table[None], pos[None])[0],
+                       pos % self.block_size)
+        lat_f = self._flat(lat)
+        counts = jnp.asarray(NO_COUNTS)
+        for l in range(cfg.num_hidden_layers):
+            x, lat_f, c = self._prefill_block(params["layers"][l], l, x, pos,
+                                              valid, table, lat_f, at, start)
+            counts = merge_counts(counts, c)
+        hn = _rms(x, params["norm"], self.eps)
+        last = jnp.clip(true_len - 1 - start, 0, C - 1)
+        logits = self._head_logits(params, jnp.take(hn, last, axis=0)[None])[0]
+        enc = [self._encode_first_token(logits)[None], counts,
+               jnp.sum(table != 0, dtype=jnp.int32)[None]]
+        if self.draft_layers:
+            with jax.named_scope("prefill.mtp"):
+                m = params["mtp"]
+                after = jnp.concatenate([ids[1:], nxt[None]])
+                after = jnp.where(pos == true_len - 1,
+                                  jnp.argmax(logits).astype(ids.dtype), after)
+                u, lat_f, _ = self._prefill_block(
+                    m, cfg.num_hidden_layers,
+                    mtp_input(cfg, params, hn, after), pos, valid, table,
+                    lat_f, at, start, "prefill.mtp")
+                u = _rms(jnp.take(u, last, axis=0)[None], m["norm"], self.eps)
+                enc.append(jnp.argmax(self._head_logits(params, u)[0])
+                           .astype(jnp.int32)[None])
+        return jnp.concatenate(enc), lat_f.reshape(lat.shape)
+
+    def _prefill_exec(self, bucket):
+        """The jitted prefill chunk program (`_prefill_paged`, or
+        `_prefill_dense` in the dense configuration)."""
+        if bucket not in self._prefill_cache:
+            fn = self._prefill_paged if self.cfg.has_indexer \
+                else self._prefill_dense
+            self._prefill_cache[bucket] = jax.jit(
+                fn, donate_argnums=self._prefill_donate)
+        return self._prefill_cache[bucket]
+
     def decode_first_token(self, encs, seg=0):
         """The prompt's first token from its last chunk's result. The
         counts behind the token are summed over the prompt's chunks (the
         largest load is the largest of them) and kept for
-        `admit_metadata`."""
+        `admit_metadata`, the MTP layer's first draft for
+        `first_draft`."""
         chunks = np.stack([np.asarray(e) for e in encs])
         counts = chunks[:, 1:]
         self._admit_counts = [int(v) for v in counts[:, :3].sum(axis=0)] \
             + [int(counts[:, 3].max()), int(counts[-1, 4])]
+        self._admit_draft = int(chunks[-1, 6]) if self.draft_layers else None
         return super().decode_first_token([chunks[-1, 0]])
+
+    def first_draft(self):
+        """The token the MTP layer drafted after the first token that
+        `decode_first_token` just read."""
+        return self._admit_draft
 
     def admit_metadata(self):
         """The prompt's MoE counts under the chunk counters' names and

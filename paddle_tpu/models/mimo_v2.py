@@ -266,18 +266,19 @@ def swiglu(p, v):
     return (gate * (v @ p["wu"].astype(v.dtype))) @ p["wd"].astype(v.dtype)
 
 
-def moe_experts(cfg, p, v, idx, weights, active=None):
+def moe_experts(cfg, p, v, idx, weights, active=None, scope="moe.experts"):
     """The held experts' part of the routed sum for v [T, H]: `sum over
     chosen k held here of w_k W2_k(silu(W1_k v) * (W3_k v))`. The pairs
     are sorted by held expert (pairs of experts held elsewhere, and of
     rows that are not `active`, go last and are not computed) and the
     three products run grouped over exactly the rows each expert got: no
     capacity, no dropped pair. Returns (r [T, H] float32, counts int32
-    [4] as `nemotron_h.pair_counts` gives them)."""
+    [4] as `nemotron_h.pair_counts` gives them). The grouped products run
+    under the named `scope`."""
     from ..kernels.pallas.grouped_matmul import grouped_matmul_sorted
     t, k = idx.shape
     order, sizes, rows = sort_pairs(cfg, idx, active)
-    with jax.named_scope("moe.experts"):
+    with jax.named_scope(scope):
         xs = jnp.take(v, order // k, axis=0)
         gate = grouped_matmul_sorted(xs, p["w1"], sizes)
         up = grouped_matmul_sorted(xs, p["w3"], sizes)

@@ -154,6 +154,13 @@ class _Slot:
     prompt: list = field(default_factory=list)    # for draft providers
     budget: int = 0            # max_new_tokens remaining
     done: bool = False
+    # drafts the model made on the device for the slot's passes
+    # (`spec_decode="mtp"`): (index in prompt + emitted of the token each
+    # predicts, the token)
+    drafts: list = field(default_factory=list)
+    # each pass's second row, whether its draft was accepted or not:
+    # (index of the draft it verified, the target's token after the draft)
+    verified: list = field(default_factory=list)
 
 
 class PagedDecoder(CachedDecoder):
@@ -401,6 +408,9 @@ class PagedDecoder(CachedDecoder):
         # mirrored into the observability registry when telemetry is on
         self.spec_stats = {"verify_calls": 0, "proposed": 0,
                            "accepted": 0, "emitted": 0}
+        # MTP layers whose draft a decode chunk verifies on the device
+        # (`spec_decode="mtp"`): an engine that has them says so
+        self.draft_layers = getattr(self, "draft_layers", 0)
         # copy-on-write boundary-block copy: src/dst are traced scalars
         # so ONE executable serves every block pair
         self._cow_copy_jit = jax.jit(
@@ -754,15 +764,11 @@ class PagedDecoder(CachedDecoder):
             tok, lens, bad, eos, acc, pools = carry
             act = live & (i < budgets)
             logits, pools, aux = step(tok, lens, act, pools)
-            logits = jnp.where(poison[:, None],
-                               jnp.asarray(jnp.nan, logits.dtype),
-                               logits)
-            bad = bad | (act & jnp.any(~jnp.isfinite(logits), axis=-1))
+            logits, bad = PagedDecoder._poisoned(logits, poison, act, bad)
             nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             nxt = jnp.where(act, nxt, tok)
             after = jnp.where(act, lens + 1, lens)
-            if eos_id >= 0:
-                eos = eos | (act & (nxt == jnp.int32(eos_id)))
+            eos = PagedDecoder._ended(eos, act, nxt, eos_id)
             if tally is not None:
                 acc = tally(acc, aux, act, lens)
             return (nxt, after, bad, eos, acc, tuple(pools)), nxt
@@ -774,9 +780,86 @@ class PagedDecoder(CachedDecoder):
             jnp.arange(n, dtype=jnp.int32))
         took = jnp.minimum(jnp.int32(n), jnp.maximum(budgets, 0))
         budgets = jnp.where(live, budgets - took, budgets)
-        live_out = live & (budgets > 0) & ~eos
+        live_out = PagedDecoder._still_live(live, budgets, eos)
         return (jnp.swapaxes(toks, 0, 1), bad, tok, lens, live_out,
                 budgets) + pools, acc
+
+    # -- the arithmetic `_chunk_scan` and `_draft_scan` share ------------
+    @staticmethod
+    def _poisoned(logits, poison, act, bad):
+        """A step's logits [S, ...] with a poisoned slot's made NaN, and
+        `bad` raised where a live slot's are not finite."""
+        lead = (slice(None),) + (None,) * (logits.ndim - 1)
+        logits = jnp.where(poison[lead], jnp.asarray(jnp.nan, logits.dtype),
+                           logits)
+        return logits, bad | (act & jnp.any(
+            ~jnp.isfinite(logits), axis=tuple(range(1, logits.ndim))))
+
+    @staticmethod
+    def _ended(eos, act, last, eos_id):
+        """`eos` raised where a live slot's last token this step is eos
+        (a slot stops at the chunk's end, or sooner where the step says)."""
+        if eos_id >= 0:
+            eos = eos | (act & (last == jnp.int32(eos_id)))
+        return eos
+
+    @staticmethod
+    def _still_live(live, budgets, eos):
+        """Live after a chunk: budget left and no eos emitted."""
+        return live & (budgets > 0) & ~eos
+
+    @staticmethod
+    def _draft_scan(step, tok0, seqlens0, live, budgets, poison, pools, n,
+                    eos_id, tally=None, acc=lambda: ()):
+        """`_chunk_scan` for a model that drafts its own next token on the
+        device (`spec_decode="mtp"`): each of the n steps is one verify
+        pass, which yields one or two tokens a slot. tok0 [S, 2] holds
+        each slot's current token (at position seqlens) and its draft.
+        `step(tok, draft, lens, act, pools) -> (logits [S, 2, V], g [S,
+        2], cand [S, 2], pools, aux)`: the target's logits and tokens at
+        the current token's row and the draft's, and the draft that
+        would follow either. A pass emits g0, and g1 too where the draft
+        was g0 (greedy verification: the stream is plain greedy decode's),
+        within the slot's budget and up to an eos, after which the slot
+        stops; the next pass starts behind the last token emitted, with
+        the draft that follows it. Returns ((passes [S, n, 4] int32 = (g0,
+        g1, tokens emitted 0..2, the draft after them), bad, tok' [S, 2],
+        seqlens', live', budgets') + pools, acc).
+
+        Two things differ from `_chunk_scan`, because a pass emits a
+        count the host cannot know before the chunk comes home: the
+        budget left is carried (`_chunk_scan` reads it off the step's
+        index), and a slot stops at its eos inside the chunk
+        (`_chunk_scan`'s runs on to the chunk's end and the host trims),
+        so each pass's count is what the host commits."""
+        def body(carry, i):
+            tok, draft, lens, left, bad, eos, acc, pools = carry
+            act = live & (left > 0) & ~eos
+            logits, g, cand, pools, aux = step(tok, draft, lens, act, pools)
+            logits, bad = PagedDecoder._poisoned(logits, poison, act, bad)
+            two = (g[:, 0] == draft) & (left >= 2)
+            if eos_id >= 0:
+                two = two & (g[:, 0] != jnp.int32(eos_id))
+            emitted = jnp.where(act, 1 + two.astype(jnp.int32), 0)
+            nxt = jnp.where(two, g[:, 1], g[:, 0])
+            after = jnp.where(two, cand[:, 1], cand[:, 0])
+            eos = PagedDecoder._ended(eos, act, nxt, eos_id)
+            if tally is not None:
+                acc = tally(acc, aux, act, lens)
+            out = jnp.stack([g[:, 0], g[:, 1], emitted, after], axis=1)
+            return (jnp.where(act, nxt, tok), jnp.where(act, after, draft),
+                    lens + emitted, left - emitted, bad, eos, acc,
+                    tuple(pools)), out
+
+        bad0 = jnp.zeros(tok0.shape[:1], bool)
+        (tok, draft, lens, left, bad, eos, acc, pools), passes = \
+            jax.lax.scan(body, (tok0[:, 0], tok0[:, 1], seqlens0, budgets,
+                                bad0, jnp.zeros_like(bad0), acc(),
+                                tuple(pools)),
+                         jnp.arange(n, dtype=jnp.int32))
+        live_out = PagedDecoder._still_live(live, left, eos)
+        return (jnp.swapaxes(passes, 0, 1), bad, jnp.stack([tok, draft], 1),
+                lens, live_out, left) + pools, acc
 
     def _spec_verify_impl(self, params, toks, seqlens, tables, live,
                           budgets, poison, kpool, vpool):

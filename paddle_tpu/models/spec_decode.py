@@ -15,6 +15,14 @@ token-identical to plain greedy decode — the draft only changes HOW
 FAST tokens appear, never WHICH tokens (tier-1 gate in
 tests/test_kv_quant_spec.py).
 
+A model with a multi-token-prediction (MTP) layer drafts for itself ON
+THE DEVICE (`spec_decode="mtp"`): its engine's decode chunk runs verify
+passes whose MTP rows propose the next pass's draft
+(`PagedDecoder._draft_scan`; the dense latent engine,
+models/deepseek_v32.py), so no host work sits between passes and the
+pipelined loop keeps its look-ahead. The host-side providers below draft
+between device calls instead.
+
 Draft providers (one host-side interface, swappable):
 
 - NGramDraft — self-speculative prompt-lookup (no extra model): match
@@ -109,8 +117,9 @@ class ModelDraft(DraftProvider):
 @dataclass
 class SpecConfig:
     """k: drafted tokens per verify pass (the verify executable row
-    count is k+1; one executable per distinct k). draft: "ngram" or a
-    DraftProvider instance."""
+    count is k+1; one executable per distinct k). draft: "ngram", a
+    DraftProvider instance, or "mtp" (the model's own MTP layer drafts on
+    the device: no host-side provider)."""
     k: int = 4
     draft: object = "ngram"
     max_ngram: int = 3
@@ -118,6 +127,8 @@ class SpecConfig:
     def provider(self):
         if isinstance(self.draft, DraftProvider):
             return self.draft
+        if self.draft == "mtp":
+            return None
         if self.draft == "ngram":
             return NGramDraft(max_ngram=self.max_ngram)
         raise ValueError(f"unknown draft kind {self.draft!r}")
@@ -126,11 +137,20 @@ class SpecConfig:
 def resolve_spec(spec, decoder=None):
     """Normalize serve(spec_decode=...) inputs to (SpecConfig, provider).
     Accepts None, an int k, "auto" (autotune-cached draft length for
-    this model geometry, default 4), a dict of SpecConfig fields, or a
-    SpecConfig."""
+    this model geometry, default 4), "mtp" (k = the decoder's MTP layers,
+    drafted on the device; provider None), a dict of SpecConfig fields,
+    or a SpecConfig."""
     if spec is None:
         return None, None
-    if spec == "auto":
+    if spec == "mtp":
+        layers = getattr(decoder, "draft_layers", 0)
+        if not layers:
+            raise NotImplementedError(
+                "spec_decode='mtp' needs an engine whose model drafts on "
+                "the device with a multi-token-prediction layer; this one "
+                "has none")
+        spec = SpecConfig(k=int(layers), draft="mtp")
+    elif spec == "auto":
         k = None
         if decoder is not None:
             from ..kernels.autotune import lookup_spec_decode

@@ -59,6 +59,12 @@ adds counter mirrors, the step ledger's clock reads (`execute` = the
 loop's waits, `compile` = what the compile listener heard) and, before a
 program's first call, an analysis copy (`observability/programs.py`).
 
+A model that drafts on the device (`spec_decode="mtp"`) keeps the fused
+path: each step of its chunk is a verify pass
+(`PagedDecoder._draft_scan`), the device state's token is [S, 2] (the
+token and its draft), and the commit takes the one or two tokens each
+pass emitted, so look-ahead and pipelined admission compose with it.
+
 PT_PIPE_TEETH (CI mutation hooks, tools/serving_drill.py
 --verify-teeth): "force_sync" re-uploads the full state every chunk
 (the h2d/host_gap gates must trip); "mutate_feedback" corrupts one
@@ -95,6 +101,17 @@ def serve_loop(eng, requests, *, max_new_tokens=32, eos_token_id=None,
     from ..models.paged_decode import _Slot
     from ..models.spec_decode import resolve_spec
     spec_cfg, draft = resolve_spec(spec_decode, eng)
+    # the model's own MTP layer drafts inside the chunk program: the
+    # fused path, with no host-side provider between passes
+    drafting = spec_cfg is not None and spec_cfg.draft == "mtp"
+    if drafting:
+        if spec_cfg.k != eng.draft_layers:
+            raise NotImplementedError(
+                f"spec_decode k={spec_cfg.k} with drafts on the device: "
+                f"the engine's {eng.draft_layers} MTP layer(s) draft "
+                f"{eng.draft_layers} token(s) a pass; more would chain "
+                f"them on their own drafts")
+        spec_cfg = None
     if pipeline is True and spec_cfg is not None:
         # explicit refusal, not a silent fallback: the verify pass is
         # host-interactive by construction (draft proposals come from
@@ -159,6 +176,8 @@ def serve_loop(eng, requests, *, max_new_tokens=32, eos_token_id=None,
     bs = eng.block_size
     MB = eng.blocks_per_seq
     tokens = np.zeros(eng.max_slots, np.int32)
+    # each slot's draft, where the model drafts on the device
+    drafts = np.zeros(eng.max_slots, np.int32)
     seqlens = np.zeros(eng.max_slots, np.int32)
     tables = np.zeros((eng.max_slots, MB), np.int32)
     live = np.zeros(eng.max_slots, bool)
@@ -559,7 +578,8 @@ def serve_loop(eng, requests, *, max_new_tokens=32, eos_token_id=None,
             mark_state_dirty()
         uploads = 0
         if dev["state"] is None:
-            tok_up = tokens.copy()
+            tok_up = np.stack([tokens, drafts], axis=1) if drafting \
+                else tokens.copy()
             if pipe_teeth == "mutate_feedback" and live.any():
                 # teeth: corrupt one feedback token AT UPLOAD — the
                 # parity gate must catch the divergent stream
@@ -587,7 +607,7 @@ def serve_loop(eng, requests, *, max_new_tokens=32, eos_token_id=None,
         st = dev["state"]
         args = (eng._params,) + st + pools + (n, eos_dev)
         if telemetry:
-            analysed(("chunkst_n", int(n), eos_dev),
+            analysed(("chunkst_n", int(n), eos_dev, drafting),
                      eng._paged_chunk_state_jit, args)
         t_disp = time.perf_counter()
         with _obs.span("serve:chunk", steps=int(n),
@@ -651,26 +671,73 @@ def serve_loop(eng, requests, *, max_new_tokens=32, eos_token_id=None,
         last_ready[0] = t_ready
         with _obs.span("serve:commit") as sp:
             took, live_before = 0, int(live.sum())
+            proposed = accepted = 0
             for i, s_ref in rec["slots"]:
                 if not live[i] or eng._slots[i] is not s_ref:
                     continue
                 if quarantine_on and bad[i]:
                     quarantine(i, ct0, t_ready, time.perf_counter())
                     continue
-                take = min(n_eff, eng._slots[i].budget)
-                advance(i, [int(t) for t in toks[i, :take]], ct0,
-                        t_ready)
-                took += take
+                if drafting:
+                    # a pass's (g0, g1, emitted, draft after them)
+                    passes = toks[i, :n_eff]
+                    emit = [int(t) for g0, g1, k, _ in passes
+                            for t in (g0, g1)[:k]][:eng._slots[i].budget]
+                    made = passes[passes[:, 2] > 0]
+                    if not len(made):
+                        continue
+                    drafts[i] = made[-1, 3]
+                    proposed += len(made)
+                    accepted += int(np.sum(made[:, 2] == 2))
+                    # each pass's draft, by the index of the token it
+                    # predicts
+                    slot = eng._slots[i]
+                    at = len(slot.prompt) + len(slot.emitted) \
+                        + np.cumsum(made[:, 2])
+                    slot.drafts.extend(zip(at.tolist(),
+                                           made[:, 3].tolist()))
+                    slot.verified.extend(zip((at - made[:, 2]).tolist(),
+                                             made[:, 1].tolist()))
+                else:
+                    emit = [int(t) for t in
+                            toks[i, :min(n_eff, eng._slots[i].budget)]]
+                advance(i, emit, ct0, t_ready)
+                took += len(emit)
             # `steps` the device ran for this chunk, `committed` of them
-            # kept (fewer where a look-ahead chunk was trimmed)
+            # kept (fewer where a look-ahead chunk was trimmed), in rows:
+            # a verify pass computes two a slot
+            rows = 2 if drafting else 1
+            extra = dict(drafted=proposed, accepted=accepted) \
+                if drafting else {}
             sp.set(tokens=took, retired=live_before - int(live.sum()),
-                   steps=rec["n"], committed=int(n_eff), **counters)
+                   steps=rec["n"] * rows, committed=int(n_eff) * rows,
+                   **extra, **counters)
+        if drafting:
+            note_drafts(int(n_eff), proposed, accepted, took)
         if n_eff < rec["n"]:
             # the device ran the full overshot chunk — its state is
             # ahead of the trimmed mirrors; resync at next dispatch
             # (the extra pool writes hold exactly the tokens the next
             # chunk re-derives, so rewriting them is value-identical)
             mark_state_dirty()
+
+    def note_drafts(passes, proposed, accepted, emitted):
+        """The speculative tallies of committed passes (one draft a live
+        slot a pass), from the tokens the chunk sent home."""
+        st = eng.spec_stats
+        st["verify_calls"] += passes
+        st["proposed"] += proposed
+        st["accepted"] += accepted
+        st["emitted"] += emitted
+        if telemetry:
+            reg = _obs.registry()
+            reg.counter("paddle_tpu_spec_decode_verify_calls_total",
+                        "speculative batched-verify passes").inc(passes)
+            reg.counter("paddle_tpu_spec_decode_proposed_total",
+                        "draft tokens proposed").inc(proposed)
+            reg.counter("paddle_tpu_spec_decode_accepted_total",
+                        "draft tokens accepted by greedy "
+                        "verification").inc(accepted)
 
     def admit_payload(i, req_id, payload, max_new, t_admit, sp):
         """Streamed-KV admission (prefill/decode disaggregation): the
@@ -978,6 +1045,9 @@ def serve_loop(eng, requests, *, max_new_tokens=32, eos_token_id=None,
         slot.emitted.append(first)
         slot.budget -= 1
         tokens[i] = first
+        if drafting:
+            drafts[i] = eng.first_draft()
+            slot.drafts.append((s0 + 1, int(drafts[i])))
         seqlens[i] = s0
         hit_eos = (eos_token_id is not None
                    and first == eos_token_id)
@@ -1353,7 +1423,8 @@ def serve_loop(eng, requests, *, max_new_tokens=32, eos_token_id=None,
                         extra={"live_slots": int(live.sum()),
                                "chunk_steps": (int(spec_cfg.k + 1)
                                                if spec_cfg is not None
-                                               else int(fused_steps))})
+                                               else int(fused_steps)
+                                               * (2 if drafting else 1))})
     except BaseException:
         # the engine may be unusable, but the OBSERVABILITY
         # must stay truthful: drop this call's unfinished

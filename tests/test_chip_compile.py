@@ -42,12 +42,14 @@ def for_the_chip(monkeypatch):
     from paddle_tpu.kernels.pallas import (flash_attention, flash_prefill,
                                            fused_elementwise,
                                            grouped_matmul, lightning_index,
-                                           mla_decode, mla_prefill,
+                                           mla_decode, mla_paged_decode,
+                                           mla_prefill,
                                            ragged_paged_attention, rms_norm,
                                            ssm_update)
     for mod in (flash_attention, flash_prefill, fused_elementwise,
-                grouped_matmul, lightning_index, mla_decode, mla_prefill,
-                ragged_paged_attention, rms_norm, ssm_update):
+                grouped_matmul, lightning_index, mla_decode,
+                mla_paged_decode, mla_prefill, ragged_paged_attention,
+                rms_norm, ssm_update):
         monkeypatch.setattr(mod, "_interpret", lambda: False)
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
@@ -496,3 +498,97 @@ def test_latent_prefill_program(latent, for_the_chip):
     text = _fits_beside_its_pools(compiled, pools)
     assert "%prefill.index" in text and "%prefill.attend" in text
     assert not re.search(r"bf16\[16384,128,\d+\]", text)
+
+
+# -- the dense latent engine with its MTP draft (glm47_flash_serve_mtp_13k) ------
+
+@pytest.mark.parametrize("rows", [1, 2])
+def test_mla_paged_decode(one_chip, for_the_chip, rows):
+    """The dense absorbed decode kernel at the cell's widths (20 heads,
+    latent 512 + 64 in 640 lanes, 48 slots, 208 blocks of 64 a slot,
+    7 layers of 7,680 blocks), one row a slot and the verify pass's two:
+    it reads the pool through the block table, no copy of a slot's rows."""
+    from paddle_tpu.kernels.pallas.mla_paged_decode import (
+        mla_paged_decode_attention)
+    S, MB, NB = 48, 208, 7 * 7680
+    text = _compiled_text(
+        lambda qc, qp, pool, tab, lens: mla_paged_decode_attention(
+            qc, qp, pool, tab, lens, 7680, 512, 256 ** -0.5),
+        one_chip, ((S, rows, 20, 512), BF16), ((S, rows, 20, 64), BF16),
+        ((NB, 64, 640), BF16), ((S, MB), jnp.int32), ((S, rows), jnp.int32))
+    assert "tpu_custom_call" in text
+    assert not re.search(r"bf16\[48,\d+,64,640\]", text)
+
+
+@pytest.fixture(scope="module")
+def dense_latent(one_chip):
+    """The serving engine of `glm47_flash_l6_mtp1` with its 4.54 B
+    parameters described, not made: (decoder, pools, described)."""
+    from paddle_tpu.models.deepseek_v32 import LatentPagedDecoder
+    from paddle_tpu.models.glm4_moe_lite import Glm4MoeLiteConfig
+    cfg = Glm4MoeLiteConfig(num_hidden_layers=6, dtype="bfloat16")
+
+    def described(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+
+    class Described:
+        config = cfg
+
+        def param_tree(self):
+            tree = {"layers": [{} for _ in range(cfg.num_hidden_layers)],
+                    "mtp": {}}
+            for name, (shape, f32) in cfg.param_shapes().items():
+                leaf = described(shape, jnp.float32 if f32 else BF16)
+                if name.startswith("layers."):
+                    _, i, key = name.split(".")
+                    tree["layers"][int(i)][key] = leaf
+                elif name.startswith("mtp."):
+                    tree["mtp"][name[4:]] = leaf
+                else:
+                    tree[name] = leaf
+            return tree
+
+    dec = LatentPagedDecoder(Described(), max_len=13312, block_size=64,
+                             num_blocks=7680, max_slots=48,
+                             prefill_chunk=1024)
+    pools = tuple(described(p.shape, p.dtype)
+                  for p in jax.eval_shape(dec.new_pools))
+    return dec, pools, described
+
+
+@pytest.mark.parametrize("drafting", [True, False])
+def test_dense_latent_chunk_program(dense_latent, for_the_chip, drafting):
+    """8 decode steps for 48 slots at 13,312 positions, verify passes of
+    two rows a slot with the MTP draft (tokens [48, 2]) or plain steps:
+    the dense kernel reads the latent rows through the block table (no
+    gathered copy of a slot's rows), the MTP block runs, the grouped
+    expert products run, and the 13.5 GB of arguments fit the chip beside
+    the temporaries."""
+    dec, pools, described = dense_latent
+    S, MB = dec.max_slots, dec.blocks_per_seq
+    i32, flag = jnp.int32, jnp.bool_
+    tok = (S, 2) if drafting else (S,)
+    compiled = dec._paged_chunk_state_jit.lower(
+        dec._params, described(tok, i32), described((S,), i32),
+        described((S, MB), i32), described((S,), flag),
+        described((S,), i32), described((S,), flag), *pools, 8,
+        -1).compile()
+    text = _fits_beside_its_pools(compiled, pools)
+    assert "%decode.attend.dense" in text and "%moe.experts" in text
+    assert ("%decode.mtp" in text) == drafting
+    assert not re.search(r"bf16\[48,(13312|208,64),640\]", text)
+
+
+def test_dense_latent_prefill_program(dense_latent, for_the_chip):
+    """A 1,024-row chunk against 13,312 positions, causal, with the MTP
+    layer's rows: the attention kernel forms the heads' keys and values
+    from the latent rows, no [keys, heads, dims] array in HBM."""
+    dec, pools, described = dense_latent
+    i32 = jnp.int32
+    compiled = dec._prefill_exec(1024).lower(
+        dec._params, described((1024,), i32), described((), i32),
+        described((), i32), described((dec.blocks_per_seq,), i32),
+        *pools, described((), i32)).compile()
+    text = _fits_beside_its_pools(compiled, pools)
+    assert "%prefill.attend" in text and "%prefill.mtp" in text
+    assert not re.search(r"bf16\[13312,20,\d+\]", text)
