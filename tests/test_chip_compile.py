@@ -507,17 +507,35 @@ def test_mla_paged_decode(one_chip, for_the_chip, rows):
     """The dense absorbed decode kernel at the cell's widths (20 heads,
     latent 512 + 64 in 640 lanes, 48 slots, 208 blocks of 64 a slot,
     7 layers of 7,680 blocks), one row a slot and the verify pass's two:
-    it reads the pool through the block table, no copy of a slot's rows."""
-    from paddle_tpu.kernels.pallas.mla_paged_decode import (
-        mla_paged_decode_attention)
-    S, MB, NB = 48, 208, 7 * 7680
+    it reads the pool through the block table, no copy of a slot's rows.
+    Its scoped VMEM: a product of 16 blocks (1,024 keys), a copy group of
+    48, so the double buffer is 2 x 48 x 80 KB = 7.5 MiB, and the launch
+    declares 39.5 MiB (41,418,752 B) of the v5e's 128 MiB; the query and
+    output blocks, m, l, acc and a product's float32 scores and
+    probabilities take under 1 MiB of the 32 MiB beside the buffer."""
+    from paddle_tpu.kernels.pallas import mla_paged_decode as kernel
+    S, MB, NB, n = 48, 208, 7 * 7680, rows * 20
     text = _compiled_text(
-        lambda qc, qp, pool, tab, lens: mla_paged_decode_attention(
+        lambda qc, qp, pool, tab, lens: kernel.mla_paged_decode_attention(
             qc, qp, pool, tab, lens, 7680, 512, 256 ** -0.5),
         one_chip, ((S, rows, 20, 512), BF16), ((S, rows, 20, 64), BF16),
         ((NB, 64, 640), BF16), ((S, MB), jnp.int32), ((S, rows), jnp.int32))
     assert "tpu_custom_call" in text
     assert not re.search(r"bf16\[48,\d+,64,640\]", text)
+    block_bytes = 64 * 640 * 2
+    group, chunk = kernel._blocks_per_step(block_bytes, 64, MB)
+    assert (group, chunk) == (48, 16)
+    declared = [int(size) for line in text.splitlines()
+                if "tpu_custom_call" in line
+                for size in re.findall(
+                    r'"scoped_memory_configs":\[\{"memory_space":"1",'
+                    r'"offset":"0","size":"(\d+)"', line)]
+    assert declared == [2 * group * block_bytes + 32 * 2**20] == [41_418_752]
+    held = (2 * group * block_bytes                    # the double buffer
+            + 2 * 2 * n * (640 + 512) * 2              # q, o: two each
+            + 4 * n * (128 + 128 + 512)                # m, l, acc
+            + 3 * 4 * n * chunk * 64)                  # scores, p, mask
+    assert held < declared[0] <= 128 * 2**20
 
 
 @pytest.fixture(scope="module")

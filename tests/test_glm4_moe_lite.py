@@ -429,19 +429,30 @@ def _paged_reference(qc, qpe, pool, tables, lens, base, kvr, scale):
 
 
 @pytest.mark.parametrize("rows", [1, 2])
-@pytest.mark.parametrize("step_bytes", [1 << 20, 2 * 8 * 128 * 4])
-def test_paged_decode_kernel_is_the_plain_paged_attention(monkeypatch, rows,
-                                                          step_bytes):
+@pytest.mark.parametrize("product_keys,buffer_bytes", [
+    (2048, 8 << 20),          # the defaults: one product a whole table
+    (16, 2 * 2 * 2 * 4096),   # two blocks a product, two products a group
+    (32, 2 * 4 * 4096),       # a group of four: the long slot's second ends
+                              # after two live blocks, inside its product
+    (8, 2 * 4096),            # one block a group and a product
+])
+def test_paged_decode_kernel_is_the_plain_paged_attention(
+        monkeypatch, rows, product_keys, buffer_bytes):
     """The dense kernel against gathering every block of a slot's table:
-    ragged lengths (one key, a block and one, the whole table), a row a
-    slot and two, several blocks a grid step or two, a layer's base."""
+    ragged lengths (a block and one, one key, the whole table right after
+    that one-block slot, so the next slot's first group is copied through
+    its own table, three blocks less a key), a row a slot and two,
+    products of one, two and six blocks, groups cut short by a slot's
+    end, tables out of order, a layer's base. Blocks here are 4,096 B (8
+    rows of 128 float32 lanes)."""
     from paddle_tpu.kernels.pallas import mla_paged_decode as kernel
-    monkeypatch.setattr(kernel, "_STEP_BYTES", step_bytes)
+    monkeypatch.setattr(kernel, "_PRODUCT_KEYS", product_keys)
+    monkeypatch.setattr(kernel, "_BUFFER_BYTES", buffer_bytes)
     rng = np.random.default_rng(rows)
-    S, H, kvr, dr, bs, MB, NB, W = 3, 4, 32, 8, 8, 6, 40, 128
+    S, H, kvr, dr, bs, MB, NB, W = 4, 4, 32, 8, 8, 6, 40, 128
     pool = jnp.asarray(rng.normal(size=(2 * NB, bs, W)), F32)
     tables = jnp.asarray(rng.integers(1, NB, (S, MB)), jnp.int32)
-    last = np.asarray([1, 9, 48])
+    last = np.asarray([9, 1, 48, 23])
     lens = jnp.asarray(np.stack([last - rows + 1 + r for r in range(rows)],
                                 1).clip(1), jnp.int32)
     qc = jnp.asarray(rng.normal(size=(S, rows, H, kvr)), F32)
