@@ -500,6 +500,30 @@ def test_latent_prefill_program(latent, for_the_chip):
     assert not re.search(r"bf16\[16384,128,\d+\]", text)
 
 
+@pytest.mark.parametrize("heads,dn,dv,keys,masked,group", [
+    (128, 128, 128, 16384, True, 8),   # deepseek_v32_serve_backlog_16k
+    (20, 192, 256, 13312, False, 5),   # glm47_flash_serve_mtp_13k, causal
+])
+def test_mla_prefill(one_chip, for_the_chip, heads, dn, dv, keys, masked,
+                     group):
+    """The latent prefill kernel alone at the cells' widths (1,024 rows,
+    latent 512 + 64 in 640 lanes, key tiles of 1,024): the group of heads
+    a grid step that `_heads_per_step` derives, its blocks, scratch and a
+    head's tiles, fit the chip's VMEM."""
+    from paddle_tpu.kernels.pallas import mla_prefill as kernel
+    assert kernel._heads_per_step(heads, 1024, 1024, dn, dv, 128, 640, 512,
+                                  2, masked) == group
+    shapes = [((1024, heads, dn + 64), BF16), ((keys, 640), BF16),
+              ((512, heads * (dn + dv)), BF16)]
+    if masked:
+        shapes.append(((1024, keys), jnp.bool_))
+    text = _compiled_text(
+        lambda q, lat, w, *mask: kernel.mla_prefill_attention(
+            q, lat, w, mask[0] if mask else None, 7168, 512, 64, 0.1),
+        one_chip, *shapes)
+    assert "tpu_custom_call" in text
+
+
 # -- the dense latent engine with its MTP draft (glm47_flash_serve_mtp_13k) ------
 
 @pytest.mark.parametrize("rows", [1, 2])

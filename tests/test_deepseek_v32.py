@@ -14,6 +14,7 @@ The reference (`chipbench/reference/deepseek_v32.py`) is float32
 `highest`, one sequence at a time, every head expanded, the choice by
 `lax.top_k`, and imports nothing of the program.
 """
+import copy
 import math
 
 import jax
@@ -600,21 +601,47 @@ def test_chosen_rows_are_the_tables_rows(model, layer):
     assert (np.asarray(got) == np.asarray(dec._rows(layer, tables, pos))).all()
 
 
-@pytest.mark.parametrize("q_start", [0, 64])
-def test_prefill_attention_kernel_is_the_masked_expanded_form(model, q_start):
+@pytest.mark.parametrize("heads,group,tk,q_start", [
+    pytest.param(4, None, 128, 0, id="0"),
+    pytest.param(4, None, 128, 64, id="64"),
+    # 8 heads of dn = dv = 64 (a head's Wkvb columns whole lanes, as the
+    # cell's are), 2 or 4 a grid step, 1,536 keys in three tiles of 512;
+    # 32 rows from 0 (tiles 1 and 2 past the chunk's last row), from 496
+    # (the rows cross tiles 0 and 1), from 700 (tile 0 below the
+    # diagonal, tile 2 past the last row) and from 1,504 (every tile)
+    *[pytest.param(8, g, 1536, s, id=f"8h-G{g}-q{s}")
+      for g in (2, 4) for s in (0, 496, 700, 1504)]])
+def test_prefill_attention_kernel_is_the_masked_expanded_form(
+        model, monkeypatch, heads, group, tk, q_start):
     """The kernel forms k_nope and v from the latent rows a tile at a
-    time; the plain form expands them all and masks the scores."""
-    from paddle_tpu.kernels.pallas.mla_prefill import mla_prefill_attention
+    time, a group of heads a grid step; the plain form expands them all
+    and masks the scores. Where the rows reach past the first tile, the
+    first row's choice misses tile 0 and the second row's tile 1."""
+    from paddle_tpu.kernels.pallas import mla_prefill
     cfg, p = model.config, model.param_tree()["layers"][0]
     rng = np.random.default_rng(3)
-    tq, tk = 32, 128
-    q = jnp.asarray(rng.normal(size=(tq, 4, 24)), F32)
+    if heads != cfg.num_attention_heads:
+        cfg = copy.copy(cfg)
+        cfg.num_attention_heads = heads
+        cfg.qk_nope_head_dim = cfg.v_head_dim = 64
+        p = {"wkv_b": jnp.asarray(rng.normal(size=(32, heads * 128)) * 0.2,
+                                  F32)}
+    if group is not None:
+        monkeypatch.setattr(mla_prefill, "_heads_per_step",
+                            lambda *_: group)
+    tq = 32
+    q = jnp.asarray(rng.normal(size=(tq, heads, cfg.qk_head_dim)), F32)
     lat = jnp.asarray(rng.normal(size=(tk, 128)), F32).at[:, 40:].set(0)
     rows = q_start + np.arange(tq)
-    mask = (rng.random((tq, tk)) < 0.4) | (np.arange(tk)[None] == rows[:, None])
-    mask &= np.arange(tk)[None] <= rows[:, None]
-    got = mla_prefill_attention(q, lat, p["wkv_b"], jnp.asarray(mask),
-                                q_start, 32, 8, cfg.softmax_scale)
+    cols = np.arange(tk)[None]
+    mask = (rng.random((tq, tk)) < 0.4) | (cols == rows[:, None])
+    if q_start >= 512:
+        mask[0, :512] = False
+        mask[1, 512:1024] = False
+    mask &= cols <= rows[:, None]
+    got = mla_prefill.mla_prefill_attention(
+        q, lat, p["wkv_b"], jnp.asarray(mask), q_start, 32, 8,
+        cfg.softmax_scale)
     keys, vals = dm.expand(cfg, p, lat)
     want = dm.attend_expanded(cfg, q, keys, vals, jnp.asarray(mask))
     _close(got.reshape(tq, -1), want, 1e-5)

@@ -386,13 +386,14 @@ PARENT = {
     "latent_chunk":
         "f1fadb677cacc99afbbd9c1a862864b8b3bf19a7397498ddff2394cf87e54b57",
     "latent_prefill":
-        "60b99ed6f1a44cdf88258d9e49e9a4f10b3edf89b2addcc283711b437ad468d2",
+        "be14ac90dc1ff0969f2861b7fc56694e8f19ba1b35f419574d8533dd4a5fa9fc",
 }
 
 
 def test_sparse_programs_lower_to_the_parents_text():
-    """sha256 of the CPU lowering at the parent commit of the V3.2
-    configuration's decode chunk and prefill chunk, at toy widths whose
+    """sha256 of the CPU lowering of the V3.2 configuration's decode
+    chunk and prefill chunk (the prefill's since its attention kernel
+    takes a group of heads a grid step), at toy widths whose
     heads' Wkvb columns are whole lanes (dn + dv = 128), as the cell's
     (256) are: the indexer made optional, the dense engine and the
     causal prefill kernel beside them move neither. A PR that means to
@@ -463,15 +464,42 @@ def test_paged_decode_kernel_is_the_plain_paged_attention(
            1e-5)
 
 
-@pytest.mark.parametrize("q_start", [0, 32])
-def test_causal_prefill_kernel_is_the_all_ones_masked_form(q_start):
-    from paddle_tpu.kernels.pallas.mla_prefill import mla_prefill_attention
+def _causal_reference(q, lat, w, q_start, kvr, dr, dv, scale):
+    """Every head's keys and values expanded, [Tq, nh, dv]."""
+    tq, nh, _ = q.shape
+    kv = (lat[:, :kvr] @ w).reshape(lat.shape[0], nh, -1)
+    pe = jnp.broadcast_to(lat[:, None, kvr:kvr + dr], (lat.shape[0], nh, dr))
+    k = jnp.concatenate([kv[..., :-dv], pe], -1)
+    s = jnp.einsum("thd,jhd->htj", q, k) * scale
+    rows = q_start + jnp.arange(tq)
+    s = jnp.where(jnp.arange(lat.shape[0])[None] <= rows[:, None], s,
+                  -jnp.inf)
+    return jnp.einsum("htj,jhd->thd", jax.nn.softmax(s, -1), kv[..., -dv:])
+
+
+@pytest.mark.parametrize("q_start,nh,dn,dv,tk,group", [
+    pytest.param(0, 2, 16, 24, 64, None, id="0"),
+    pytest.param(32, 2, 16, 24, 64, None, id="32"),
+    # 10 heads, 5 a grid step, dn + dv = 64 (no whole lanes a head),
+    # 1,536 keys in three tiles: tile 0 below the rows' diagonal, tile 1
+    # across it, tile 2 past the chunk's last row
+    pytest.param(700, 10, 24, 40, 1536, 5, id="10h-G5-q700"),
+    pytest.param(1504, 10, 24, 40, 1536, 5, id="10h-G5-q1504")])
+def test_causal_prefill_kernel_is_the_all_ones_masked_form(
+        monkeypatch, q_start, nh, dn, dv, tk, group):
+    from paddle_tpu.kernels.pallas import mla_prefill
+    if group is not None:
+        monkeypatch.setattr(mla_prefill, "_heads_per_step",
+                            lambda *_: group)
     rng = np.random.default_rng(q_start)
-    tq, tk, nh, dn, dr, dv, kvr, W = 32, 64, 2, 16, 8, 24, 32, 128
+    tq, dr, kvr, W = 32, 8, 32, 128
     q = jnp.asarray(rng.normal(size=(tq, nh, dn + dr)), F32)
     lat = jnp.asarray(rng.normal(size=(tk, W)), F32)
     w = jnp.asarray(rng.normal(size=(kvr, nh * (dn + dv))) * 0.2, F32)
-    causal = mla_prefill_attention(q, lat, w, None, q_start, kvr, dr, 0.2)
-    masked = mla_prefill_attention(q, lat, w, jnp.ones((tq, tk), bool),
-                                   q_start, kvr, dr, 0.2)
+    causal = mla_prefill.mla_prefill_attention(q, lat, w, None, q_start,
+                                               kvr, dr, 0.2)
+    masked = mla_prefill.mla_prefill_attention(
+        q, lat, w, jnp.ones((tq, tk), bool), q_start, kvr, dr, 0.2)
     _close(causal, masked, 1e-6)
+    _close(causal, _causal_reference(q, lat, w, q_start, kvr, dr, dv, 0.2),
+           1e-5)
